@@ -10,10 +10,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import ray_tpu  # noqa: E402
 from ray_tpu.autoscaler import (  # noqa: E402
     Autoscaler,

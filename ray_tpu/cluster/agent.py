@@ -594,10 +594,22 @@ class NodeAgent:
             except Exception:  # noqa: BLE001 - report loop backfills later
                 logger.exception("initial worker spawn failed")
 
-    def _start_zygote(self) -> None:
+    def _worker_env(self) -> Dict[str, str]:
+        """Environment of every worker (and of the zygote they fork from).
+        Workers start on the CPU platform whatever this agent inherited: a
+        chip belongs to one process, and on a host whose head (or another
+        worker) holds it, a pooled worker that let JAX pick its default
+        backend would try to open it too. Only a lease that assigns chips
+        lifts the pin (scheduler/instances.py ``env_for``, applied by
+        worker.py ``_export_env``)."""
         env = dict(os.environ)
         env["RAY_TPU_HEAD_ADDRESS"] = self.head_address
         env["RAY_TPU_NODE_ID"] = self.node_id
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
+
+    def _start_zygote(self) -> None:
+        env = self._worker_env()
         try:
             self._zygote = ZygoteClient(self.address, self.store_path, env)
         except OSError:
@@ -671,9 +683,7 @@ class NodeAgent:
                     handle.spawned_at = t0
                     handle.spawn_path = "fork"
                     return self._track_spawn(handle, prestart)
-        env = dict(os.environ)
-        env["RAY_TPU_HEAD_ADDRESS"] = self.head_address
-        env["RAY_TPU_NODE_ID"] = self.node_id
+        env = self._worker_env()
         interpreter = sys.executable
         if pip_env is not None:
             kind = pip_env[2] if len(pip_env) > 2 else "pip"
@@ -2504,9 +2514,9 @@ class NodeAgent:
             # respawn workers that died outside a push (including ones that
             # crashed at startup before ever registering). A spawn that
             # never registers within the timeout counts as dead too — a
-            # wedged startup (e.g. accelerator transport hang) would
-            # otherwise hold its _spawns_pending reservation forever and
-            # suppress backfill/prestart for the rest of the agent's life.
+            # hung startup would otherwise hold its _spawns_pending
+            # reservation forever and suppress backfill/prestart for the
+            # rest of the agent's life.
             if self._zygote is not None:
                 self._zygote.drain_exits()
             with self._lock:

@@ -1189,7 +1189,7 @@ class HeadServer:
             # breaker -> health path: a wedged/blackholed transport to this
             # node opens its circuit and declares it unreachable in
             # ~rpc_breaker_window_s instead of stalling every dispatch for
-            # its full timeout (the 600s accelerator-transport wedge class)
+            # its full timeout
             self._clients[info.node_id] = RpcClient(
                 info.address,
                 on_unreachable=lambda nid=info.node_id: (
@@ -3073,10 +3073,10 @@ class HeadServer:
 
     @property
     def device_state(self):
-        """Lazy DeviceSchedulerState with bring-up timeout: JAX backend init
-        happens on the first scheduling round (never at construction), and a
-        wedged accelerator transport degrades to the host golden model
-        instead of freezing the scheduler (scheduler/device.py
+        """Lazy DeviceSchedulerState: JAX backend init happens on the first
+        scheduling round (never at construction). None when the device
+        scheduler is off; raises what the bring-up raised when the
+        configured platform cannot be had (scheduler/device.py
         LazyDeviceState)."""
         return self._lazy_device.get()
 
@@ -3458,8 +3458,8 @@ class HeadServer:
             device_state = None
         else:
             # lazy XLA/backend init happens OUTSIDE the view lock: a slow
-            # (or wedged) backend bring-up must stall only the scheduler
-            # thread, never every RPC handler that needs the lock
+            # backend bring-up must stall only the scheduler thread, never
+            # every RPC handler that needs the lock
             device_state = self.device_state
         with self._lock:
             n = self.view.num_nodes

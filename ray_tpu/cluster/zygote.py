@@ -1,9 +1,8 @@
 """Fork-server ("zygote") for millisecond worker spawn.
 
 The agent's cold spawn path pays a full interpreter start + the worker
-module graph import (grpc, cloudpickle — and jax when ``JAX_PLATFORMS``
-is set) per worker: seconds on a loaded host, and the dominant cost of
-actor churn (BENCH_r05 actor_creations_per_s). The reference avoids it
+module graph import (grpc, cloudpickle, and jax at first use) per worker:
+seconds on a loaded host, and the dominant cost of actor churn. The reference avoids it
 with worker_pool.cc's prestarted idle workers; CPython can do one
 better: ONE process (this module) pays the import exactly once, then
 ``os.fork()`` clones it per worker in milliseconds.
@@ -287,20 +286,13 @@ def main() -> None:
     parser.add_argument("--store", default="")
     args = parser.parse_args()
 
-    # Pay the worker's import graph ONCE, pre-fork. Mirrors worker.main:
-    # jax is imported (and its platform pinned) only when JAX_PLATFORMS
-    # is set — config.update creates no backend, so no threads exist at
-    # fork time. RAY_TPU_ZYGOTE_PRELOAD names extra modules to warm.
+    # Pay the worker's import graph ONCE, pre-fork — jax included, which
+    # takes its platform from the JAX_PLATFORMS of the spawn environment
+    # (agent ``_worker_env``). Importing creates no backend, so no threads
+    # exist at fork time. RAY_TPU_ZYGOTE_PRELOAD names extra modules to warm.
     from . import worker as _worker_mod  # noqa: F401 - import for side effect
 
-    plat = os.environ.get("JAX_PLATFORMS")
-    if plat:
-        try:
-            import jax
-
-            jax.config.update("jax_platforms", plat)
-        except Exception:  # noqa: BLE001 - jax optional
-            pass
+    import jax  # noqa: F401 - import for side effect
     for name in filter(None, os.environ.get("RAY_TPU_ZYGOTE_PRELOAD", "").split(",")):
         try:
             __import__(name.strip())

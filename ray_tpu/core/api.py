@@ -49,8 +49,9 @@ def init(
     multi-process cluster's head (the distributed runtime in
     ray_tpu.cluster; the reference's ray.init(address=...) +
     Ray-Client mode). The scheduler runs the batched XLA kernels on the
-    device selected by ``RAY_TPU_SCHED_PLATFORM`` (default host XLA; set
-    "tpu" to pin the chip) — ``use_device_scheduler=False`` or
+    first device of the platform ``RAY_TPU_SCHED_PLATFORM`` names ("cpu"
+    by default, "tpu" for the attached chip; a platform that is absent is
+    an error) — ``use_device_scheduler=False`` or
     ``RAY_TPU_DEVICE_SCHEDULER=0`` selects the NumPy golden model instead.
     """
     if runtime_initialized():

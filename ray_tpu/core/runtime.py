@@ -29,6 +29,7 @@ import itertools
 import logging
 import os
 import threading
+import time
 import traceback
 import uuid
 from concurrent.futures import ThreadPoolExecutor
@@ -56,8 +57,6 @@ _STREAM_END = object()  # generator-exhausted sentinel (values can be None)
 
 
 def _now() -> float:
-    import time
-
     return time.monotonic()
 
 
@@ -444,9 +443,9 @@ class Runtime:
     # ------------------------------------------------------------------
     @property
     def device_state(self):
-        """Lazy DeviceSchedulerState with bring-up timeout (see
-        scheduler/device.py LazyDeviceState): a wedged accelerator backend
-        degrades to the host golden model instead of freezing init."""
+        """Lazy DeviceSchedulerState (see scheduler/device.py
+        LazyDeviceState): None when the device scheduler is off; raises
+        when the configured platform cannot be had."""
         return self._lazy_device.get()
 
     def _unready_args(self, spec: TaskSpec) -> List[ObjectRef]:
@@ -506,6 +505,9 @@ class Runtime:
                 logger.exception("scheduler round failed; requeueing batch")
                 with self._cond:
                     self._pending.extend(batch)
+                # a failure that repeats (a scheduler platform that cannot
+                # be had) must not spin this thread at full speed
+                time.sleep(0.1)
 
     def register_pg(self, state) -> None:
         """Queue a placement group for scheduling (SchedulePendingPlacementGroups
@@ -650,7 +652,7 @@ class Runtime:
             return
 
         totals = avail = alive = None
-        # lazy XLA init outside the lock (a wedged backend must not freeze
+        # lazy XLA init outside the lock (a slow bring-up must not stall
         # every thread that needs the view)
         device_state = self.device_state
         with self._lock:

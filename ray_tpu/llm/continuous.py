@@ -28,6 +28,7 @@ pattern this follows.
 from __future__ import annotations
 
 import functools
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -38,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import transformer as tfm
+from ray_tpu.util.compile_cache import configure_compile_cache
 
 from .engine import ByteTokenizer, GenerationConfig
 
@@ -121,8 +123,25 @@ class PagedKVPool:
         self._free_set.update(pages)
 
 
+def _locked(method):
+    """Run an engine method under the engine's lock."""
+
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with self._lock:
+            return method(self, *args, **kwargs)
+
+    return wrapper
+
+
 class ContinuousBatchingEngine:
-    """Slot-based continuous batching over the flagship transformer."""
+    """Slot-based continuous batching over the flagship transformer.
+
+    Thread contract: a serve replica runs up to ``max_concurrency``
+    requests at once, and each of them drives ``step()`` until its own
+    answer is there. Everything that touches slots, queue, pool or the
+    device-side slot state therefore runs under one lock; a step taken by
+    any request's thread advances every active slot."""
 
     def __init__(
         self,
@@ -144,6 +163,24 @@ class ContinuousBatchingEngine:
                 "paged continuous batching currently supports dense MLP "
                 "models (use LLMEngine for MoE)"
             )
+        configure_compile_cache()
+        if use_pallas_attention and not pallas_interpret:
+            from ray_tpu.ops import paged_attention as pa
+
+            staged = pa.staged_vmem_bytes(
+                n_pages, page_size, cfg.head_dim, cfg.dtype
+            )
+            if staged > pa.SCOPED_VMEM_BYTES:
+                raise ValueError(
+                    "use_pallas_attention=True: the paged-decode kernel "
+                    "stages one head's whole pool slice in VMEM, "
+                    f"{staged / 2**20:.1f} MiB for n_pages={n_pages} x "
+                    f"page_size={page_size} x head_dim={cfg.head_dim} x "
+                    f"{jnp.dtype(cfg.dtype).name} (K and V, double-"
+                    f"buffered), over the {pa.SCOPED_VMEM_BYTES / 2**20:.0f} "
+                    "MiB a kernel may use; shrink the pool or leave the "
+                    "default gather path on"
+                )
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
@@ -169,6 +206,7 @@ class ContinuousBatchingEngine:
             if params is not None
             else tfm.init_params(cfg, jax.random.PRNGKey(0))
         )
+        self._lock = threading.RLock()
         self.slots = [_Slot() for _ in range(self.B)]
         self.queue: deque = deque()
         self.results: Dict[int, List[int]] = {}
@@ -525,6 +563,7 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     # scheduler
     # ------------------------------------------------------------------
+    @_locked
     def submit(self, prompt: List[int], gen: GenerationConfig) -> int:
         if gen.top_k:
             raise NotImplementedError(
@@ -737,6 +776,7 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     # disaggregated serving: prefill/decode split (PR 18)
     # ------------------------------------------------------------------
+    @_locked
     def prefill_extract(self, prompt: List[int], gen: GenerationConfig):
         """Prefill-worker half of the KV handoff: run the bucketed
         prefill program for ``prompt``, sample the first token
@@ -808,6 +848,7 @@ class ContinuousBatchingEngine:
         }
         return manifest, k, v
 
+    @_locked
     def adopt_pages(self, manifest: dict, k, v) -> Optional[int]:
         """Decode-engine half of the KV handoff: graft prefilled KV
         pages straight into this engine's pool and admit the request
@@ -930,6 +971,7 @@ class ContinuousBatchingEngine:
             self._swap_started = None
         return self.weights_epoch
 
+    @_locked
     def _force_evict_active(self) -> None:
         """Evict every still-active slot at the swap-drain deadline: the
         partial output lands in results (eos-truncated like a normal
@@ -961,6 +1003,7 @@ class ContinuousBatchingEngine:
             self.slots[si] = _Slot()
             self.active_mask = self.active_mask.at[si].set(False)
 
+    @_locked
     def step(self) -> List[int]:
         """Admit + one decode step for all active slots. Returns req_ids
         finished in this step."""
@@ -1022,19 +1065,24 @@ class ContinuousBatchingEngine:
         local prefill ever runs)."""
         yielded = 0
         try:
-            while rid not in self.results:
-                self.step()
-                slot = next(
-                    (s for s in self.slots if s.req_id == rid and s.active),
-                    None,
-                )
-                if slot is not None:
-                    out = slot.out
-                    if slot.eos is not None and slot.eos in out:
+            while True:
+                with self._lock:
+                    if rid in self.results:
+                        break
+                    self.step()
+                    slot = next(
+                        (
+                            s for s in self.slots
+                            if s.req_id == rid and s.active
+                        ),
+                        None,
+                    )
+                    out = list(slot.out) if slot is not None else []
+                    if slot is not None and slot.eos in out:
                         out = out[: out.index(slot.eos)]
-                    while yielded < len(out):
-                        yield out[yielded]
-                        yielded += 1
+                while yielded < len(out):
+                    yield out[yielded]
+                    yielded += 1
             final = self.results.pop(rid)
             while yielded < len(final):
                 yield final[yielded]
@@ -1044,6 +1092,7 @@ class ContinuousBatchingEngine:
             # stop burning decode steps on a dead client
             self._cancel(rid)
 
+    @_locked
     def _cancel(self, rid: int) -> None:
         """Drop a request wherever it is: queued, active, or finished."""
         self.results.pop(rid, None)
@@ -1073,6 +1122,7 @@ class ContinuousBatchingEngine:
         out = self.generate_ids(enc, gen)
         return [self.tokenizer.decode(ids) for ids in out]
 
+    @_locked
     def stats(self) -> dict:
         out = {
             "free_pages": self.pool.free_pages,

@@ -81,6 +81,9 @@ def build_llm_deployment(
     page_size: int = 16,
     n_pages: int = 256,
     prefix_cache: bool = True,
+    # text <-> token ids; None = the engines' 258-id ByteTokenizer, which
+    # cannot name most ids of a wider vocabulary
+    tokenizer: Optional[Any] = None,
     slo: Optional[Any] = None,
     # disaggregated serving (PR 18): >0 stands up a companion
     # "<name>-prefill" deployment — the router runs the prefill phase
@@ -123,6 +126,7 @@ def build_llm_deployment(
             max_batch=max_batch,
             page_size=page_size,
             n_pages=n_pages,
+            tokenizer=tokenizer,
             prefix_cache=cache,
             model_id=model_id,
         )
@@ -145,7 +149,10 @@ def build_llm_deployment(
             if engine == "continuous":
                 self.engine = _make_engine(base_model_id)
             else:
-                self.engine = LLMEngine(model_config, params, max_len=max_len)
+                self.engine = LLMEngine(
+                    model_config, params, max_len=max_len,
+                    tokenizer=tokenizer,
+                )
             self._tokens_out = 0
             # hot-swap plane: base + variant weights by model id; the
             # node WeightsHub (shm arena) is probed first so same-node

@@ -17,11 +17,13 @@ Design (vs the reference, which wraps vLLM/torch and has no native model):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.ops.layers import (
@@ -34,6 +36,7 @@ from ray_tpu.ops.layers import (
 from ray_tpu.ops.pipeline import pipeline_apply
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.models import moe as moe_mod
+from ray_tpu.util.compile_cache import configure_compile_cache
 
 
 @dataclass(frozen=True)
@@ -139,8 +142,73 @@ def shard_params(params, cfg: ModelConfig, mesh: Mesh):
     )
 
 
+def _causal_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, mesh: Optional[Mesh] = None
+) -> jax.Array:
+    """Causal self-attention of one block outside the sp axis: the Pallas
+    flash kernels wherever Mosaic compiles them (forward and backward,
+    ragged lengths padded inside), the fused-XLA reference on the CPU
+    backend, where a Pallas kernel could only be interpreted.
+
+    Under a mesh the kernels run in a shard_map, batch split over dp and
+    heads over tp: every (batch, head) attends on its own, so the split is
+    exact, and it has to be spelled out because the partitioner refuses a
+    Mosaic kernel unless every mesh axis is manual ("cannot be
+    automatically partitioned"). Inside the pipeline's shard_map pp and sp
+    are manual already; this one takes the axes that are left. Autodiff
+    cannot carry residuals out of a shard_map nested that way (it stacks
+    them over every varying axis, pp included, which Shardy rejects), so
+    forward and backward are two shard_maps under one custom_vjp and the
+    residuals cross as ordinary outputs. For the same reason nothing in
+    the two bodies may be independent of their inputs — partial evaluation
+    of the layer scan would split it off as a residual — so a ragged
+    length is padded out here, not inside. KV heads that do not divide
+    over tp stay whole on every tp shard. The reference needs none of
+    this: the partitioner splits it by itself."""
+    if jax.default_backend() == "cpu":
+        return attention_reference(q, k, v, causal=True)
+    from ray_tpu.ops import flash_attention as fa
+
+    if mesh is None or mesh.size == 1:
+        return fa.flash_attention(q, k, v, causal=True)
+    ctx = jax.sharding.get_abstract_mesh()  # set inside an outer shard_map
+    heads = "tp" if k.shape[2] % mesh.shape["tp"] == 0 else None
+    spec = P("dp", None, heads, None)  # q, k, v, out and their cotangents
+    lse_spec = P("dp", heads, None)
+    sharded = functools.partial(
+        jax.shard_map,
+        mesh=mesh if ctx.empty else None,
+        axis_names=set(mesh.axis_names) - set(ctx.manual_axes),
+        check_vma=True,
+    )
+    sm_fwd = sharded(
+        functools.partial(fa.flash_fwd, causal=True),
+        in_specs=(spec, spec, spec),
+        out_specs=(spec, lse_spec),
+    )
+    sm_bwd = sharded(
+        functools.partial(fa.flash_bwd, causal=True),
+        in_specs=(spec, spec, spec, spec, lse_spec, spec),
+        out_specs=(spec, spec, spec),
+    )
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return sm_fwd(q, k, v)[0]
+
+    def attend_fwd(q, k, v):
+        out, lse = sm_fwd(q, k, v)
+        return out, (q, k, v, out, lse)
+
+    attend.defvjp(attend_fwd, lambda res, do: sm_bwd(*res, do))
+    t = q.shape[1]
+    pad = fa.pad_len(t, t, True, fa.DEFAULT_BLOCK_Q, fa.DEFAULT_BLOCK_K)
+    return attend(*(fa.pad_rows(x, pad) for x in (q, k, v)))[:, :t]
+
+
 def _block(cfg: ModelConfig, p: Dict[str, jax.Array], h: jax.Array,
-           angles: jax.Array, *, sp_manual: bool) -> jax.Array:
+           angles: jax.Array, *, sp_manual: bool,
+           mesh: Optional[Mesh] = None) -> jax.Array:
     """One decoder block. h: [B, T(_local), D]; angles already offset."""
     b, t, d = h.shape
     hd = cfg.head_dim
@@ -157,13 +225,8 @@ def _block(cfg: ModelConfig, p: Dict[str, jax.Array], h: jax.Array,
             attn = ulysses_attention(q, k, v, "sp", causal=True)
         else:
             attn = ring_attention(q, k, v, "sp", causal=True)
-    elif jax.default_backend() not in ("cpu",):
-        # TPU: pallas flash kernel (falls back internally on ragged shapes)
-        from ray_tpu.ops.flash_attention import flash_attention
-
-        attn = flash_attention(q, k, v, causal=True)
     else:
-        attn = attention_reference(q, k, v, causal=True)
+        attn = _causal_attention(q, k, v, mesh)
     h = h + attn.reshape(b, t, -1) @ p["wo"]
     x = rms_norm(h, p["ln2"])
     if cfg.n_experts > 0:
@@ -173,9 +236,13 @@ def _block(cfg: ModelConfig, p: Dict[str, jax.Array], h: jax.Array,
     return h + y
 
 
-def _scan_blocks(cfg: ModelConfig, blocks, h, angles, *, sp_manual: bool):
+def _scan_blocks(cfg: ModelConfig, blocks, h, angles, *, sp_manual: bool,
+                 mesh: Optional[Mesh] = None):
     def body(h, layer_p):
-        return _block(cfg, layer_p, h, angles, sp_manual=sp_manual), None
+        return (
+            _block(cfg, layer_p, h, angles, sp_manual=sp_manual, mesh=mesh),
+            None,
+        )
 
     if cfg.remat:
         # prevent_cse=False: under lax.scan the CSE-prevention barriers
@@ -202,7 +269,9 @@ def forward(
     angles_full = rope_freqs(cfg.head_dim, t, cfg.rope_theta)
 
     if pp == 1 and sp == 1:
-        h = _scan_blocks(cfg, params["blocks"], h, angles_full, sp_manual=False)
+        h = _scan_blocks(
+            cfg, params["blocks"], h, angles_full, sp_manual=False, mesh=mesh
+        )
     elif pp == 1:
         # sequence-parallel only: ring attention over sp
         def sp_body(blocks, h_loc):
@@ -241,7 +310,7 @@ def forward(
 
             def stage_fn(blocks, x_one):
                 return _scan_blocks(
-                    cfg, blocks, x_one, ang, sp_manual=sp > 1
+                    cfg, blocks, x_one, ang, sp_manual=sp > 1, mesh=mesh
                 )
 
             return pipeline_apply(stage_fn, stage_blocks, x_mb, "pp")
@@ -376,6 +445,13 @@ def loss_fn(params, tokens, cfg: ModelConfig, mesh=None, *, num_microbatches=0):
 
 def make_train_step(cfg: ModelConfig, optimizer, mesh=None, *, num_microbatches=0):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state, loss)."""
+    configure_compile_cache()
+    shardings = None
+    if mesh is not None:
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            param_specs(cfg, mesh.shape.get("pp", 1)),
+        )
 
     def train_step(params, opt_state, tokens):
         loss, grads = jax.value_and_grad(loss_fn)(
@@ -383,6 +459,17 @@ def make_train_step(cfg: ModelConfig, optimizer, mesh=None, *, num_microbatches=
         )
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = jax.tree.map(lambda p, u: p + u, params, updates)
+        if shardings is not None:
+            # Parameters, and the optimizer's parameter-shaped state, leave
+            # the step sharded as they entered. Left open, the TPU compiler
+            # returns the norm scales and their moments split over tp under
+            # pp·tp: the next call recompiles (or, compiled ahead of time,
+            # refuses its own output) and donation finds no buffer to reuse.
+            pin = jax.lax.with_sharding_constraint
+            params = pin(params, shardings)
+            opt_state = optax.tree_map_params(
+                optimizer, pin, opt_state, shardings
+            )
         return params, opt_state, loss
 
     return train_step

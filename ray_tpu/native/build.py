@@ -19,13 +19,15 @@ SOURCES = {
 }
 
 
-def build_native(name: str = "objstore") -> str:
-    """Compile (if stale) and return the path to lib<name>.so."""
+def build_native(name: str = "objstore", *, force: bool = False) -> str:
+    """Compile (if stale, or ``force``) and return the path to lib<name>.so.
+    A compiler failure raises ``subprocess.CalledProcessError``."""
     src = os.path.join(_HERE, SOURCES[name])
     out = os.path.join(_BUILD_DIR, f"lib{name}.so")
     with _lock:
         if (
-            os.path.exists(out)
+            not force
+            and os.path.exists(out)
             and os.path.getmtime(out) >= os.path.getmtime(src)
         ):
             return out
@@ -50,3 +52,11 @@ def build_native(name: str = "objstore") -> str:
         )
         os.replace(tmp, out)
     return out
+
+
+def rebuild_all() -> dict:
+    """Compile every component from its committed source, whatever
+    ``_build/`` holds: the mtime test above trusts a library that a copied
+    tree brought along, which may not be what these sources build.
+    Returns {name: path}."""
+    return {name: build_native(name, force=True) for name in SOURCES}

@@ -178,15 +178,70 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
-def _fwd_impl(qg, kg, vg, causal, block_q, block_k, interpret):
-    bh, t, d = qg.shape
+def _out_like(x, shape=None, dtype=None):
+    """pallas_call out_shape for an output that varies over the same
+    manual mesh axes as ``x`` (shard_map's check_vma needs it spelled out;
+    outside shard_map the set is empty)."""
+    return jax.ShapeDtypeStruct(
+        x.shape if shape is None else shape,
+        x.dtype if dtype is None else dtype,
+        vma=jax.typeof(x).vma,
+    )
+
+
+def _to_bh(x):
+    """[B, T, H, D] -> [B·H, T, D]: (batch, head) is the grid's first axis."""
+    b, t, h, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, t, d)
+
+
+def _from_bh(x, b):
+    bh, t, d = x.shape
+    return x.reshape(b, bh // b, t, d).transpose(0, 2, 1, 3)
+
+
+def pad_len(t: int, s: int, causal: bool, block_q: int, block_k: int):
+    """Rows of zero padding that bring a ragged causal self-attention to
+    the block multiple, 0 when the shapes already tile, None when they
+    cannot be padded. Exact for causal t == s: padded keys sit at
+    positions >= t, strictly in every real query's masked future, and
+    padded query rows are sliced off (their cotangents are zero). Keeps
+    the O(T) flash memory profile on ragged lengths (e.g. the T-1
+    next-token training slice), where the reference would materialize
+    [T, S] per layer."""
+    if not (t % block_q or s % block_k):
+        return 0
+    if causal and t == s:
+        return -t % (block_q * block_k // math.gcd(block_q, block_k))
+    return None
+
+
+def pad_rows(x, pad):
+    return jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else x
+
+
+def flash_fwd(q, k, v, *, causal=True, block_q=DEFAULT_BLOCK_Q,
+              block_k=DEFAULT_BLOCK_K, interpret=False):
+    """Forward kernel only: ``(out [B,T,H,D], lse [B,H,T_pad])``. With
+    ``flash_bwd`` this is the pair ``flash_attention`` differentiates
+    through; a caller that has to carry the residuals across a boundary
+    autodiff cannot cross (models/transformer.py, attention under a mesh)
+    uses the pair directly. Shapes must tile or be paddable (``pad_len``)."""
+    b, t, h, d = q.shape
+    groups = h // k.shape[2]
+    pad = pad_len(t, k.shape[1], causal, block_q, block_k)
+    q, k, v = (pad_rows(x, pad) for x in (q, k, v))
+    # GQA: each K/V head serves `groups` consecutive Q heads
+    qg = _to_bh(q)
+    kg = _to_bh(jnp.repeat(k, groups, axis=2))
+    vg = _to_bh(jnp.repeat(v, groups, axis=2))
+    bh, tp, _ = qg.shape
     s = kg.shape[1]
-    scale = 1.0 / (d**0.5)
     out, lse = pl.pallas_call(
         functools.partial(
-            _fwd_kernel, block_k=block_k, causal=causal, scale=scale
+            _fwd_kernel, block_k=block_k, causal=causal, scale=1.0 / d**0.5
         ),
-        grid=(bh, t // block_q),
+        grid=(bh, tp // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
             pl.BlockSpec((1, s, d), lambda b, qi: (b, 0, 0)),
@@ -196,42 +251,39 @@ def _fwd_impl(qg, kg, vg, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
             pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(qg.shape, qg.dtype),
-            jax.ShapeDtypeStruct((bh, 1, t), jnp.float32),
-        ],
+        out_shape=[_out_like(qg), _out_like(qg, (bh, 1, tp), jnp.float32)],
         interpret=interpret,
     )(qg, kg, vg)
-    return out, lse
+    return _from_bh(out, b)[:, :t], lse.reshape(b, h, tp)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_grouped(qg, kg, vg, causal, block_q, block_k, interpret):
-    """Grouped layout [B·KH·G, T, D]; K/V already repeated per group (the
-    repeat sits OUTSIDE this boundary so autodiff sums dk/dv over groups)."""
-    out, _ = _fwd_impl(qg, kg, vg, causal, block_q, block_k, interpret)
-    return out
-
-
-def _flash_grouped_fwd(qg, kg, vg, causal, block_q, block_k, interpret):
-    out, lse = _fwd_impl(qg, kg, vg, causal, block_q, block_k, interpret)
-    return out, (qg, kg, vg, out, lse)
-
-
-def _flash_grouped_bwd(causal, block_q, block_k, interpret, res, do):
-    qg, kg, vg, out, lse = res
-    bh, t, d = qg.shape
+def flash_bwd(q, k, v, out, lse, do, *, causal=True,
+              block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
+              interpret=False):
+    """Backward kernels: ``(dq, dk, dv)`` from the forward's inputs, its
+    output and its logsumexp, and the output cotangent."""
+    b, t, h, d = q.shape
+    s0, hkv = k.shape[1], k.shape[2]
+    groups = h // hkv
+    pad = lse.shape[2] - t
+    q, k, v, out, do = (pad_rows(x, pad) for x in (q, k, v, out, do))
+    qg, og, dog = _to_bh(q), _to_bh(out), _to_bh(do)
+    kg = _to_bh(jnp.repeat(k, groups, axis=2))
+    vg = _to_bh(jnp.repeat(v, groups, axis=2))
+    bh, tp, _ = qg.shape
     s = kg.shape[1]
-    scale = 1.0 / (d**0.5)
-    # delta_i = rowsum(dO ⊙ O): the softmax-jacobian correction term
+    scale = 1.0 / d**0.5
+    lse = lse.reshape(bh, 1, tp)
+    # delta_i = rowsum(dO ⊙ O): the softmax-jacobian correction term, in
+    # the same [bh, 1, t] layout as lse (see _fwd_kernel)
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )[:, None, :]  # [bh, 1, t] — same layout as lse (see _fwd_kernel)
+        dog.astype(jnp.float32) * og.astype(jnp.float32), axis=-1
+    )[:, None, :]
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, block_k=block_k, causal=causal, scale=scale
         ),
-        grid=(bh, t // block_q),
+        grid=(bh, tp // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
             pl.BlockSpec((1, s, d), lambda b, qi: (b, 0, 0)),
@@ -241,36 +293,59 @@ def _flash_grouped_bwd(causal, block_q, block_k, interpret, res, do):
             pl.BlockSpec((1, 1, block_q), lambda b, qi: (b, 0, qi)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, qi: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(qg.shape, qg.dtype),
+        out_shape=_out_like(qg),
         interpret=interpret,
-    )(qg, kg, vg, do, lse, delta)
+    )(qg, kg, vg, dog, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, block_q=block_q, causal=causal, scale=scale
         ),
         grid=(bh, s // block_k),
         in_specs=[
-            pl.BlockSpec((1, t, d), lambda b, ki: (b, 0, 0)),
+            pl.BlockSpec((1, tp, d), lambda b, ki: (b, 0, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
-            pl.BlockSpec((1, t, d), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, 1, t), lambda b, ki: (b, 0, 0)),
-            pl.BlockSpec((1, 1, t), lambda b, ki: (b, 0, 0)),
+            pl.BlockSpec((1, tp, d), lambda b, ki: (b, 0, 0)),
+            pl.BlockSpec((1, 1, tp), lambda b, ki: (b, 0, 0)),
+            pl.BlockSpec((1, 1, tp), lambda b, ki: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, ki: (b, ki, 0)),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct(kg.shape, kg.dtype),
-            jax.ShapeDtypeStruct(vg.shape, vg.dtype),
-        ],
+        out_shape=[_out_like(kg), _out_like(vg)],
         interpret=interpret,
-    )(qg, kg, vg, do, lse, delta)
-    return dq, dk, dv
+    )(qg, kg, vg, dog, lse, delta)
+
+    def kv_grad(g):
+        # sum over the `groups` query heads that shared each K/V head
+        g = _from_bh(g, b)[:, :s0].reshape(b, s0, hkv, groups, d)
+        return g.astype(jnp.float32).sum(axis=3).astype(g.dtype)
+
+    return _from_bh(dq, b)[:, :t], kv_grad(dk), kv_grad(dv)
 
 
-_flash_grouped.defvjp(_flash_grouped_fwd, _flash_grouped_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, block_q, block_k, interpret):
+    return _flash_fwd(q, k, v, causal, block_q, block_k, interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
+    out, lse = flash_fwd(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret,
+    )
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, block_q, block_k, interpret, res, do):
+    return flash_bwd(
+        *res, do, causal=causal, block_q=block_q, block_k=block_k,
+        interpret=interpret,
+    )
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(
@@ -287,52 +362,7 @@ def flash_attention(
     block_k: int = DEFAULT_BLOCK_K,
     interpret: bool = False,
 ) -> jax.Array:
-    b, t, h, d = q.shape
-    s, hkv = k.shape[1], k.shape[2]
-    groups = h // hkv
-    if t % block_q or s % block_k:
-        if causal and t == s:
-            # Ragged causal self-attention: zero-pad to the block multiple
-            # and slice the pad rows back off. Exact — padded keys sit at
-            # positions >= t, strictly in every real query's masked future,
-            # and the pad's transpose discards their cotangents. Keeps the
-            # O(T) flash memory profile on ragged lengths (e.g. the T-1
-            # next-token training slice), where the reference fallback
-            # would materialize [T, S] per layer.
-            m = block_q * block_k // math.gcd(block_q, block_k)
-            pad = -t % m
-            zq = ((0, 0), (0, pad), (0, 0), (0, 0))
-            out = flash_attention(
-                jnp.pad(q, zq), jnp.pad(k, zq), jnp.pad(v, zq),
-                causal=True, block_q=block_q, block_k=block_k,
-                interpret=interpret,
-            )
-            return out[:, :t]
+    if pad_len(q.shape[1], k.shape[1], causal, block_q, block_k) is None:
         # ragged cross/non-causal tails fall back to the fused-XLA path
         return attention_reference(q, k, v, causal=causal)
-
-    # layout: fold (batch, kv_head, group) into the grid's first axis; GQA
-    # shares each K/V head across `groups` Q heads. The repeat stays
-    # outside the custom-vjp boundary so dk/dv sum over groups for free.
-    qg = (
-        q.reshape(b, t, hkv, groups, d)
-        .transpose(0, 2, 3, 1, 4)
-        .reshape(b * hkv * groups, t, d)
-    )
-    kg = (
-        k.transpose(0, 2, 1, 3)[:, :, None]
-        .repeat(groups, 2)
-        .reshape(b * hkv * groups, s, d)
-    )
-    vg = (
-        v.transpose(0, 2, 1, 3)[:, :, None]
-        .repeat(groups, 2)
-        .reshape(b * hkv * groups, s, d)
-    )
-
-    out = _flash_grouped(qg, kg, vg, causal, block_q, block_k, interpret)
-    return (
-        out.reshape(b, hkv, groups, t, d)
-        .transpose(0, 3, 1, 2, 4)
-        .reshape(b, t, h, d)
-    )
+    return _flash(q, k, v, causal, block_q, block_k, interpret)

@@ -15,9 +15,12 @@ over the slot's table entries; each iteration dynamically indexes one
 running max/sum/output.
 
 VMEM note: the BlockSpec stages one HEAD's pool slice
-(n_pages·page·head_dim elements) per program — with the engine defaults
-(256 pages × 16 × 64 × bf16 ≈ 512 KB) this fits VMEM comfortably. Pools
-larger than VMEM need the HBM-resident variant with explicit page DMA
+(n_pages·page·head_dim elements) per program, for K and for V, each
+double-buffered by the pipeline — with the engine defaults (256 pages ×
+16 × 64 × bf16 ≈ 512 KB a slice) this fits comfortably; ``staged_vmem_bytes``
+is that sum and ``SCOPED_VMEM_BYTES`` what Mosaic allows a kernel by
+default (the engine checks one against the other at construction). Larger
+pools need the HBM-resident variant with explicit page DMA
 (make_async_copy); the call signature is layout-compatible.
 
 Numerics are validated against the XLA reference in interpret mode
@@ -32,6 +35,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+#: Mosaic's default scoped-VMEM allowance for one kernel. Compiling for a
+#: described v5e refuses this kernel exactly when ``staged_vmem_bytes``
+#: exceeds it at head_dim 128 ("Scoped allocation with size ... and limit
+#: 16.00M", tests/test_chip_compile.py pins the refusal).
+SCOPED_VMEM_BYTES = 16 * 2**20
+
+
+def staged_vmem_bytes(n_pages: int, page_size: int, head_dim: int, dtype) -> int:
+    """VMEM the kernel's BlockSpecs stage: one head's whole pool slice for
+    K and for V, two pipeline buffers each."""
+    return 4 * n_pages * page_size * head_dim * jnp.dtype(dtype).itemsize
 
 
 def _paged_kernel(

@@ -26,6 +26,11 @@ ACCELERATOR_ENV_VARS = {
     "GPU": "CUDA_VISIBLE_DEVICES",
 }
 
+# resource name -> the JAX platform its chips belong to. Workers are
+# spawned on the CPU platform (cluster/agent.py ``_worker_env``); only a
+# lease that assigns chips lifts that to "<platform>,cpu".
+ACCELERATOR_PLATFORMS = {"TPU": "tpu", "GPU": "cuda"}
+
 
 class AcceleratorInstanceSet:
     """Index-level free list for one accelerator resource on one node."""
@@ -112,10 +117,15 @@ class NodeAcceleratorState:
     def env_for(assignment: Dict[str, List[Tuple[int, float]]]) -> Dict[str, str]:
         """Render `TPU_VISIBLE_CHIPS` / `CUDA_VISIBLE_DEVICES` for a lease
         (python/ray/_private/accelerators/tpu.py set_current_process_visible
-        analog)."""
+        analog), plus the `JAX_PLATFORMS` that lets the holder open those
+        chips."""
         env: Dict[str, str] = {}
+        platforms: List[str] = []
         for name, a in (assignment or {}).items():
             var = ACCELERATOR_ENV_VARS.get(name)
             if var and a:
                 env[var] = ",".join(str(i) for i, _ in a)
+                platforms.append(ACCELERATOR_PLATFORMS[name])
+        if platforms:
+            env["JAX_PLATFORMS"] = ",".join(platforms + ["cpu"])
         return env

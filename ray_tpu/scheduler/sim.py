@@ -317,6 +317,12 @@ def run_sim(
                 3,
             ),
             "sched_rounds": int(sum(delta)),
+            # every _schedule_batch call, device or host model: equal to
+            # device_stats["rounds"] iff no round ran on the host model
+            "head_sched_rounds": int(head.metrics["sched_rounds"]),
+            "device_platform": (
+                ds.device.platform if ds is not None else None
+            ),
             "device_stats": dict(ds.stats) if ds is not None else None,
             "pipeline_stats": (
                 head._pipeline.stats() if head._pipeline is not None else None

@@ -140,3 +140,69 @@ def test_cluster_actors_disjoint_chips_and_fractional_share():
         set_runtime(None)
         client.shutdown()
         c.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# who may open a chip: workers are CPU processes unless a lease assigns chips
+# ---------------------------------------------------------------------------
+
+
+def test_env_for_lifts_the_platform_with_the_chips():
+    from ray_tpu.scheduler.instances import NodeAcceleratorState
+
+    assert NodeAcceleratorState.env_for({"TPU": [(1, 1.0), (3, 1.0)]}) == {
+        "TPU_VISIBLE_CHIPS": "1,3",
+        "JAX_PLATFORMS": "tpu,cpu",
+    }
+    assert NodeAcceleratorState.env_for({}) == {}
+
+
+class _PlatformActor:
+    def platforms(self):
+        import os
+
+        import jax
+
+        # the variable, what JAX took from it, and that no backend other
+        # than the CPU's was opened to answer
+        return (
+            os.environ.get("JAX_PLATFORMS"),
+            jax.config.jax_platforms,
+            os.environ.get("TPU_VISIBLE_CHIPS"),
+        )
+
+
+def test_workers_get_the_cpu_platform_from_the_spawn_path(monkeypatch):
+    """The head's process may hold the chip; agents are spawned from it
+    and workers from them. A worker must be a CPU process whatever
+    environment the tree inherited — not because JAX_PLATFORMS happened to
+    be set there — and only a lease that carries chips lifts that."""
+    from ray_tpu.cluster import Cluster
+    from ray_tpu.core.runtime import set_runtime
+
+    # what a chip host's shell looks like: no platform named anywhere.
+    # (This process's own JAX was pinned by conftest through jax.config.)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    c = Cluster()
+    c.add_node({"CPU": 8.0, "TPU": 4.0}, num_workers=2)
+    client = c.client()
+    set_runtime(client)
+    try:
+        Actor = ray_tpu.remote(_PlatformActor)
+        plain = Actor.options(num_cpus=1).remote()
+        assert ray_tpu.get(plain.platforms.remote(), timeout=60) == (
+            "cpu", "cpu", None,
+        )
+        # The lease's platform list would make JAX look for a TPU at its
+        # first use; this test asks for the configuration only, so no
+        # backend is opened on a host that has no chip.
+        chips = Actor.options(num_tpus=2, num_cpus=0).remote()
+        env_plat, jax_plat, visible = ray_tpu.get(
+            chips.platforms.remote(), timeout=60
+        )
+        assert (env_plat, jax_plat) == ("tpu,cpu", "tpu,cpu")
+        assert len(visible.split(",")) == 2
+    finally:
+        set_runtime(None)
+        client.shutdown()
+        c.shutdown()

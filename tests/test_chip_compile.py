@@ -1,0 +1,297 @@
+"""The main path's kernels, compiled for a described TPU v5e at real widths.
+
+No chip is attached and nothing runs: the TPU compiler installed here
+compiles for a topology that is only described, and refuses what the
+chip's compiler would refuse (a block that does not tile, more VMEM than a
+kernel may stage, a Mosaic kernel the partitioner cannot split). Interpret
+mode, which every other kernel test uses, sees none of that. A compile
+that passes here is not a chip run; ``chip_smoke.py`` is.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every xdist worker
+imports every test file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _shapes(sharding, *specs):
+    return [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in specs
+    ]
+
+
+def _compile(fn, *args, **static):
+    return jax.jit(fn, static_argnames=tuple(static)).lower(
+        *args, **static
+    ).compile()
+
+
+# -- flash attention: [B, T, H, KH, D] of chip_smoke's train phase -----------
+
+
+@pytest.mark.parametrize("t", [1024, 1023], ids=["T1024", "T1023-ragged"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_flash_attention(one_chip, t, direction):
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    q, k, v = _shapes(one_chip, *[((8, t, 16, 128), jnp.bfloat16)] * 3)
+    if direction == "forward":
+        fn = flash_attention
+        want = 1
+    else:
+        def fn(q, k, v):
+            return jax.grad(
+                lambda *a: flash_attention(*a).astype(jnp.float32).sum(),
+                argnums=(0, 1, 2),
+            )(q, k, v)
+
+        want = 3  # forward for the residuals, dq, dk/dv
+    text = _compile(fn, q, k, v).as_text()
+    assert text.count(f'"{KERNEL}"') == want
+
+
+# -- paged decode -------------------------------------------------------------
+
+
+def _paged_args(sharding, n_pages):
+    # chip_smoke's widths: 8 slots, 16 KV heads x 128, 16-token pages,
+    # max_seq_len 1024 -> 64 table entries a slot
+    return _shapes(
+        sharding,
+        ((8, 16, 1, 128), jnp.bfloat16),
+        ((16, n_pages, 16, 128), jnp.bfloat16),
+        ((16, n_pages, 16, 128), jnp.bfloat16),
+        ((8, 64), jnp.int32),
+        ((8,), jnp.int32),
+    )
+
+
+def test_paged_decode_at_the_smoke_geometry(one_chip):
+    from ray_tpu.ops.paged_attention import paged_attention_decode
+
+    text = _compile(
+        paged_attention_decode, *_paged_args(one_chip, 256), page_size=16
+    ).as_text()
+    assert KERNEL in text
+
+
+def test_paged_decode_refuses_a_deployment_sized_pool(one_chip):
+    """Today's limit, pinned: the kernel stages one head's whole pool slice
+    in VMEM, so a 4,096-page pool (64 MiB staged against 16 MiB) does not
+    compile. The PR that makes the kernel walk pages in HBM flips this
+    test — and ``ContinuousBatchingEngine``'s construction check with it."""
+    from ray_tpu.ops import paged_attention as pa
+
+    assert pa.staged_vmem_bytes(4096, 16, 128, jnp.bfloat16) == 64 * 2**20
+    assert pa.staged_vmem_bytes(1024, 16, 128, jnp.bfloat16) == (
+        pa.SCOPED_VMEM_BYTES
+    )
+    with pytest.raises(Exception, match="vmem"):
+        _compile(
+            pa.paged_attention_decode,
+            *_paged_args(one_chip, 4096),
+            page_size=16,
+        )
+    # and the largest pool the engine's check lets through does compile
+    _compile(
+        pa.paged_attention_decode, *_paged_args(one_chip, 1024), page_size=16
+    )
+
+
+# -- scheduler kernels: the head's real round ---------------------------------
+
+N, R, B, U = 1024, 16, 4096, 8  # nodes, resources, sched_max_batch, shapes
+
+
+def _cluster(sharding):
+    return _shapes(
+        sharding,
+        ((N, R), jnp.float32),   # totals
+        ((N, R), jnp.float32),   # avail
+        ((N,), jnp.bool_),       # alive
+        ((N,), jnp.int32),       # node types
+        ((1, R), jnp.float32),   # per-type throughput
+    )
+
+
+@pytest.mark.parametrize("preempt", [False, True], ids=["plain", "preempt"])
+def test_waterfall_round(one_chip, preempt):
+    """The jitted round exactly as DeviceSchedulerState dispatches it."""
+    from ray_tpu.scheduler.device import _jitted_fns, score_weights_from_cfg
+
+    kernel = _jitted_fns()[0]
+    demand = _shapes(
+        one_chip,
+        ((U, R), jnp.float32),
+        ((B,), jnp.int32),
+        ((U,), jnp.float32),
+        ((), jnp.uint32),
+    )
+    kernel.lower(
+        *_cluster(one_chip), *demand,
+        spread_threshold=0.5, weights=score_weights_from_cfg(),
+        preempt=preempt, explain=False,
+    ).compile()
+
+
+def test_ring_round(one_chip):
+    from ray_tpu.scheduler.device import _jitted_fns, score_weights_from_cfg
+
+    slots = 64  # sched_ring_slots
+    ring = _shapes(
+        one_chip,
+        ((slots, R), jnp.float32),
+        ((slots,), jnp.int32),
+        ((slots,), jnp.float32),
+        ((), jnp.uint32),
+    )
+    _jitted_fns()[2].lower(
+        *_cluster(one_chip), *ring,
+        spread_threshold=0.5, weights=score_weights_from_cfg(), preempt=True,
+    ).compile()
+
+
+def test_shape_slots(one_chip):
+    from ray_tpu.scheduler.device import _jitted_fns
+
+    totals, avail, alive = _cluster(one_chip)[:3]
+    (shapes,) = _shapes(one_chip, ((64, R), jnp.float32))
+    _jitted_fns()[3].lower(totals, avail, alive, shapes).compile()
+
+
+def test_elasticity_solve(one_chip):
+    """``elastic_pack_solve`` at chip_smoke's 8,192 nodes x 1,024 shapes."""
+    from ray_tpu.scheduler.binpack import solve_pack_counts
+
+    args = _shapes(
+        one_chip,
+        ((8192, R), jnp.float32),
+        ((1024, R), jnp.float32),
+        ((1024,), jnp.float32),
+    )
+    solve_pack_counts.lower(*args, iters=24).compile()
+
+
+@pytest.mark.parametrize("strategy", ["PACK", "SPREAD", "STRICT_SPREAD"])
+def test_bundle_kernels(one_chip, strategy):
+    from ray_tpu.scheduler import bundles
+
+    totals, avail, alive = _cluster(one_chip)[:3]
+    (mat,) = _shapes(one_chip, ((8, R), jnp.float32))
+    if strategy == "PACK":
+        bundles.pack_bundles.lower(totals, avail, alive, mat).compile()
+    else:
+        bundles.spread_bundles.lower(
+            totals, avail, alive, mat, strict=strategy == "STRICT_SPREAD"
+        ).compile()
+
+
+# -- the train step under a mesh ----------------------------------------------
+
+
+@pytest.mark.parametrize("mesh_name", ["dp2_tp2", "pp2_tp2"])
+def test_train_step_keeps_the_flash_kernels_under_a_mesh(
+    topo, mesh_name, monkeypatch
+):
+    """A Mosaic kernel cannot be split by the partitioner, and autodiff
+    cannot carry residuals out of a shard_map nested in the pipeline's:
+    ``transformer._causal_attention`` spells both out, and
+    ``make_train_step`` pins what the step returns. Small widths — the
+    structure is what is compiled here; chip_smoke.py --multichip runs it
+    at full width."""
+    import optax
+
+    from ray_tpu.models import transformer as tfm
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    # the model asks the attached backend, which is the CPU here
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mc = {
+        "dp2_tp2": MeshConfig(dp=2, tp=2),
+        "pp2_tp2": MeshConfig(pp=2, tp=2),
+    }[mesh_name]
+    mesh = build_mesh(mc, topo.devices)
+    cfg = tfm.ModelConfig(
+        vocab_size=1024, d_model=256, n_layers=2, n_heads=4, n_kv_heads=4,
+        d_ff=512, max_seq_len=256, remat=True,
+    )
+    opt = optax.adam(3e-4)
+
+    def on_mesh(tree, specs):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=NamedSharding(mesh, s)
+            ),
+            tree,
+            specs,
+        )
+
+    specs = tfm.param_specs(cfg, mc.pp)
+    params = on_mesh(
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))),
+        specs,
+    )
+    adam, rest = jax.eval_shape(opt.init, params)
+    opt_state = (
+        adam._replace(
+            count=on_mesh(adam.count, jax.sharding.PartitionSpec()),
+            mu=on_mesh(adam.mu, specs),
+            nu=on_mesh(adam.nu, specs),
+        ),
+        rest,
+    )
+    tokens = jax.ShapeDtypeStruct(
+        (4, 256), jnp.int32,
+        sharding=NamedSharding(mesh, jax.sharding.PartitionSpec("dp", None)),
+    )
+    step = jax.jit(
+        tfm.make_train_step(
+            cfg, opt, mesh, num_microbatches=2 * mc.pp if mc.pp > 1 else 0
+        )
+    )
+    compiled = step.lower(params, opt_state, tokens).compile()
+    # forward, its remat copy, dq, dk/dv
+    assert compiled.as_text().count(f'"{KERNEL}"') >= 3
+    # parameters and optimizer state leave the step sharded as they
+    # entered (left open, this compiler splits the norm scales over tp)
+    out_params, out_state, _ = compiled.output_shardings
+    for want, got in zip(
+        jax.tree.leaves((params, opt_state)),
+        jax.tree.leaves((out_params, out_state)),
+    ):
+        assert got.is_equivalent_to(want.sharding, len(want.shape))

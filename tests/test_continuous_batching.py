@@ -135,3 +135,60 @@ def test_pallas_attention_matches_gather_path(small):
     assert pallas.generate_ids(prompts, gen) == base.generate_ids(
         prompts, gen
     )
+
+
+def test_concurrent_callers_share_one_engine(small):
+    """A serve replica runs several requests at once, each thread driving
+    step() until its own answer is there. More threads than slots (and
+    than cores), a shortened switch interval: two threads inside _admit at
+    once would hand one slot to two requests and lose one of them, and its
+    caller would never return."""
+    import sys
+    import threading
+    import time
+
+    cfg, params = small
+    gen = GenerationConfig(max_new_tokens=10, temperature=0.0)
+    prompts = [[1 + (i * 7 + j) % 90 for j in range(3 + i)] for i in range(12)]
+    want = ContinuousBatchingEngine(
+        cfg, params, max_batch=3, page_size=8, n_pages=64
+    ).generate_ids(prompts, gen)
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_batch=3, page_size=8, n_pages=64
+    )
+    got = [None] * len(prompts)
+
+    def call(i):
+        got[i] = eng.generate_ids([prompts[i]], gen)[0]
+
+    threads = [
+        threading.Thread(target=call, args=(i,), daemon=True)
+        for i in range(len(prompts))
+    ]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 120
+        for t in threads:
+            t.join(timeout=max(0.0, deadline - time.monotonic()))
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads), "a caller never returned"
+    assert got == want
+    assert eng.pool.free_pages == eng.pool.usable_pages
+
+
+def test_pallas_pool_beyond_vmem_raises_at_construction(small):
+    """The paged-decode kernel stages one head's whole pool slice in VMEM:
+    a pool past that is refused where it is asked for, with the sizes,
+    not by the compiler at the first decode step."""
+    cfg = tfm.ModelConfig(
+        vocab_size=96, d_model=2048, n_layers=1, n_heads=16, n_kv_heads=16,
+        d_ff=64, max_seq_len=128,
+    )  # head_dim 128, bf16
+    with pytest.raises(ValueError, match=r"64\.0 MiB for n_pages=4096.*16 MiB"):
+        ContinuousBatchingEngine(
+            cfg, params={}, n_pages=4096, use_pallas_attention=True
+        )

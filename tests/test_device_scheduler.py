@@ -1,7 +1,7 @@
 """The device-resident XLA scheduler as the live runtime's default path.
 
-VERDICT r1 item 1: the kernels must be the product scheduler, state resident
-on the scheduler device with delta sync, and no prefer-row hotspot (weak-5).
+The kernels must be the product scheduler, state resident on the scheduler
+device with delta sync, and no prefer-row hotspot.
 """
 import numpy as np
 import pytest
@@ -85,7 +85,7 @@ def test_no_node_zero_hotspot():
     st.sync(view)
     d = dense(vocab, view, {"CPU": 1.0})
     counts = np.zeros(8, dtype=int)
-    # many single-request rounds — the pathological case from VERDICT
+    # many single-request rounds — the pathological case
     for _ in range(48):
         row = int(st.schedule(np.stack([d]))[0])
         counts[row] += 1
@@ -119,3 +119,59 @@ def test_device_matches_golden_capacity():
     assert placed.shape[0] == 6  # 3 nodes x 2 CPU
     binc = np.bincount(placed, minlength=3)
     assert binc.max() <= 2
+
+
+# ---------------------------------------------------------------------------
+# no fallback hides the device
+# ---------------------------------------------------------------------------
+
+
+def test_named_platform_that_is_absent_is_an_error(monkeypatch):
+    """RAY_TPU_SCHED_PLATFORM=tpu where there is no TPU: the scheduler
+    raises, every time; it does not move to the CPU or the host model."""
+    from ray_tpu.scheduler.device import LazyDeviceState
+
+    with pytest.raises(RuntimeError):
+        DeviceSchedulerState(platform="tpu")
+    monkeypatch.setenv("RAY_TPU_SCHED_PLATFORM", "tpu")
+    lazy = LazyDeviceState(True)
+    with pytest.raises(RuntimeError) as first:
+        lazy.get()
+    with pytest.raises(RuntimeError) as again:
+        lazy.get()
+    assert again.value is first.value and lazy._result is None
+    # off means off: the NumPy golden model, by the caller's choice
+    assert LazyDeviceState(False).get() is None
+
+
+@pytest.mark.parametrize("from_env", [True, False], ids=["env", "checkout"])
+def test_compile_cache_is_placed_from_outside(from_env, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR decides; unset, the cache is one fixed
+    directory inside the checkout. A fresh process: the helper runs once."""
+    import os
+    import subprocess
+    import sys
+
+    from ray_tpu.util import compile_cache
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run(
+        [
+            sys.executable, "-c",
+            "import jax\n"
+            "from ray_tpu.scheduler.device import DeviceSchedulerState\n"
+            "DeviceSchedulerState()\n"
+            "print(jax.config.jax_compilation_cache_dir)\n",
+        ],
+        capture_output=True, text=True, timeout=120, cwd=repo, env=env,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    want = str(tmp_path) if from_env else compile_cache.DEFAULT_DIR
+    assert out.stdout.strip().splitlines()[-1] == want
+    assert compile_cache.DEFAULT_DIR == os.path.join(
+        repo, ".jax_compile_cache"
+    )

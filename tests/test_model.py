@@ -113,6 +113,31 @@ def test_pp_pipeline_matches_dense(setup):
     )
 
 
+@pytest.mark.parametrize(
+    "mesh_cfg",
+    [MeshConfig(dp=2, tp=2), MeshConfig(pp=2, tp=2)],
+    ids=["dp2_tp2", "pp2_tp2"],
+)
+def test_tp_meshes_match_one_device_loss_and_grads(setup, devices8, mesh_cfg):
+    """The two meshes chip_smoke.py --multichip takes to four chips: the
+    loss and every gradient equal the unsharded ones."""
+    params, _, _ = setup
+    toks = jax.random.randint(jax.random.PRNGKey(5), (8, 17), 0, CFG.vocab_size)
+    want_l, want_g = jax.value_and_grad(tfm.loss_fn)(params, toks, CFG)
+    mesh = build_mesh(mesh_cfg, devices8[:4])
+    mbs = 2 * mesh_cfg.pp if mesh_cfg.pp > 1 else 0
+    got_l, got_g = jax.jit(
+        jax.value_and_grad(
+            lambda p, t: tfm.loss_fn(p, t, CFG, mesh, num_microbatches=mbs)
+        )
+    )(tfm.shard_params(params, CFG, mesh), toks)
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-5)
+    for got, want in zip(jax.tree.leaves(got_g), jax.tree.leaves(want_g)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-4
+        )
+
+
 def test_full_mesh_train_step_runs_and_matches(devices8):
     mesh = build_mesh(MeshConfig(dp=2, pp=2, sp=2), devices8)
     params = tfm.init_params(CFG, jax.random.PRNGKey(0))
