@@ -31,6 +31,8 @@ import uuid
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu.util import tracing
+
 from .object_store import ObjectRef, TaskError
 
 DEFAULT_MAX_CONCURRENCY_ASYNC = 1000
@@ -266,6 +268,12 @@ class ActorState:
                 "attempt": 0,
                 "group": group,
                 "stream_tid": stream_tid,
+                # the submitter's trace, installed around execution as
+                # for a task (runtime._execute), so a method call's
+                # nested submissions and spans stay in the caller's trace
+                "trace": tracing.child_context(
+                    stream_tid or (returns[0].hex if returns else "")
+                ),
             }
             if self.is_async and self.alive:
                 self._dispatch_async(call)
@@ -317,6 +325,9 @@ class ActorState:
         ctx = get_context()
         ctx.node_id = self.node_id
         ctx.actor_id = self.actor_id
+        # this coroutine is its own asyncio task and a task's context is
+        # its own copy: nothing to restore at the end
+        tracing.install(call.get("trace"))
         try:
             args, kwargs = self.runtime._resolve_args(call["args"], call["kwargs"])
             fn = getattr(instance, call["method"])
@@ -326,6 +337,11 @@ class ActorState:
             if should_await(result):
                 result = await result
             self._seal_result(call, result)
+        except GeneratorExit:
+            # a coroutine left parked on a stopped loop (the actor died)
+            # is being collected: the death path owns its call, and the
+            # same call dict may already be in flight again, redelivered
+            raise
         except BaseException as exc:  # noqa: BLE001
             self._seal_failure(call, exc)
         finally:
@@ -338,6 +354,7 @@ class ActorState:
         ctx = get_context()
         ctx.node_id = self.node_id
         ctx.actor_id = self.actor_id
+        trace_token = tracing.install(call.get("trace"))
         try:
             if call.get("stream_tid"):
                 # num_returns="streaming" method: the generator drives the
@@ -386,6 +403,7 @@ class ActorState:
         except BaseException as exc:  # noqa: BLE001
             self._seal_failure(call, exc)
         finally:
+            tracing.uninstall(trace_token)
             ctx.node_id = None
             ctx.actor_id = None
 

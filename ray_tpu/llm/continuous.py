@@ -39,6 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import transformer as tfm
+from ray_tpu.util import tracing
 from ray_tpu.util.compile_cache import configure_compile_cache
 
 from .engine import ByteTokenizer, GenerationConfig
@@ -57,9 +58,21 @@ class _Slot:
 
 @dataclass
 class _Request:
+    """A request from ``submit()``/``adopt_pages()`` to its end. ``span``
+    is its ``engine.request`` span, open all that time; the times are
+    ``perf_counter`` stamps and the lock counters are summed over every
+    acquisition of the engine lock in ``stream_rid``."""
+
     req_id: int
     prompt: List[int]
     gen: GenerationConfig
+    span: Any = None
+    t_submit: float = 0.0
+    t_admit: float = 0.0
+    t_first: float = 0.0
+    lock_wait_s: float = 0.0
+    lock_wait_max_s: float = 0.0
+    lock_acquires: int = 0
 
 
 class PagedKVPool:
@@ -210,7 +223,15 @@ class ContinuousBatchingEngine:
         self.slots = [_Slot() for _ in range(self.B)]
         self.queue: deque = deque()
         self.results: Dict[int, List[int]] = {}
+        # every request the engine has not ended yet, queued or in a slot
+        self._live: Dict[int, _Request] = {}
         self._next_req = 0
+        # running totals behind stats(): the operator's view of what the
+        # engine.admit / engine.request spans carry one by one
+        self.admit_pool_stalls = 0
+        self.lock_wait_s = 0.0
+        self.lock_acquires = 0
+        self.queue_wait_s = 0.0
         # disaggregated serving (PR 18): which weights this engine runs,
         # bumped by swap_params; manifests stamp both so a decode engine
         # never grafts KV computed under different weights
@@ -593,10 +614,39 @@ class ContinuousBatchingEngine:
                 f"but max_pages_per_seq={self.max_pages_per_seq} "
                 f"(page_size={self.page})"
             )
-        rid = self._next_req
+        req = self._begin_request(list(prompt), gen)
+        self.queue.append(req)
+        return req.req_id
+
+    def _begin_request(self, prompt: List[int], gen: GenerationConfig):
+        req = _Request(self._next_req, prompt, gen)
         self._next_req += 1
-        self.queue.append(_Request(rid, list(prompt), gen))
-        return rid
+        req.span = tracing.span(
+            "engine.request", "engine", rid=req.req_id,
+            prompt_tokens=len(prompt),
+        ).begin()
+        req.t_submit = time.perf_counter()
+        self._live[req.req_id] = req
+        return req
+
+    def _end_request(self, rid: int, end: str, new_tokens: int) -> None:
+        """The engine is done with ``rid`` (``end``: finished, cancelled
+        or evicted): close its ``engine.request`` span."""
+        req = self._live.pop(rid, None)
+        if req is None:
+            return
+        admitted = req.t_admit or time.perf_counter()
+        req.span.end(
+            new_tokens=new_tokens,
+            queue_wait_ms=(admitted - req.t_submit) * 1e3,
+            prefill_ms=(
+                (req.t_first - req.t_admit) * 1e3 if req.t_first else 0.0
+            ),
+            lock_wait_ms=req.lock_wait_s * 1e3,
+            lock_wait_max_ms=req.lock_wait_max_s * 1e3,
+            lock_acquires=req.lock_acquires,
+            end=end,
+        )
 
     def _pages_needed(self, req: _Request) -> int:
         total = len(req.prompt) + req.gen.max_new_tokens
@@ -609,6 +659,19 @@ class ContinuousBatchingEngine:
             # weights-epoch, the queue stays parked until the new
             # weights are installed — no request ever mixes epochs
             return
+        live = sum(s.active for s in self.slots)
+        if not self.queue or live == self.B:
+            return
+        with tracing.span("engine.admit", "engine", live=live) as sp:
+            admitted, pool_stall = self._admit_queued()
+            self.admit_pool_stalls += pool_stall
+            sp.set(admitted=admitted, pool_stall=pool_stall)
+
+    def _admit_queued(self):
+        """``_admit``'s loop. Returns how many requests it admitted and
+        whether the pool stalled it (1: a free slot and a queued request,
+        and ``alloc`` gave ``None``; else 0)."""
+        admitted = 0
         for si, slot in enumerate(self.slots):
             if slot.active or not self.queue:
                 continue
@@ -616,8 +679,11 @@ class ContinuousBatchingEngine:
             need = min(self._pages_needed(req), self.max_pages_per_seq)
             pages = self.pool.alloc(need)
             if pages is None:
-                break  # backpressure: the POOL is the capacity
+                return admitted, 1  # backpressure: the POOL is the capacity
             self.queue.popleft()
+            admitted += 1
+            req.t_admit = time.perf_counter()
+            self.queue_wait_s += req.t_admit - req.t_submit
             prompt = req.prompt
             t = len(prompt)
             # shared prefix cache: restore the longest cached page-aligned
@@ -633,20 +699,7 @@ class ContinuousBatchingEngine:
             if hit is not None:
                 last_logits = self._admit_with_prefix(req, pages, table, hit)
             else:
-                t_pad = max(self.page, -(-t // self.page) * self.page)
-                prompt_pages = t_pad // self.page
-                tokens = np.zeros(t_pad, np.int32)
-                tokens[:t] = prompt
-                logits, self.pool.k, self.pool.v = self._prefill(
-                    self.params,
-                    self.pool.k,
-                    self.pool.v,
-                    jnp.asarray(tokens),
-                    t_pad,
-                    jnp.asarray(pages[:prompt_pages], dtype=jnp.int32),
-                )
-                last_logits = logits[t - 1]
-                self.full_prefill_count += 1
+                last_logits = self._prefill_prompt(prompt, pages)
             if self.prefix_cache is not None:
                 # publish this prompt's full pages for other replicas
                 # (reads the pool AFTER prefill wrote it — the np gather
@@ -655,6 +708,7 @@ class ContinuousBatchingEngine:
                     prompt, pages, hit.tokens if hit is not None else 0
                 )
             first = self._sample_first(req.gen, last_logits, t)
+            req.t_first = time.perf_counter()
             if hit is not None:
                 # np conversions above synced every consumer of the
                 # pinned views; dropping them releases the arena pin
@@ -683,6 +737,27 @@ class ContinuousBatchingEngine:
                 np.uint32(req.gen.seed & 0xFFFFFFFF)
             )
             self._maybe_finish(si)
+        return admitted, 0
+
+    def _prefill_prompt(self, prompt, pages):
+        """Run the prefill program over the whole (padded) prompt, its KV
+        written into the first of ``pages``. Returns the last real
+        token's logits."""
+        t = len(prompt)
+        t_pad = max(self.page, -(-t // self.page) * self.page)
+        tokens = np.zeros(t_pad, np.int32)
+        tokens[:t] = prompt
+        with tracing.span("engine.prefill", "engine", t_pad=t_pad, hit_tokens=0):
+            logits, self.pool.k, self.pool.v = self._prefill(
+                self.params,
+                self.pool.k,
+                self.pool.v,
+                jnp.asarray(tokens),
+                t_pad,
+                jnp.asarray(pages[: t_pad // self.page], dtype=jnp.int32),
+            )
+        self.full_prefill_count += 1
+        return logits[t - 1]
 
     def _admit_with_prefix(self, req, pages, table, hit):
         """Cache-hit admission: copy the pinned KV views into this
@@ -709,69 +784,77 @@ class ContinuousBatchingEngine:
         suffix_pages = t_pad // self.page
         tokens = np.zeros(t_pad, np.int32)
         tokens[:ts] = suffix
-        logits, self.pool.k, self.pool.v = self._prefill_suffix(
-            self.params,
-            self.pool.k,
-            self.pool.v,
-            jnp.asarray(tokens),
-            t_pad,
-            jnp.int32(hit.tokens),
-            jnp.asarray(table),
-            jnp.asarray(
-                pages[hist_pages : hist_pages + suffix_pages],
-                dtype=jnp.int32,
-            ),
-        )
+        with tracing.span(
+            "engine.prefill", "engine", t_pad=t_pad,
+            hit_tokens=int(hit.tokens),
+        ):
+            logits, self.pool.k, self.pool.v = self._prefill_suffix(
+                self.params,
+                self.pool.k,
+                self.pool.v,
+                jnp.asarray(tokens),
+                t_pad,
+                jnp.int32(hit.tokens),
+                jnp.asarray(table),
+                jnp.asarray(
+                    pages[hist_pages : hist_pages + suffix_pages],
+                    dtype=jnp.int32,
+                ),
+            )
         return logits[ts - 1]
 
     def _prefix_insert(self, prompt, pages, covered: int) -> None:
         """Publish the prompt's FULL pages (already in the pool) to the
         shared cache — skipped when the hit already covered them."""
-        ins = (len(prompt) // self.page) * self.page
-        if ins <= covered or ins == 0:
-            return
-        n_pages = ins // self.page
-        if n_pages > len(pages):
-            return
-        if getattr(self.prefix_cache, "contains_prefix", None) and (
-            self.prefix_cache.contains_prefix(prompt[:ins])
-        ):
-            # already published (hot prompt): skip the device→host KV
-            # gather entirely — it's a blocking sync on the admit path
-            return
-        dev = jnp.asarray(pages[:n_pages], dtype=jnp.int32)
-        from ray_tpu.cluster import device_plane as _dp
+        with tracing.span("engine.prefix_insert", "engine", pages=0) as sp:
+            ins = (len(prompt) // self.page) * self.page
+            if ins <= covered or ins == 0:
+                return
+            n_pages = ins // self.page
+            if n_pages > len(pages):
+                return
+            if getattr(self.prefix_cache, "contains_prefix", None) and (
+                self.prefix_cache.contains_prefix(prompt[:ins])
+            ):
+                # already published (hot prompt): skip the device→host KV
+                # gather entirely — it's a blocking sync on the admit path
+                return
+            dev = jnp.asarray(pages[:n_pages], dtype=jnp.int32)
+            from ray_tpu.cluster import device_plane as _dp
 
-        if _dp.device_plane_enabled():
-            # the gathered KV block stays a device buffer: the cache's
-            # seal exports it as a device frame (zero-copy where the
-            # backend aliases host memory, chunked D2H pump elsewhere) —
-            # the eager np.asarray device→host sync is gone from the
-            # admit path, and lookups on the other side land the pages
-            # back on device with one device_put
-            k = self.pool.k[:, :, dev]
-            v = self.pool.v[:, :, dev]
-        else:
-            k = np.asarray(self.pool.k[:, :, dev])
-            v = np.asarray(self.pool.v[:, :, dev])
-        self.prefix_cache.insert(prompt[:ins], k, v)
+            if _dp.device_plane_enabled():
+                # the gathered KV block stays a device buffer: the cache's
+                # seal exports it as a device frame (zero-copy where the
+                # backend aliases host memory, chunked D2H pump elsewhere)
+                # — the eager np.asarray device→host sync is gone from the
+                # admit path, and lookups on the other side land the pages
+                # back on device with one device_put
+                k = self.pool.k[:, :, dev]
+                v = self.pool.v[:, :, dev]
+            else:
+                k = np.asarray(self.pool.k[:, :, dev])
+                v = np.asarray(self.pool.v[:, :, dev])
+            self.prefix_cache.insert(prompt[:ins], k, v)
+            sp.set(pages=n_pages)
 
     def _sample_first(self, gen: GenerationConfig, last_logits, t: int) -> int:
-        if gen.temperature > 0.0:
-            # same uint32 normalization as the decode path — one key
-            # stream per request across prefill and decode
-            kk = jax.random.fold_in(
-                jax.random.PRNGKey(np.uint32(gen.seed & 0xFFFFFFFF)),
-                t,
-            )
-            return int(
-                jax.random.categorical(
-                    kk,
-                    jnp.asarray(last_logits)
-                    / max(gen.temperature, 1e-6),
+        # the int() below is where the host waits for the prefill's logits
+        with tracing.span("engine.first_token", "engine"):
+            if gen.temperature > 0.0:
+                # same uint32 normalization as the decode path — one key
+                # stream per request across prefill and decode
+                kk = jax.random.fold_in(
+                    jax.random.PRNGKey(np.uint32(gen.seed & 0xFFFFFFFF)),
+                    t,
                 )
-            )
-        return int(np.asarray(last_logits).argmax())
+                return int(
+                    jax.random.categorical(
+                        kk,
+                        jnp.asarray(last_logits)
+                        / max(gen.temperature, 1e-6),
+                    )
+                )
+            return int(np.asarray(last_logits).argmax())
 
     # ------------------------------------------------------------------
     # disaggregated serving: prefill/decode split (PR 18)
@@ -806,18 +889,9 @@ class ContinuousBatchingEngine:
                 f"(free={self.pool.free_pages}, need={prompt_pages})"
             )
         try:
-            tokens = np.zeros(t_pad, np.int32)
-            tokens[:t] = prompt
-            logits, self.pool.k, self.pool.v = self._prefill(
-                self.params,
-                self.pool.k,
-                self.pool.v,
-                jnp.asarray(tokens),
-                t_pad,
-                jnp.asarray(pages, dtype=jnp.int32),
+            first = self._sample_first(
+                gen, self._prefill_prompt(prompt, pages), t
             )
-            self.full_prefill_count += 1
-            first = self._sample_first(gen, logits[t - 1], t)
             dev = jnp.asarray(pages, dtype=jnp.int32)
             from ray_tpu.cluster import device_plane as _dp
 
@@ -883,8 +957,10 @@ class ContinuousBatchingEngine:
         pages = self.pool.alloc(need)
         if pages is None:
             return None  # pool backpressure: the POOL is the capacity
-        rid = self._next_req
-        self._next_req += 1
+        req = self._begin_request(prompt, gen)
+        # grafted mid-batch: no queue, no prefill here
+        req.t_admit = req.t_first = req.t_submit
+        rid = req.req_id
         dev = jnp.asarray(pages[:ship_pages], dtype=jnp.int32)
         if isinstance(k, np.ndarray):
             k = jnp.asarray(k)
@@ -983,6 +1059,7 @@ class ContinuousBatchingEngine:
             if slot.eos is not None and slot.eos in out:
                 out = out[: out.index(slot.eos)]
             self.results[slot.req_id] = out
+            self._end_request(slot.req_id, "evicted", len(slot.out))
             self.pool.free(slot.pages)
             self.slots[si] = _Slot()
             self.active_mask = self.active_mask.at[si].set(False)
@@ -999,6 +1076,7 @@ class ContinuousBatchingEngine:
             if slot.eos is not None and slot.eos in out:
                 out = out[: out.index(slot.eos)]
             self.results[slot.req_id] = out
+            self._end_request(slot.req_id, "finished", len(slot.out))
             self.pool.free(slot.pages)
             self.slots[si] = _Slot()
             self.active_mask = self.active_mask.at[si].set(False)
@@ -1007,30 +1085,49 @@ class ContinuousBatchingEngine:
     def step(self) -> List[int]:
         """Admit + one decode step for all active slots. Returns req_ids
         finished in this step."""
-        self._admit()
-        before = set(self.results)
-        if any(s.active for s in self.slots):
-            nxt, self.pool.k, self.pool.v = self._decode_step(
-                self.params,
-                self.pool.k,
-                self.pool.v,
-                self.block_tables,
-                self.positions,
-                self.cur_tokens,
-                self.active_mask,
-                self.temps,
-                self.seeds,
-            )
-            nxt_h = np.asarray(nxt)
-            self.positions = self.positions + jnp.where(self.active_mask, 1, 0)
-            self.cur_tokens = nxt
-            for si, slot in enumerate(self.slots):
-                if not slot.active:
-                    continue
-                slot.pos += 1
-                slot.out.append(int(nxt_h[si]))
-                self._maybe_finish(si)
-        return [r for r in self.results if r not in before]
+        with tracing.span("engine.step", "engine"):
+            self._admit()
+            before = set(self.results)
+            live = [s for s in self.slots if s.active]
+            if live:
+                decode = tracing.span("engine.decode", "engine")
+                if decode:
+                    page = self.page
+                    decode.set(
+                        live=len(live),
+                        ctx=sum(s.pos + 1 for s in live),
+                        pages_reserved=sum(len(s.pages) for s in live),
+                        pages_written=sum(
+                            -(-(s.pos + 1) // page) for s in live
+                        ),
+                        queued=len(self.queue),
+                    )
+                with decode:
+                    nxt, self.pool.k, self.pool.v = self._decode_step(
+                        self.params,
+                        self.pool.k,
+                        self.pool.v,
+                        self.block_tables,
+                        self.positions,
+                        self.cur_tokens,
+                        self.active_mask,
+                        self.temps,
+                        self.seeds,
+                    )
+                # where the host waits for the step's tokens
+                with tracing.span("engine.readback", "engine"):
+                    nxt_h = np.asarray(nxt)
+                self.positions = self.positions + jnp.where(
+                    self.active_mask, 1, 0
+                )
+                self.cur_tokens = nxt
+                for si, slot in enumerate(self.slots):
+                    if not slot.active:
+                        continue
+                    slot.pos += 1
+                    slot.out.append(int(nxt_h[si]))
+                    self._maybe_finish(si)
+            return [r for r in self.results if r not in before]
 
     def pending(self) -> int:
         return len(self.queue) + sum(s.active for s in self.slots)
@@ -1064,9 +1161,12 @@ class ContinuousBatchingEngine:
         ``adopt_pages()`` (the disaggregated handoff path, where no
         local prefill ever runs)."""
         yielded = 0
+        req = self._live.get(rid)
         try:
             while True:
+                t_wait = time.perf_counter()
                 with self._lock:
+                    self._note_lock_wait(req, time.perf_counter() - t_wait)
                     if rid in self.results:
                         break
                     self.step()
@@ -1092,6 +1192,16 @@ class ContinuousBatchingEngine:
             # stop burning decode steps on a dead client
             self._cancel(rid)
 
+    def _note_lock_wait(self, req: Optional[_Request], waited: float) -> None:
+        """One acquisition of the engine lock by a request's stream (the
+        caller holds the lock): how long its thread waited for it."""
+        self.lock_wait_s += waited
+        self.lock_acquires += 1
+        if req is not None:
+            req.lock_wait_s += waited
+            req.lock_acquires += 1
+            req.lock_wait_max_s = max(req.lock_wait_max_s, waited)
+
     @_locked
     def _cancel(self, rid: int) -> None:
         """Drop a request wherever it is: queued, active, or finished."""
@@ -1099,9 +1209,11 @@ class ContinuousBatchingEngine:
         for i, req in enumerate(self.queue):
             if req.req_id == rid:
                 del self.queue[i]
+                self._end_request(rid, "cancelled", 0)
                 return
         for si, slot in enumerate(self.slots):
             if slot.active and slot.req_id == rid:
+                self._end_request(rid, "cancelled", len(slot.out))
                 self.pool.free(slot.pages)
                 self.slots[si] = _Slot()
                 self.active_mask = self.active_mask.at[si].set(False)
@@ -1134,6 +1246,10 @@ class ContinuousBatchingEngine:
             "full_prefill_count": self.full_prefill_count,
             "adopted_count": self.adopted_count,
             "swap_force_evicted": self.swap_force_evicted,
+            "admit_pool_stalls": self.admit_pool_stalls,
+            "lock_wait_s": self.lock_wait_s,
+            "lock_acquires": self.lock_acquires,
+            "queue_wait_s": self.queue_wait_s,
         }
         if self.prefix_cache is not None:
             out["prefix_cache"] = self.prefix_cache.stats()

@@ -37,6 +37,7 @@ from typing import Any, Dict, Optional
 
 import ray_tpu
 import ray_tpu.serve as serve
+from ray_tpu.util import tracing
 from .engine import GenerationConfig, LLMEngine
 
 
@@ -329,32 +330,48 @@ def build_llm_deployment(
             self._ensure_model(request)
             gen = _gen_from_request(request)
             skip = max(0, int(request.get("resume_from", 0)))
-            prompt = self.engine.tokenizer.encode(request["prompt"])
-            # disaggregated handoff: graft the prefill worker's KV pages
-            # and stream from the adopted slot — no local prefill. Any
-            # handoff failure falls through to stream_ids (local
-            # re-prefill), the same path a resume_from failover takes.
-            rid = None
-            handoff = (
-                request.get("handoff")
-                if isinstance(request, dict)
-                else None
-            )
-            if handoff and not skip and hasattr(self.engine, "adopt_pages"):
-                rid = self._adopt_handoff(handoff)
-            tokens = (
-                self.engine.stream_rid(rid)
-                if rid is not None
-                else self.engine.stream_ids(prompt, gen)
-            )
-            n = 0
-            for tok in tokens:
-                self._note_first_token()
-                if n >= skip:
-                    writer.write(self.engine.tokenizer.decode([int(tok)]))
-                n += 1
-                self._tokens_out += 1
-            writer.close_channel()
+            with tracing.span(
+                "replica.stream", "engine", pid=f"serve:{name}", skip=skip
+            ) as sp:
+                prompt = self.engine.tokenizer.encode(request["prompt"])
+                # disaggregated handoff: graft the prefill worker's KV
+                # pages and stream from the adopted slot — no local
+                # prefill. Any handoff failure falls through to
+                # stream_ids (local re-prefill), the same path a
+                # resume_from failover takes.
+                rid = None
+                handoff = (
+                    request.get("handoff")
+                    if isinstance(request, dict)
+                    else None
+                )
+                if (
+                    handoff
+                    and not skip
+                    and hasattr(self.engine, "adopt_pages")
+                ):
+                    rid = self._adopt_handoff(handoff)
+                tokens = (
+                    self.engine.stream_rid(rid)
+                    if rid is not None
+                    else self.engine.stream_ids(prompt, gen)
+                )
+                n = 0
+                try:
+                    for tok in tokens:
+                        self._note_first_token()
+                        if n >= skip:
+                            writer.write(
+                                self.engine.tokenizer.decode([int(tok)])
+                            )
+                        n += 1
+                        self._tokens_out += 1
+                finally:
+                    # a consumer gone mid-stream cancels in the engine
+                    # here, not whenever this frame is collected
+                    tokens.close()
+                    sp.set(tokens=n)
+                writer.close_channel()
             return n
 
         # -- online-RL hot-swap (ISSUE 20) -------------------------------

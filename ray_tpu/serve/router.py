@@ -36,6 +36,7 @@ from collections import deque
 from typing import Any, Dict, Optional, Tuple
 
 import ray_tpu
+from ray_tpu.util import tracing
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
 from .admission import AdmissionController, Overloaded, controller_from_cfg
@@ -527,13 +528,21 @@ class RoutedStream:
             {**self._labels, "model": str(self.model)} if self.model else None
         )
         SERVE_STREAMS.inc(labels=self._labels)
+        # one trace for the stream's whole life: every dispatch (the
+        # first and each failover) is submitted under it, so the
+        # replica's and the engine's spans share its id
+        self._trace = tracing.child_context("serve_stream")
         try:
-            self._attach(router._dispatch_stream(payload, self.resume_base))
+            self._dispatch(self.resume_base)
         except BaseException:
             self._finish("500")
             raise
 
-    def _attach(self, dispatched) -> None:
+    def _dispatch(self, resume_from: int) -> None:
+        with tracing.installed(self._trace):
+            dispatched = self._router._dispatch_stream(
+                self._payload, resume_from
+            )
         self._reader, self._ref, self._replica, self._cleanup = dispatched
 
     # -- consumption ----------------------------------------------------
@@ -663,11 +672,7 @@ class RoutedStream:
         # prefix a previous router incarnation delivered): the new
         # replica regenerates deterministically and skips exactly those,
         # so acked deltas are neither repeated nor lost
-        self._attach(
-            self._router._dispatch_stream(
-                self._payload, self.resume_base + self.delivered
-            )
-        )
+        self._dispatch(self.resume_base + self.delivered)
         return True
 
     # -- teardown -------------------------------------------------------
@@ -702,28 +707,24 @@ class RoutedStream:
             SERVE_TPOT_MS.observe(tpot, labels=self._labels)
             if self._mlabels:
                 SERVE_TPOT_MS.observe(tpot, labels=self._mlabels)
-        try:
-            # request-lifecycle span (ISSUE 15): one slice per stream in
-            # the Chrome-trace export, beside the task slices it caused
-            from ray_tpu.util.tracing import SPANS
-
-            SPANS.record(
-                "serve_stream",
-                "serve",
-                self._t0_wall,
-                time.monotonic() - self._t0,
-                pid=f"serve:{self._labels['deployment']}",
-                code=code,
-                delivered=self.delivered,
-                failovers=self.failovers,
-                ttft_ms=(
-                    (self._t_first - self._t0) * 1000.0
-                    if self._t_first is not None
-                    else None
-                ),
-            )
-        except Exception:  # noqa: BLE001 - observability only
-            pass
+        # request-lifecycle span (ISSUE 15): one slice per stream in the
+        # Chrome-trace export, beside the task slices it caused
+        tracing.SPANS.record(
+            "serve_stream",
+            "serve",
+            self._t0_wall,
+            time.monotonic() - self._t0,
+            pid=f"serve:{self._labels['deployment']}",
+            code=code,
+            delivered=self.delivered,
+            failovers=self.failovers,
+            ttft_ms=(
+                (self._t_first - self._t0) * 1000.0
+                if self._t_first is not None
+                else None
+            ),
+            **tracing.event_args(self._trace),
+        )
         self._router._note_finished(code)
         self._ticket.done()
 
@@ -741,11 +742,12 @@ class RoutedStream:
 # the router
 # ---------------------------------------------------------------------------
 class _UnaryRequest:
-    def __init__(self, router, ref, ticket, t0, model=None):
+    def __init__(self, router, ref, ticket, t0, model=None, trace=None):
         self._router = router
         self.ref = ref
         self._ticket = ticket
         self._t0 = t0
+        self._trace = trace
         self._t0_wall = time.time()
         self._done = False
         self._labels = {"deployment": router._rs.dep.name}
@@ -782,19 +784,15 @@ class _UnaryRequest:
                 (time.monotonic() - self._t0) * 1000.0,
                 labels=self._labels,
             )
-            try:
-                from ray_tpu.util.tracing import SPANS
-
-                SPANS.record(
-                    "serve_unary",
-                    "serve",
-                    self._t0_wall,
-                    time.monotonic() - self._t0,
-                    pid=f"serve:{self._labels['deployment']}",
-                    code=code,
-                )
-            except Exception:  # noqa: BLE001 - observability only
-                pass
+            tracing.SPANS.record(
+                "serve_unary",
+                "serve",
+                self._t0_wall,
+                time.monotonic() - self._t0,
+                pid=f"serve:{self._labels['deployment']}",
+                code=code,
+                **tracing.event_args(self._trace),
+            )
             self._router._note_finished(code)
             self._ticket.done()
 
@@ -847,9 +845,12 @@ class ServeRouter:
         t0 = time.monotonic()
         hit = None
         try:
-            ref, replica = self._rs.submit_traced(
-                method, (payload,), {}, model=model
-            )
+            with tracing.installed(
+                tracing.child_context("serve_unary")
+            ) as trace:
+                ref, replica = self._rs.submit_traced(
+                    method, (payload,), {}, model=model
+                )
             hit = self._lease_hit(replica)
         except BaseException as exc:
             ticket.done()
@@ -869,7 +870,7 @@ class ServeRouter:
         (SERVE_LEASE_HITS if hit else SERVE_LEASE_MISSES).inc(
             labels=self._labels
         )
-        return _UnaryRequest(self, ref, ticket, t0, model=model)
+        return _UnaryRequest(self, ref, ticket, t0, model=model, trace=trace)
 
     def call(
         self,

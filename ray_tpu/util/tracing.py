@@ -21,6 +21,8 @@ Here the context is a small dict ``{"trace_id", "span_id"}`` carried in
 from __future__ import annotations
 
 import contextvars
+import itertools
+import sys
 import threading
 import time as _time
 from collections import deque
@@ -96,12 +98,119 @@ def event_args(trace: Optional[dict]) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# process-local span recorder (ISSUE 15): named duration spans beyond the
-# per-task lifecycle — scheduler rounds, serve request lifecycle,
-# socket-plane stripes, elastic reshape phases. Spans land in a bounded
-# ring and merge into every Chrome-trace export
-# (core/events.TaskEventBuffer.dump_timeline) and crash bundle.
+# process-local span recorder (ISSUE 15, repaired in ISSUE 26): named
+# duration spans beyond the per-task lifecycle — scheduler rounds, serve
+# request lifecycle, the serving engine's step, socket-plane stripes,
+# elastic reshape phases. Spans land in a bounded ring and merge into
+# every Chrome-trace export (core/events.TaskEventBuffer.dump_timeline)
+# and crash bundle.
 # ---------------------------------------------------------------------------
+
+#: epoch seconds at ``time.perf_counter() == 0``, taken once at import.
+#: ``span()`` takes both ends of an interval from ``perf_counter`` and the
+#: ring keeps Chrome's epoch ``ts`` through this one anchor, so a reader
+#: that holds perf_counter stamps maps them onto ring spans exactly:
+#: ``ts_us = (PERF_EPOCH_S + t_perf) * 1e6``.
+PERF_EPOCH_S = _time.time() - _time.perf_counter()
+
+_span_ids = itertools.count(1)
+_open = threading.local()  # .span: innermost span open on this thread
+
+
+class Span:
+    """One interval on the ``perf_counter`` clock with two sinks: the
+    process ring (``SPANS``) and, while a profiler captures, the
+    profiler's own trace, where it lies on the device operations' clock.
+
+    ``with tracing.span(name, cat, **args) as sp`` is the thread-scoped
+    form: it nests (``parent`` is the span open on the same thread),
+    carries the ``trace_id`` of ``current()`` and is annotated for the
+    profiler. ``tracing.span(...).begin()`` ... ``.end()`` is the
+    detached form for an interval that ends elsewhere than it began (a
+    request's life): same ids, ring only. ``set()`` adds args any time
+    before the end; they must be JSON-serializable host values."""
+
+    __slots__ = ("name", "cat", "pid", "args", "t0", "_ring", "_outer", "_ann")
+
+    def __init__(self, ring, name: str, cat: str, pid: str, args: dict):
+        self._ring = ring
+        self.name, self.cat, self.pid, self.args = name, cat, pid, args
+        self.t0 = 0.0
+        self._outer = self._ann = None
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def begin(self) -> "Span":
+        self._outer = outer = getattr(_open, "span", None)
+        trace = _ctx.get()
+        self.args["id"] = next(_span_ids)
+        if outer is not None:
+            self.args["parent"] = outer.args["id"]
+        if trace is not None:
+            self.args["trace_id"] = trace["trace_id"]
+        self.t0 = _time.perf_counter()
+        return self
+
+    def end(self, **args) -> None:
+        t1 = _time.perf_counter()
+        if args:
+            self.args.update(args)
+        self._ring.append(
+            {
+                "name": self.name,
+                "cat": self.cat,
+                "ph": "X",
+                "ts": (PERF_EPOCH_S + self.t0) * 1e6,
+                "dur": (t1 - self.t0) * 1e6,
+                "pid": self.pid or "process",
+                "tid": threading.get_ident(),
+                "args": self.args,
+            }
+        )
+
+    def __enter__(self) -> "Span":
+        # a profiler can only capture in a process that has loaded JAX,
+        # and this module must not load it (node agents never do)
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        _open.span = self.begin()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+        _open.span = self._outer
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+
+
+class _NoSpan:
+    """What ``span()`` hands out while ``cfg.trace_spans`` is off."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        pass
+
+    def begin(self) -> "_NoSpan":
+        return self
+
+    def end(self, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def __bool__(self) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
 
 
 class SpanBuffer:
@@ -110,6 +219,19 @@ class SpanBuffer:
     def __init__(self, max_spans: int = 50_000):
         self._spans: deque = deque(maxlen=max_spans)
         self._lock = threading.Lock()
+
+    def append(self, span: dict) -> None:
+        with self._lock:
+            self._spans.append(span)
+
+    def span(self, name: str, cat: str = "runtime", pid: str = "", **args):
+        """The one way to time an interval inside the program: see
+        ``Span``. The single switch is ``cfg.trace_spans``; off, nothing
+        is recorded or annotated and the returned object is falsy, so a
+        caller can skip work that only feeds ``set()``."""
+        if not cfg.trace_spans:
+            return _NO_SPAN
+        return Span(self, name, cat, pid, args)
 
     def record(
         self,
@@ -121,9 +243,9 @@ class SpanBuffer:
         tid=0,
         **args,
     ) -> None:
-        """One completed span: ``start_ts`` is epoch seconds
-        (time.time()), ``dur_s`` its wall duration. ``args`` must be
-        JSON-serializable (they land in trace exports verbatim)."""
+        """One completed span timed by its caller: ``start_ts`` is epoch
+        seconds (time.time()), ``dur_s`` its wall duration. ``args`` must
+        be JSON-serializable (they land in trace exports verbatim)."""
         if not cfg.trace_spans:
             return
         span = {
@@ -137,16 +259,7 @@ class SpanBuffer:
         }
         if args:
             span["args"] = args
-        with self._lock:
-            self._spans.append(span)
-
-    @contextmanager
-    def span(self, name: str, cat: str = "runtime", pid: str = "", **args):
-        t0 = _time.time()
-        try:
-            yield
-        finally:
-            self.record(name, cat, t0, _time.time() - t0, pid=pid, **args)
+        self.append(span)
 
     def slices(
         self, since_s: Optional[float] = None, cat: Optional[str] = None
@@ -169,3 +282,16 @@ class SpanBuffer:
 
 #: the process's span ring (one per process, like the metrics registry)
 SPANS = SpanBuffer()
+#: ``tracing.span(name, cat, **args)``: a span into the process's ring
+span = SPANS.span
+
+
+@contextmanager
+def installed(trace: Optional[dict]):
+    """``install(trace)`` for the length of a block: what is submitted
+    inside it belongs to that trace."""
+    token = _ctx.set(trace)
+    try:
+        yield trace
+    finally:
+        _ctx.reset(token)

@@ -518,3 +518,40 @@ def test_stale_epoch_rpc_rejected_after_head_restart(tmp_path):
             client.close()
     finally:
         c.shutdown()
+
+
+@pytest.mark.filterwarnings("ignore::pytest.PytestUnraisableExceptionWarning")
+def test_a_collected_stale_coroutine_does_not_fail_its_redelivered_call():
+    """The attempt a chaos kill left parked on the stopped loop is
+    collected while its redelivered call is in flight (same call, retry
+    budget spent): its GeneratorExit is no failure of the call."""
+    import gc
+    import threading
+
+    import ray_tpu as rtpu
+    from ray_tpu.core.runtime import get_runtime
+
+    first_run = threading.Event()
+
+    class Parked:
+        async def work(self, tag):
+            import asyncio
+
+            if not first_run.is_set():
+                first_run.set()
+                await asyncio.sleep(30)
+            else:
+                gc.collect()
+            return tag
+
+    rtpu.init(num_nodes=2, resources_per_node={"CPU": 4})
+    try:
+        a = rtpu.remote(Parked).options(
+            max_restarts=1, max_task_retries=1, max_concurrency=1
+        ).remote()
+        ref = a.work.remote("m1")
+        assert first_run.wait(10), "m1 never started"
+        get_runtime().kill_node(a._actor_state.node_id)
+        assert rtpu.get(ref, timeout=30) == "m1"
+    finally:
+        rtpu.shutdown()
