@@ -341,10 +341,11 @@ def model_bench() -> dict:
         pk, pv = eng.pool.k, eng.pool.v
         toks_d, pos = eng.cur_tokens, eng.positions
         n_dec = 256
-        warm = eng._decode_step(  # warm the chained shapes
+        # the step takes the pool donated: rebind it from every call
+        warm, pk, pv = eng._decode_step(  # warm the chained shapes
             eng.params, pk, pv, eng.block_tables, pos, toks_d,
             eng.active_mask, eng.temps, eng.seeds,
-        )[0]
+        )
         np.asarray(warm)
         t0 = time.perf_counter()
         for _ in range(n_dec):

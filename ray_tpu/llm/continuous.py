@@ -136,6 +136,26 @@ class PagedKVPool:
         self._free_set.update(pages)
 
 
+_POOL = ("pool_k", "pool_v")  # the operands every pool writer donates
+
+
+class KVPoolLost(RuntimeError):
+    """A program that writes the KV pool failed after the pool had been
+    donated to it: the pages of every live request are gone with it, and
+    the engine cannot serve until it is built again."""
+
+
+@functools.partial(jax.jit, donate_argnames=_POOL)
+def _scatter_pages(pool_k, pool_v, pages, k, v):
+    """Write whole pages that were computed elsewhere (a prefix-cache hit,
+    a prefill worker's handoff) into the pool. k, v:
+    ``[L, KH, len(pages), page, hd]``."""
+    return (
+        pool_k.at[:, :, pages].set(k.astype(pool_k.dtype)),
+        pool_v.at[:, :, pages].set(v.astype(pool_v.dtype)),
+    )
+
+
 def _locked(method):
     """Run an engine method under the engine's lock."""
 
@@ -294,7 +314,10 @@ class ContinuousBatchingEngine:
             )
             return attn.reshape(b, cfg.n_heads * cfg.head_dim)
 
-        @jax.jit
+        # every program that writes the pool takes it donated and returns
+        # it as its last two results: the output aliases the input, so the
+        # scatter is in place and nothing of the pool's size is copied
+        @functools.partial(jax.jit, donate_argnames=_POOL)
         def decode_step(
             params, pool_k, pool_v, tables, positions, tokens, active,
             temps, seeds,
@@ -408,7 +431,9 @@ class ContinuousBatchingEngine:
             )
             return out.astype(dtype)
 
-        @functools.partial(jax.jit, static_argnums=(4,))
+        @functools.partial(
+            jax.jit, static_argnums=(4,), donate_argnames=_POOL
+        )
         def prefill(params, pool_k, pool_v, tokens, t_pad, page_ids):
             """Prefill ONE sequence of (padded) length t_pad; write its KV
             into the given pages; return last-token logits. tokens:
@@ -481,7 +506,9 @@ class ContinuousBatchingEngine:
             logits = (h[0] @ params["head"]).astype(jnp.float32)
             return logits, pool_k, pool_v
 
-        @functools.partial(jax.jit, static_argnums=(4,))
+        @functools.partial(
+            jax.jit, static_argnums=(4,), donate_argnames=_POOL
+        )
         def prefill_suffix(
             params,
             pool_k,
@@ -739,6 +766,27 @@ class ContinuousBatchingEngine:
             self._maybe_finish(si)
         return admitted, 0
 
+    def _write_pool(self, program):
+        """Run ``program(pool_k, pool_v)``, a call of one of the programs
+        that write the pool, and rebind the pool from its last two
+        results. This is the only place that holds the pool's arrays: the
+        programs take them donated, so the arrays passed in are dead once
+        the call is made. Returns the program's first result."""
+        k, v = self.pool.k, self.pool.v
+        try:
+            out = program(k, v)
+        except Exception as e:
+            if k.is_deleted() or v.is_deleted():
+                raise KVPoolLost(
+                    "the KV pool was donated to a program that then failed "
+                    f"({type(e).__name__}: {e}); the pages of "
+                    f"{sum(s.active for s in self.slots)} live slots are "
+                    "lost and this engine must be rebuilt"
+                ) from e
+            raise
+        self.pool.k, self.pool.v = out[-2:]
+        return out[0]
+
     def _prefill_prompt(self, prompt, pages):
         """Run the prefill program over the whole (padded) prompt, its KV
         written into the first of ``pages``. Returns the last real
@@ -748,13 +796,11 @@ class ContinuousBatchingEngine:
         tokens = np.zeros(t_pad, np.int32)
         tokens[:t] = prompt
         with tracing.span("engine.prefill", "engine", t_pad=t_pad, hit_tokens=0):
-            logits, self.pool.k, self.pool.v = self._prefill(
-                self.params,
-                self.pool.k,
-                self.pool.v,
-                jnp.asarray(tokens),
-                t_pad,
-                jnp.asarray(pages[: t_pad // self.page], dtype=jnp.int32),
+            logits = self._write_pool(
+                lambda k, v: self._prefill(
+                    self.params, k, v, jnp.asarray(tokens), t_pad,
+                    jnp.asarray(pages[: t_pad // self.page], dtype=jnp.int32),
+                )
             )
         self.full_prefill_count += 1
         return logits[t - 1]
@@ -776,8 +822,9 @@ class ContinuousBatchingEngine:
             k_src = jnp.asarray(np.asarray(k_src))
         if isinstance(v_src, np.ndarray):
             v_src = jnp.asarray(np.asarray(v_src))
-        self.pool.k = self.pool.k.at[:, :, dev_pages].set(k_src)
-        self.pool.v = self.pool.v.at[:, :, dev_pages].set(v_src)
+        self._write_pool(
+            lambda k, v: _scatter_pages(k, v, dev_pages, k_src, v_src)
+        )
         suffix = req.prompt[hit.tokens :]
         ts = len(suffix)
         t_pad = max(self.page, -(-ts // self.page) * self.page)
@@ -788,18 +835,15 @@ class ContinuousBatchingEngine:
             "engine.prefill", "engine", t_pad=t_pad,
             hit_tokens=int(hit.tokens),
         ):
-            logits, self.pool.k, self.pool.v = self._prefill_suffix(
-                self.params,
-                self.pool.k,
-                self.pool.v,
-                jnp.asarray(tokens),
-                t_pad,
-                jnp.int32(hit.tokens),
-                jnp.asarray(table),
-                jnp.asarray(
-                    pages[hist_pages : hist_pages + suffix_pages],
-                    dtype=jnp.int32,
-                ),
+            logits = self._write_pool(
+                lambda k, v: self._prefill_suffix(
+                    self.params, k, v, jnp.asarray(tokens), t_pad,
+                    jnp.int32(hit.tokens), jnp.asarray(table),
+                    jnp.asarray(
+                        pages[hist_pages : hist_pages + suffix_pages],
+                        dtype=jnp.int32,
+                    ),
+                )
             )
         return logits[ts - 1]
 
@@ -962,16 +1006,7 @@ class ContinuousBatchingEngine:
         req.t_admit = req.t_first = req.t_submit
         rid = req.req_id
         dev = jnp.asarray(pages[:ship_pages], dtype=jnp.int32)
-        if isinstance(k, np.ndarray):
-            k = jnp.asarray(k)
-        if isinstance(v, np.ndarray):
-            v = jnp.asarray(v)
-        self.pool.k = self.pool.k.at[:, :, dev].set(
-            k.astype(self.pool.k.dtype)
-        )
-        self.pool.v = self.pool.v.at[:, :, dev].set(
-            v.astype(self.pool.v.dtype)
-        )
+        self._write_pool(lambda pk, pv: _scatter_pages(pk, pv, dev, k, v))
         table = np.zeros(self.max_pages_per_seq, np.int32)
         table[: len(pages)] = pages
         first = int(manifest["first"])
@@ -1103,16 +1138,12 @@ class ContinuousBatchingEngine:
                         queued=len(self.queue),
                     )
                 with decode:
-                    nxt, self.pool.k, self.pool.v = self._decode_step(
-                        self.params,
-                        self.pool.k,
-                        self.pool.v,
-                        self.block_tables,
-                        self.positions,
-                        self.cur_tokens,
-                        self.active_mask,
-                        self.temps,
-                        self.seeds,
+                    nxt = self._write_pool(
+                        lambda k, v: self._decode_step(
+                            self.params, k, v, self.block_tables,
+                            self.positions, self.cur_tokens,
+                            self.active_mask, self.temps, self.seeds,
+                        )
                     )
                 # where the host waits for the step's tokens
                 with tracing.span("engine.readback", "engine"):

@@ -12,6 +12,7 @@ process at a time may load the TPU library, and every xdist worker
 imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -131,6 +132,67 @@ def test_paged_decode_refuses_a_deployment_sized_pool(one_chip):
     _compile(
         pa.paged_attention_decode, *_paged_args(one_chip, 1024), page_size=16
     )
+
+
+# -- the serving engine's pool writers at the benchmark's pool geometry --------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_pool_writers_copy_no_pool(one_chip, program):
+    """``mistral-7b-v0.3-l16``'s cells: 32 slots, a [16,8,2048,16,128] bf16
+    pool (1 GiB of K, 1 GiB of V), contexts to 2,560. A program that writes
+    a few rows of the pool and returns it must alias it, not copy it: no
+    ``copy`` of the pool's shape in the optimised module, and temporaries
+    plus results that alias no operand smaller than one pool. Undonated,
+    the same programs held ``copy.101``/``copy.102`` and 2 GiB of results
+    of their own, 7 and 12 ms a step on the chip (PERF.md, PR 27)."""
+    from ray_tpu.llm.continuous import ContinuousBatchingEngine
+    from ray_tpu.models import transformer as tfm
+
+    cfg = tfm.ModelConfig(
+        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, d_ff=14336, max_seq_len=2560, rope_theta=1e6,
+        dtype=jnp.bfloat16,
+    )
+    slots, page, n_pages = 32, 16, 2048
+    table = cfg.max_seq_len // page
+    # the programs close over the geometry of a slot, not over the pool's
+    # size: a pool of one slot's pages keeps the engine built here small
+    eng = ContinuousBatchingEngine(
+        cfg, params={}, max_batch=slots, page_size=page,
+        n_pages=table + 1, max_pages_per_seq=table,
+    )
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    pool_shape = (cfg.n_layers, cfg.n_kv_heads, n_pages, page, cfg.head_dim)
+    (pool,) = _shapes(one_chip, (pool_shape, cfg.dtype))
+    if program == "decode_step":
+        rest = _shapes(
+            one_chip,
+            ((slots, table), jnp.int32), ((slots,), jnp.int32),
+            ((slots,), jnp.int32), ((slots,), jnp.bool_),
+            ((slots,), jnp.float32), ((slots,), jnp.uint32),
+        )
+        lowered = eng._decode_step.lower(params, pool, pool, *rest)
+    else:
+        t_pad = 2048  # the longest prompt of the cells
+        tokens, pages = _shapes(
+            one_chip, ((t_pad,), jnp.int32), ((t_pad // page,), jnp.int32)
+        )
+        lowered = eng._prefill.lower(params, pool, pool, tokens, t_pad, pages)
+    compiled = lowered.compile()
+    dims = ",".join(map(str, pool_shape))
+    copies = re.findall(
+        rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text()
+    )
+    assert not copies
+    mem = compiled.memory_analysis()
+    one_pool = 2 * pool.size  # bytes of K alone
+    assert mem.alias_size_in_bytes == 2 * one_pool
+    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes + own < one_pool
 
 
 # -- scheduler kernels: the head's real round ---------------------------------

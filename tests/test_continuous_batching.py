@@ -6,11 +6,15 @@ memory-management change, not a math change. Plus: staggered admission,
 page-pool backpressure, and page reuse across more requests than the
 pool holds at once.
 """
+import inspect
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.llm import continuous
 from ray_tpu.llm.continuous import ContinuousBatchingEngine
 from ray_tpu.llm.engine import GenerationConfig, LLMEngine
 from ray_tpu.models import transformer as tfm
@@ -192,3 +196,206 @@ def test_pallas_pool_beyond_vmem_raises_at_construction(small):
         ContinuousBatchingEngine(
             cfg, params={}, n_pages=4096, use_pallas_attention=True
         )
+
+
+# ---------------------------------------------------------------------------
+# the pool is only ever updated in place: every writer takes it donated
+# ---------------------------------------------------------------------------
+
+
+class _Hit:
+    def __init__(self, tokens, k, v):
+        self.tokens, self.k, self.v = tokens, k, v
+
+    def release(self):
+        pass
+
+
+class _ListPrefixCache:
+    """The engine's side of ``serve.prefix_cache`` over a list: the longest
+    page-aligned prefix any inserted prompt shares with the one asked for."""
+
+    def __init__(self, page):
+        self.page, self.entries, self.hits = page, [], 0
+
+    def insert(self, tokens, k, v):
+        self.entries.append((list(tokens), np.asarray(k), np.asarray(v)))
+
+    def lookup(self, prompt, max_tokens):
+        best = None
+        for tokens, k, v in self.entries:
+            n = 0
+            while n < min(len(tokens), max_tokens) and tokens[n] == prompt[n]:
+                n += 1
+            n -= n % self.page
+            if n and (best is None or n > best.tokens):
+                pages = n // self.page
+                best = _Hit(n, k[:, :, :pages], v[:, :, :pages])
+        self.hits += best is not None
+        return best
+
+    def stats(self):
+        return {"hits": self.hits}
+
+
+_LONG = [3, 5, 7, 9, 11, 2, 4, 6, 8, 1, 3, 5, 7, 2, 9, 4, 6, 1]  # 2 pages + 2
+
+
+def _engine(small, **kw):
+    cfg, params = small
+    return ContinuousBatchingEngine(
+        cfg, params, max_batch=2, page_size=8, n_pages=32, **kw
+    )
+
+
+def _spy(monkeypatch, owner, name, seen):
+    """Record, for each call of the jitted program ``owner.name``, the
+    pool it was handed and the compiled program's text."""
+    program = getattr(owner, name)
+
+    def spied(*args, **kw):
+        names = list(inspect.signature(program).parameters)
+        at = names.index("pool_k")
+        before = len(jax.tree.leaves(args[:at]))
+        text = program.lower(*args, **kw).compile().as_text()
+        seen.append((args[at], args[at + 1], before, text))
+        return program(*args, **kw)
+
+    monkeypatch.setattr(owner, name, spied)
+
+
+def _drive_step(name):
+    def drive(eng, _small, monkeypatch, seen):
+        _spy(monkeypatch, eng, name, seen)
+        eng.submit([1, 2, 3], GenerationConfig(max_new_tokens=4))
+        eng.step()  # one prefill, then one decode step
+
+    return drive
+
+
+def _drive_prefix_hit(name):
+    def drive(eng, _small, monkeypatch, seen):
+        gen = GenerationConfig(max_new_tokens=4)
+        eng.generate_ids([_LONG], gen)  # publishes the prompt's two pages
+        owner = eng if name == "_prefill_suffix" else continuous
+        _spy(monkeypatch, owner, name, seen)
+        eng.generate_ids([_LONG[:16] + [9, 9, 9]], gen)
+        assert eng.prefix_cache.hits == 1
+
+    return drive
+
+
+def _drive_adopt(eng, small, monkeypatch, seen):
+    gen = GenerationConfig(max_new_tokens=4)
+    manifest, k, v = _engine(small).prefill_extract(_LONG, gen)
+    _spy(monkeypatch, continuous, "_scatter_pages", seen)
+    assert eng.adopt_pages(manifest, k, v) is not None
+
+
+@pytest.mark.parametrize(
+    "drive, with_cache",
+    [
+        (_drive_step("_decode_step"), False),
+        (_drive_step("_prefill"), False),
+        (_drive_prefix_hit("_prefill_suffix"), True),
+        (_drive_prefix_hit("_scatter_pages"), True),
+        (_drive_adopt, False),
+    ],
+    ids=["decode_step", "prefill", "prefill_suffix", "prefix_hit_restore",
+         "adopt_pages"],
+)
+def test_every_pool_writer_updates_the_pool_in_place(
+    small, monkeypatch, drive, with_cache
+):
+    """The arrays that were the pool before a writer ran are gone after it
+    (donated), the engine holds live ones, and the compiled program aliases
+    its last two results to the two pool operands: nothing of the pool's
+    size is copied."""
+    eng = _engine(
+        small, prefix_cache=_ListPrefixCache(8) if with_cache else None
+    )
+    seen = []
+    drive(eng, small, monkeypatch, seen)
+    assert len(seen) == 1
+    old_k, old_v, before, text = seen[0]
+    assert old_k.is_deleted() and old_v.is_deleted()
+    assert not eng.pool.k.is_deleted() and not eng.pool.v.is_deleted()
+    assert np.isfinite(np.asarray(eng.pool.k)).all()
+    header = text.split("\n", 1)[0]
+    aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
+    assert [int(p) for _, p in aliased] == [before, before + 1], header
+    outs = [int(o) for o, _ in aliased]
+    assert outs[1] == outs[0] + 1  # K and V: the program's last two results
+
+
+def _undonated(eng, monkeypatch):
+    """The same engine with the parent commit's programs: the same Python
+    bodies, jitted without donation."""
+    eng._decode_step = jax.jit(eng._decode_step.__wrapped__)
+    for name in ("_prefill", "_prefill_suffix"):
+        setattr(
+            eng, name,
+            jax.jit(getattr(eng, name).__wrapped__, static_argnums=(4,)),
+        )
+    monkeypatch.setattr(
+        continuous, "_scatter_pages",
+        jax.jit(continuous._scatter_pages.__wrapped__),
+    )
+    return eng
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_donation_changes_no_token(small, monkeypatch, temperature):
+    """Five requests over two slots, so they admit as others finish, then
+    four that hit the prefix cache the first round filled: token for token
+    what the undonated programs give."""
+    gen = GenerationConfig(
+        max_new_tokens=9, temperature=temperature, seed=1234
+    )
+    first = [_LONG, [4, 8], _LONG[:9], [2] * 17, [7, 1, 5]]
+    second = [_LONG[:16] + [9, 9], _LONG, [2] * 17 + [3], [6]]
+
+    def run(eng):
+        out = eng.generate_ids(first, gen) + eng.generate_ids(second, gen)
+        assert eng.prefix_cache.hits >= 3
+        assert eng.pool.free_pages == eng.pool.usable_pages
+        return out
+
+    got = run(_engine(small, prefix_cache=_ListPrefixCache(8)))
+    with monkeypatch.context() as m:
+        want = run(
+            _undonated(_engine(small, prefix_cache=_ListPrefixCache(8)), m)
+        )
+    assert got == want
+    assert all(len(o) == 9 for o in got)
+
+
+@pytest.mark.parametrize("when", ["after_donation", "before_donation"])
+def test_a_failed_pool_writer_leaves_a_typed_error(small, when):
+    """A writer that fails once the pool is donated has taken the pool with
+    it: that step and every later one raise ``KVPoolLost``, never a bare
+    deleted-array error. One that fails before (a trace error, say) leaves
+    the pool whole and the engine serving."""
+    eng = _engine(small)
+    gen = GenerationConfig(max_new_tokens=6)
+    eng.submit([1, 2, 3], gen)
+    real = eng._decode_step
+
+    def failing(*args, **kw):
+        if when == "after_donation":
+            real(*args, **kw)
+        raise ValueError("boom")
+
+    eng._decode_step = failing
+    if when == "before_donation":
+        with pytest.raises(ValueError, match="boom"):
+            eng.step()
+        eng._decode_step = real
+        assert len(eng.generate_ids([[4, 5]], gen)[0]) == 6
+        return
+    with pytest.raises(continuous.KVPoolLost, match="ValueError: boom"):
+        eng.step()
+    eng._decode_step = real
+    for call in (eng.step, lambda: eng.generate_ids([[4, 5]], gen)):
+        with pytest.raises(continuous.KVPoolLost, match="KV pool"):
+            call()

@@ -251,9 +251,13 @@ def test_a_pool_too_small_for_two_requests_stalls_the_admit(toy):
 def test_stream_threads_lock_wait_lands_on_the_request_and_in_stats(toy):
     eng = make_engine(toy, max_batch=2)
     outs = []
+    # long answers: with six tokens the other threads' steps could finish a
+    # request before its own thread first took the lock (one run in six on
+    # a loaded host), and its span then closes with no acquisition
+    gen = GenerationConfig(max_new_tokens=48, temperature=0.0)
 
     def client(p):
-        outs.append(list(eng.stream_ids(p, GEN)))
+        outs.append(list(eng.stream_ids(p, gen)))
 
     threads = [threading.Thread(target=client, args=(p,)) for p in PROMPTS]
     for t in threads:
