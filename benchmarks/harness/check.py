@@ -37,23 +37,12 @@ reference read, and the same numbers put through the same limits:
 """
 from __future__ import annotations
 
-import importlib.util
-import os
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
 GROSS_OVER = 1.0          # a gap no rounding gives; an altered token reads ~4
 CONTROL_QUANT = "int8"    # the precision below the configurations' bfloat16
-HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def load_reference(name: str):
-    path = os.path.join(HERE, "references", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _round_up(x: int, m: int) -> int:
@@ -82,12 +71,12 @@ def pick_sample(finished: List[dict], seed: int, min_tokens: int,
     return sample
 
 
-def output_gaps(cfg: dict, params, sample: List[dict], control: bool,
+def output_gaps(cfg: dict, params, sample: List[dict], ref, control: bool,
                 log: Optional[Callable] = None):
-    """Per-token gaps of the served tokens, and of the control's tokens."""
+    """Per-token gaps of the served tokens, and of the control's tokens.
+    ``ref`` is the configuration's reference (``spec.load_reference``)."""
     import jax.numpy as jnp
 
-    ref = load_reference(cfg.get("reference", "dense_gqa"))
     gaps: List[float] = []
     control_gaps: List[float] = []
     for r in sample:
@@ -151,12 +140,13 @@ def gap_numbers(gaps: List[float], tail_over: float) -> Dict[str, object]:
 
 
 def compare(cfg: dict, params, records: List[dict], seed: int,
-            limits: dict, control: bool = False,
+            limits: dict, ref, control: bool = False,
             log: Optional[Callable] = None) -> Dict[str, dict]:
     """``records``: one dict for each request sent, with ``index``,
     ``prompt`` (ids), ``prompt_len``, ``max_new``, ``ids`` (served ids),
     ``pieces_bad`` (stream items that were not one token), ``finished``,
-    ``error``. ``limits``: the cell's ``check``. Returns
+    ``error``. ``limits``: the cell's ``check``. ``ref``: the
+    configuration's reference. Returns
     name -> {"value", "limit" | "at_least"}."""
     vocab = cfg["vocab_size"]
     finished = [r for r in records if r["finished"]]
@@ -181,7 +171,7 @@ def compare(cfg: dict, params, records: List[dict], seed: int,
         usable, seed, int(limits["sample_min_tokens"]),
         int(limits["sample_max_requests"]),
     )
-    gaps, control_gaps = output_gaps(cfg, params, sample, control, log)
+    gaps, control_gaps = output_gaps(cfg, params, sample, ref, control, log)
     out["compared_tokens"] = {"value": len(gaps), "at_least": 1}
     tail_over = float(limits["tail_over"])
     held = {

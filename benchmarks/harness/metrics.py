@@ -13,12 +13,13 @@ class Run:
     """Everything one run observed. Times are seconds on one host clock."""
     cfg: dict
     mix: dict
+    base: str                           # where the cell's own files are looked for
     peaks: dict
     t_open: float
     t_close: float
     setup_s: float
     clients: list                       # served.Client
-    decode_log: List[tuple]             # (time, live slots, sum of contexts)
+    decode_log: List[tuple]             # (time, (live context lengths))
     prefill_log: List[tuple]            # (time, padded prompt tokens)
     window_compiles: int
     memory_peak_bytes: Optional[int]

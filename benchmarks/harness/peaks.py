@@ -28,3 +28,14 @@ def peaks_for(device_kind: str) -> dict:
             f"table ({sorted(PEAKS)}); add its published peaks with their "
             "source to benchmarks/harness/peaks.py"
         ) from None
+
+
+def dtype_bytes(cfg: dict) -> int:
+    return {"bfloat16": 2, "float16": 2, "float32": 4}[cfg["torch_dtype"]]
+
+
+def least_seconds(flops: int, nbytes: int, peaks: dict) -> float:
+    """The roofline's least time: the larger of the two bounds."""
+    return max(
+        flops / peaks["bf16_flops_per_s"], nbytes / peaks["hbm_bytes_per_s"]
+    )

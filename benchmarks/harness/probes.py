@@ -48,7 +48,7 @@ class CompileCounter:
 class EngineProbes:
     """Wraps ``ContinuousBatchingEngine`` (class methods, and the jitted
     programs each instance builds). ``decode_log`` holds one
-    ``(host time, live slots, sum of live context lengths)`` for each
+    ``(host time, (context length of each live slot, ...))`` for each
     decode step, ``prefill_log`` one ``(host time, padded prompt tokens)``
     for each prefill program run.
 
@@ -115,10 +115,8 @@ class EngineProbes:
                 )
 
             def decode_step(*a, **kw):
-                live = [s.pos + 1 for s in engine.slots if s.active]
-                probes.decode_log.append(
-                    (probes.clock(), len(live), sum(live))
-                )
+                live = tuple(s.pos + 1 for s in engine.slots if s.active)
+                probes.decode_log.append((probes.clock(), live))
                 with jax.profiler.TraceAnnotation(SPAN_DECODE):
                     return decode(*a, **kw)
 

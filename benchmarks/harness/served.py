@@ -19,7 +19,7 @@ import jax
 import numpy as np
 
 from . import probes as probes_mod
-from . import traffic
+from . import spec, traffic
 
 
 class IdTokenizer:
@@ -67,34 +67,22 @@ class Client:
 class Served:
     """Context manager around one deployment of one configuration."""
 
-    def __init__(self, cfg: dict, params, probes: probes_mod.EngineProbes):
+    def __init__(self, cfg: dict, params, probes: probes_mod.EngineProbes,
+                 base: str):
         self.cfg, self.params, self.probes = cfg, params, probes
+        self.base = base  # where the cell's own families/ is looked for
         self.tok = IdTokenizer()
         self.router = None
         self.cut_report: dict = {}  # what ended the answers live at the close
 
     def __enter__(self):
-        import jax.numpy as jnp
-
         import ray_tpu
         import ray_tpu.serve as serve
         from ray_tpu.llm import build_llm_deployment
         from ray_tpu.llm.continuous import ContinuousBatchingEngine
-        from ray_tpu.models import transformer as tfm
 
         cfg, dep = self.cfg, self.cfg["deployment"]
-        model = tfm.ModelConfig(
-            vocab_size=cfg["vocab_size"], d_model=cfg["hidden_size"],
-            n_layers=cfg["num_hidden_layers"],
-            n_heads=cfg["num_attention_heads"],
-            n_kv_heads=cfg["num_key_value_heads"],
-            d_ff=cfg["intermediate_size"],
-            max_seq_len=dep["max_context_tokens"],
-            rope_theta=float(cfg["rope_theta"]),
-            dtype=jnp.dtype(cfg["torch_dtype"]),
-        )
-        if model.head_dim != cfg.get("head_dim", model.head_dim):
-            raise ValueError("the program derives another head size")
+        model = spec.load_family(cfg, self.base).model_config(cfg)
         self.probes.install(ContinuousBatchingEngine)
         self._ray, self._serve = ray_tpu, serve
         ray_tpu.init(num_nodes=1, resources_per_node={"CPU": 8})
@@ -102,6 +90,7 @@ class Served:
             model, self.params, name="llm", engine=dep["engine"],
             max_batch=dep["slots"], page_size=dep["page_size"],
             n_pages=dep["pool_pages"], tokenizer=self.tok,
+            **dep.get("engine_kwargs", {}),
         )
         # one request thread for each slot of the engine
         app = app.deployment.options(

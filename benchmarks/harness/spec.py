@@ -10,7 +10,12 @@ one per-layer metric is a file of its own:
   ``configs/`` directory;
 - ``benchmarks/layer_metrics/<metric>.py`` (a reader with ``read(run)``)
   or ``<metric>.json`` (``{"same_as": "<other metric>"}``), looked for
-  beside the cell's own ``<base>/layer_metrics/`` first.
+  beside the cell's own ``<base>/layer_metrics/`` first;
+- ``families/<family>.py`` and ``references/<reference>.py``, by the two
+  names in the configuration's file, looked for in the same two places:
+  whatever the benchmark knows of an architecture (its weights, the
+  program's configuration object, the algorithm's counts; its plain
+  reference) is behind those two names.
 """
 from __future__ import annotations
 
@@ -79,16 +84,33 @@ def load_cell(workload: str, benchmark_json: Optional[str] = None) -> Cell:
     )
 
 
-def _metric_file(metric: str, base: str) -> str:
-    for d in (os.path.join(base, "layer_metrics"),
-              os.path.join(BENCH_DIR, "layer_metrics")):
-        for ext in (".py", ".json"):
-            path = os.path.join(d, metric + ext)
+def _find(kind: str, name: str, base: str, exts=(".py",)) -> Optional[str]:
+    """``<kind>/<name><ext>``, under the cell's own ``<base>`` first."""
+    for d in (os.path.join(base, kind), os.path.join(BENCH_DIR, kind)):
+        for ext in exts:
+            path = os.path.join(d, name + ext)
             if os.path.exists(path):
                 return path
-    raise SystemExit(
-        f"per-layer metric {metric!r} has no reader file under layer_metrics/"
+    return None
+
+
+def _load_module(path: str, prefix: str):
+    spec = importlib.util.spec_from_file_location(
+        prefix + os.path.basename(path)[:-3].replace(".", "_").replace("-", "_"),
+        path,
     )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _metric_file(metric: str, base: str) -> str:
+    path = _find("layer_metrics", metric, base, (".py", ".json"))
+    if path is None:
+        raise SystemExit(
+            f"per-layer metric {metric!r} has no reader file under layer_metrics/"
+        )
+    return path
 
 
 def load_reader(metric: str, base: str) -> Callable:
@@ -98,10 +120,47 @@ def load_reader(metric: str, base: str) -> Callable:
         path = _metric_file(_load_json(path)["same_as"], base)
         if not path.endswith(".py"):
             raise SystemExit(f"same_as of {metric!r} names no reader")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + os.path.basename(path)[:-3].replace(".", "_").replace("-", "_"),
-        path,
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(path, "bench_metric_").read
+
+
+# what the harness calls of a family's file, and nothing else of it
+FAMILY_PROVIDES = (
+    "make_weights", "model_config", "decode_step_work",
+    "decode_token_flops", "prefill_flops",
+)
+
+
+def _load_named(cfg: dict, key: str, kind: str, base: str):
+    """The module ``<kind>/<cfg[key]>.py``."""
+    name = cfg.get(key)
+    if not name:
+        raise SystemExit(
+            f"configuration {cfg.get('name')!r} names no `{key}` "
+            f"(a file under {kind}/)"
+        )
+    path = _find(kind, name, base)
+    if path is None:
+        raise SystemExit(f"{key} {name!r} has no file under {kind}/")
+    return _load_module(path, f"bench_{key}_")
+
+
+def load_family(cfg: dict, base: str):
+    """The module a configuration's ``family`` names. It provides
+    ``make_weights(cfg, seed)`` (the pytree the program serves, in the
+    served type, made on the device), ``model_config(cfg)`` (the program's
+    own configuration object; raises where the program would derive a size
+    other than the one the file states) and the algorithm's work from shapes
+    and lengths alone: ``decode_step_work(cfg, contexts) -> (flops, bytes)``
+    for one decode step over live sequences of those context lengths,
+    ``decode_token_flops(cfg, context)`` and ``prefill_flops(cfg,
+    prompt_len)``."""
+    mod = _load_named(cfg, "family", "families", base)
+    missing = [n for n in FAMILY_PROVIDES if not callable(getattr(mod, n, None))]
+    if missing:
+        raise SystemExit(f"{mod.__file__} does not provide {missing}")
+    return mod
+
+
+def load_reference(cfg: dict, base: str):
+    """The plain reference a configuration's ``reference`` names."""
+    return _load_named(cfg, "reference", "references", base)

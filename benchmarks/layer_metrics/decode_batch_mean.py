@@ -3,5 +3,8 @@ the benchmark's wrapper of the decode program."""
 
 
 def read(run):
-    live = [b for t, b, _ in run.decode_log if run.t_open <= t < run.t_close]
+    live = [
+        len(ctxs) for t, ctxs in run.decode_log
+        if run.t_open <= t < run.t_close
+    ]
     return sum(live) / len(live) if live else None

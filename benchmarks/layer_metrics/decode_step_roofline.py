@@ -2,9 +2,10 @@
 chip could take for the step's ALGORITHMIC work (every weight read once,
 each live context's K and V read once, the step's FLOPs; the larger of
 bytes over peak bandwidth and FLOPs over peak rate) over the program's
-measured device time. The count comes from ``harness/counts.py`` and the
-live context lengths; it does not look at how the step is implemented."""
-from harness import counts
+measured device time. The count is the configuration's family's
+(``families/<family>.py`` ``decode_step_work``) over the step's live
+context lengths; it does not look at how the step is implemented."""
+from harness import peaks, spec
 
 
 def read(run):
@@ -12,13 +13,14 @@ def read(run):
         return None
     secs, runs = run.trace.program("decode_step")
     t0, t1 = run.capture
-    steps = [(b, ctx) for t, b, ctx in run.decode_log if t0 <= t < t1 and b]
+    steps = [ctxs for t, ctxs in run.decode_log if t0 <= t < t1 and ctxs]
     if not runs or not steps:
         return None
+    family = spec.load_family(run.cfg, run.base)
     least = sum(
-        counts.least_seconds(
-            *counts.decode_step_work(run.cfg, b, ctx), run.peaks
+        peaks.least_seconds(
+            *family.decode_step_work(run.cfg, ctxs), run.peaks
         )
-        for b, ctx in steps
+        for ctxs in steps
     ) / len(steps)
     return 100.0 * least / (secs / runs)

@@ -1,5 +1,5 @@
 """Process peak of device memory after the window, before the reference
-runs: weights, KV pool, its undonated copy and the step's temporaries."""
+runs: weights, KV pool and the step's temporaries."""
 
 
 def read(run):

@@ -86,19 +86,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peaks,
 
     from harness import check, metrics, probes, served, spec, traffic
     from harness import trace as trace_mod
-    from harness import weights
 
     clock = time.perf_counter
     stages = {"chip_found_s": clock() - T_START}  # where set-up's time goes
     configure_compile_cache()  # the program's own rule for the directory
     compiles = probes.CompileCounter()
     cfg, mix = cell.cfg, cell.mix
-    params = weights.make_weights(cfg, seed)
+    params = spec.load_family(cfg, cell.base).make_weights(cfg, seed)
     stages["weights_made_s"] = clock() - T_START
     schedule = traffic.schedule(mix, seconds)
     engine_probes = probes.EngineProbes(clock)
     probe: dict = {}
-    with served.Served(cfg, params, engine_probes) as sv:
+    with served.Served(cfg, params, engine_probes, cell.base) as sv:
         stages["serving_s"] = clock() - T_START
         warmed = sv.warm_up(schedule, seed, cfg["deployment"]["page_size"])
         setup_s = clock() - T_START
@@ -133,7 +132,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peaks,
         bytes_in_use_after=stats_dev.get("bytes_in_use"))
 
     run = metrics.Run(
-        cfg=cfg, mix=mix, peaks=peaks, t_open=t_open,
+        cfg=cfg, mix=mix, base=cell.base, peaks=peaks, t_open=t_open,
         t_close=t_close, setup_s=setup_s, clients=clients,
         decode_log=engine_probes.decode_log,
         prefill_log=engine_probes.prefill_log,
@@ -165,8 +164,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, devices, peaks,
             "error": c.error,
         })
     compared = check.compare(
-        cfg, params, records, seed, cell.cell["check"], control=control,
-        log=log,
+        cfg, params, records, seed, cell.cell["check"],
+        spec.load_reference(cfg, cell.base), control=control, log=log,
     )
     compared["cut_unexplained"] = {
         "value": cut_report["unexplained"], "limit": 0}

@@ -69,6 +69,10 @@ def test_every_cell_and_metric_finds_its_files(bench):
         assert cell.per_layer
         for m in cell.per_layer:
             assert callable(spec.load_reader(m["name"], cell.base))
+        for name in spec.FAMILY_PROVIDES:
+            assert callable(getattr(spec.load_family(cell.cfg, cell.base), name))
+        assert callable(
+            spec.load_reference(cell.cfg, cell.base).reference_logits)
     # the toy cell of the tests holds the same numbers as the real cells
     toy = spec.load_cell(
         "toy-gqa.toy", os.path.join(os.path.dirname(__file__), "toy", "BENCHMARK.json"))
