@@ -342,14 +342,14 @@ def model_bench() -> dict:
         toks_d, pos = eng.cur_tokens, eng.positions
         n_dec = 256
         # the step takes the pool donated: rebind it from every call
-        warm, pk, pv = eng._decode_step(  # warm the chained shapes
+        (warm, _), pk, pv = eng._decode_step(  # warm the chained shapes
             eng.params, pk, pv, eng.block_tables, pos, toks_d,
             eng.active_mask, eng.temps, eng.seeds,
         )
         np.asarray(warm)
         t0 = time.perf_counter()
         for _ in range(n_dec):
-            toks_d, pk, pv = eng._decode_step(
+            (toks_d, _), pk, pv = eng._decode_step(
                 eng.params, pk, pv, eng.block_tables, pos, toks_d,
                 eng.active_mask, eng.temps, eng.seeds,
             )

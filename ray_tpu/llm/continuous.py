@@ -51,7 +51,7 @@ class _Slot:
     req_id: int = -1
     pos: int = 0  # next position to write
     max_pos: int = 0  # hard stop (prompt + max_new)
-    pages: List[int] = field(default_factory=list)
+    pages: Dict[str, List[int]] = field(default_factory=dict)  # by class
     out: List[int] = field(default_factory=list)
     eos: Optional[int] = None
 
@@ -75,20 +75,17 @@ class _Request:
     lock_acquires: int = 0
 
 
-class PagedKVPool:
-    """Fixed pool of KV pages + host-side free-list allocator."""
+class _PageClass:
+    """The free list of one class of page, the one the layers of attention
+    kind ``kind`` write. Page 0 is the class's SCRATCH page: inactive
+    decode slots are redirected there so their no-op writes can never
+    collide with a live slot's page in the same scatter (duplicate-index
+    order is unspecified)."""
 
-    def __init__(self, cfg: tfm.ModelConfig, n_pages: int, page: int):
-        self.page = page
+    def __init__(self, kind: tfm.AttnKind, n_pages: int):
+        self.name = kind.name
+        self.window = kind.window  # 0: a sequence's pages grow with it
         self.n_pages = n_pages
-        # head-major: [L, KH, N, page, hd] — the Pallas decode kernel and
-        # the gather path both read per-head slices without a transpose
-        shape = (cfg.n_layers, cfg.n_kv_heads, n_pages, page, cfg.head_dim)
-        self.k = jnp.zeros(shape, cfg.dtype)
-        self.v = jnp.zeros(shape, cfg.dtype)
-        # page 0 is the SCRATCH page: inactive decode slots are redirected
-        # there so their no-op writes can never collide with a live slot's
-        # page in the same scatter (duplicate-index order is unspecified)
         self._free = list(range(1, n_pages))
         self._free_set = set(self._free)
 
@@ -122,18 +119,112 @@ class PagedKVPool:
                 )
             if not 0 < p < self.n_pages:
                 raise ValueError(
-                    f"free of invalid page {p} "
+                    f"free of invalid {self.name} page {p} "
                     f"(scratch page 0 / out of range, n_pages={self.n_pages})"
                 )
             if p in self._free_set:
                 raise ValueError(
-                    f"double free: page {p} is already on the free-list "
-                    "(one page allocated to two slots corrupts both "
-                    "slots' KV)"
+                    f"double free: {self.name} page {p} is already on the "
+                    "free-list (one page allocated to two slots corrupts "
+                    "both slots' KV)"
                 )
             seen.add(p)
         self._free.extend(pages)
         self._free_set.update(pages)
+
+
+LANES = 128  # a TPU vector register's lanes: the tile of an array's last dim
+# what one prefill program's [H, T, T] float32 scores may take: the longest
+# prompt a single program runs (2,048 tokens at 64 heads, 2,896 at 32)
+PREFILL_SCORES_BYTES = 2**30
+
+
+def stored_key_width(head_dim: int) -> int:
+    """Width a key is stored at: a head wider than one tile of ``LANES``
+    takes whole tiles (192 -> 256, zeros behind the key). The chip's
+    compiler gives a pool whose rows are one and a half tiles wide another
+    layout inside the step than at its ends and copies the whole pool
+    twice a program; rows of whole tiles keep one layout, in place."""
+    if head_dim <= LANES:
+        return head_dim
+    return -(-head_dim // LANES) * LANES
+
+
+class PagedKVPool:
+    """Fixed pools of KV pages, one for each class of page the model's
+    layers write (``cfg.kv_classes()``), each with a host-side free list.
+
+    - class ``full`` (every dense configuration's only class): a sequence
+      holds a page for each ``page`` tokens of its context; ``n_pages``
+      sizes it, and it is the capacity admission backpressures on.
+    - class ``window``: a windowed layer reads the last ``window`` keys and
+      no others, so a sequence holds a fixed ring of
+      ``ring_pages = ceil(window / page) + 1`` pages, written at
+      ``position mod (ring_pages * page)`` and reused in place however long
+      the context grows (the one page over the window is what a prompt's
+      padding may overwrite without touching a key still in a window).
+      Sized for ``max_batch`` rings.
+
+    ``k`` and ``v`` map a class's name to its array
+    ``[layers of the class, KV heads, pages, page, head size]``, head-major
+    (the Pallas decode kernel and the gather path both read per-head
+    slices without a transpose); K and V heads may differ in size, and K's
+    is ``k_dim = stored_key_width(head_dim)``."""
+
+    def __init__(self, cfg: tfm.ModelConfig, n_pages: int, page: int,
+                 max_batch: int = 0):
+        self.page = page
+        self.k_dim = stored_key_width(cfg.head_dim)
+        self.ring_pages = 0
+        self.classes: Dict[str, _PageClass] = {}
+        self.k: Dict[str, jax.Array] = {}
+        self.v: Dict[str, jax.Array] = {}
+        for name, (layers, kind) in cfg.kv_classes().items():
+            n = n_pages
+            if kind.window:
+                self.ring_pages = -(-kind.window // page) + 1
+                n = max_batch * self.ring_pages + 1
+            self.classes[name] = _PageClass(kind, n)
+            shape = (layers, kind.kv_heads, n, page)
+            self.k[name] = jnp.zeros(shape + (self.k_dim,), cfg.dtype)
+            self.v[name] = jnp.zeros(shape + (cfg.v_head_dim,), cfg.dtype)
+
+    @property
+    def n_pages(self) -> int:
+        return sum(c.n_pages for c in self.classes.values())
+
+    @property
+    def free_pages(self) -> int:
+        return sum(c.free_pages for c in self.classes.values())
+
+    @property
+    def usable_pages(self) -> int:
+        return sum(c.usable_pages for c in self.classes.values())
+
+    def need(self, tokens: int) -> Dict[str, int]:
+        """Pages of each class a sequence of ``tokens`` tokens holds."""
+        grows = -(-max(tokens, 1) // self.page)
+        return {
+            name: self.ring_pages if c.window else grows
+            for name, c in self.classes.items()
+        }
+
+    def short(self, need: Dict[str, int]) -> Optional[str]:
+        """The class that cannot give ``need`` now, if any."""
+        for name, n in need.items():
+            if self.classes[name].free_pages < n:
+                return name
+        return None
+
+    def alloc(self, need: Dict[str, int]) -> Optional[Dict[str, List[int]]]:
+        """Pages of every class, or none of any."""
+        if self.short(need) is not None:
+            return None
+        return {name: self.classes[name].alloc(n) for name, n in need.items()}
+
+    def free(self, pages: Dict[str, List[int]]) -> None:
+        for name, ids in pages.items():
+            self.classes[name].free(ids)
 
 
 _POOL = ("pool_k", "pool_v")  # the operands every pool writer donates
@@ -148,12 +239,23 @@ class KVPoolLost(RuntimeError):
 @functools.partial(jax.jit, donate_argnames=_POOL)
 def _scatter_pages(pool_k, pool_v, pages, k, v):
     """Write whole pages that were computed elsewhere (a prefix-cache hit,
-    a prefill worker's handoff) into the pool. k, v:
+    a prefill worker's handoff) into the ``full`` class of the pool. k, v:
     ``[L, KH, len(pages), page, hd]``."""
+    pk, pv = pool_k["full"], pool_v["full"]
     return (
-        pool_k.at[:, :, pages].set(k.astype(pool_k.dtype)),
-        pool_v.at[:, :, pages].set(v.astype(pool_v.dtype)),
+        {**pool_k, "full": pk.at[:, :, pages].set(k.astype(pk.dtype))},
+        {**pool_v, "full": pv.at[:, :, pages].set(v.astype(pv.dtype))},
     )
+
+
+@jax.jit
+def _gather_pages(pool_k, pool_v, pages):
+    """The given pages of the ``full`` class out of the pool, K and V:
+    ``[L, KH, len(pages), page, size]`` each, new buffers. One program
+    for each count of pages: indexed eagerly, every count compiled eight
+    small programs of its own (the index's bounds and wrap-around beside
+    the gather), over half of the programs a replica's warm-up lowered."""
+    return pool_k["full"][:, :, pages], pool_v["full"][:, :, pages]
 
 
 def _locked(method):
@@ -165,6 +267,58 @@ def _locked(method):
             return method(self, *args, **kwargs)
 
     return wrapper
+
+
+def _softmax(scores, sink=None):
+    """Softmax over the last axis. ``sink`` (broadcast against the other
+    axes) is one more column that takes mass and gives no value."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    m = jnp.maximum(jnp.max(scores, axis=-1), sink)
+    e = jnp.exp(scores - m[..., None])
+    return e / (jnp.sum(e, axis=-1) + jnp.exp(sink - m))[..., None]
+
+
+def _window_attention(q, k, v, hist_k, hist_v, q_from, window, sink):
+    """Windowed causal attention of a block of ``t`` consecutive tokens
+    whose first is at position ``q_from``: a query sees the ``window``
+    keys that end with its own. The tokens are cut into blocks of
+    ``window`` queries, and each block sees itself and the block before
+    it, so the scores are ``[t, 2 * window]`` and not ``[t, t]``; what lies
+    before the first block is ``hist_k``/``hist_v`` ``[window, KH, size]``,
+    the ``window`` positions before ``q_from`` (those below 0 are
+    masked). q: [t, H, hd]; k, v: [t, KH, size]; sink: float32[H] or None.
+    Returns float32 [t, H * v size]."""
+    t, h, hd = q.shape
+    kh = k.shape[1]
+    g, w = h // kh, window
+    nb = -(-t // w)
+    pad = nb * w - t
+
+    def blocks(x, before):
+        x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+        x = x.reshape(nb, w, *x.shape[1:])
+        prev = jnp.concatenate([before[None], x[:-1]], 0)
+        return jnp.concatenate([prev, x], 1)  # [nb, 2w, KH, size]
+
+    ks, vs = blocks(k, hist_k), blocks(v, hist_v)
+    qb = jnp.pad(q, ((0, pad), (0, 0), (0, 0))).reshape(nb, w, kh, g, hd)
+    q_pos = q_from + jnp.arange(nb * w).reshape(nb, w)
+    k_pos = q_pos[:, :1] - w + jnp.arange(2 * w)  # [nb, 2w]
+    scores = jnp.einsum(
+        "nqkgd,nskd->nkgqs", qb.astype(jnp.float32), ks.astype(jnp.float32)
+    ) / jnp.sqrt(hd)
+    seen = (
+        (k_pos[:, None, :] <= q_pos[:, :, None])
+        & (k_pos[:, None, :] > q_pos[:, :, None] - w)
+        & (k_pos[:, None, :] >= 0)
+    )
+    scores = jnp.where(seen[:, None, None], scores, -1e30)
+    probs = _softmax(
+        scores, None if sink is None else sink.reshape(kh, g)[None, :, :, None]
+    )
+    out = jnp.einsum("nkgqs,nskd->nqkgd", probs, vs.astype(jnp.float32))
+    return out.reshape(nb * w, -1)[:t]
 
 
 class ContinuousBatchingEngine:
@@ -192,11 +346,29 @@ class ContinuousBatchingEngine:
         model_id: str = "base",
     ):
         if cfg.n_experts > 0:
-            raise NotImplementedError(
-                "paged continuous batching currently supports dense MLP "
-                "models (use LLMEngine for MoE)"
+            raise tfm.UnsupportedModelFeature(
+                "`n_experts` is the train step's Switch layer, which drops "
+                "tokens over a capacity by their place in the batch; the "
+                "paged engine serves dropless experts (`ffn_pattern`, "
+                "`n_routed_experts`)"
+            )
+        self.windowed = "window" in cfg.kv_classes()
+        if self.windowed and prefix_cache is not None:
+            raise tfm.UnsupportedModelFeature(
+                "the shared prefix cache holds pages of the `full` class "
+                "alone; a model with a window class of KV page is served "
+                "with prefix_cache=False"
             )
         configure_compile_cache()
+        if use_pallas_attention and (
+            self.windowed
+            or cfg.v_head_dim != cfg.head_dim
+            or stored_key_width(cfg.head_dim) != cfg.head_dim
+        ):
+            raise tfm.UnsupportedModelFeature(
+                "use_pallas_attention=True: the paged-decode kernel reads "
+                "one class of page with K and V heads of one size"
+            )
         if use_pallas_attention and not pallas_interpret:
             from ray_tpu.ops import paged_attention as pa
 
@@ -217,11 +389,21 @@ class ContinuousBatchingEngine:
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
-        self.pool = PagedKVPool(cfg, n_pages, page_size)
+        self.pool = PagedKVPool(cfg, n_pages, page_size, max_batch)
         self.max_pages_per_seq = min(
             max_pages_per_seq
             or (min(cfg.max_seq_len, n_pages * page_size) // page_size),
-            self.pool.usable_pages,
+            n_pages - 1,
+        )
+        # the longest prompt one prefill program takes: its [H, T, T]
+        # float32 scores stay within PREFILL_SCORES_BYTES. A longer
+        # prompt's rest goes through the history-plus-suffix program in
+        # chunks of a quarter of that length, whose scores are
+        # [chunk, H, context] and grow with T only. Whole pages both.
+        whole = int((PREFILL_SCORES_BYTES / (4 * cfg.n_heads)) ** 0.5)
+        self.max_prefill_tokens = max(1, whole // page_size) * page_size
+        self.prefill_chunk = (
+            max(1, self.max_prefill_tokens // 4 // page_size) * page_size
         )
         self.tokenizer = tokenizer or ByteTokenizer()
         # opt-in Pallas paged-attention decode (ops/paged_attention.py);
@@ -267,10 +449,12 @@ class ContinuousBatchingEngine:
         # zero-re-prefill gate reads these off the decode replicas
         self.full_prefill_count = 0
         self.adopted_count = 0
-        # device-side slot state
-        self.block_tables = jnp.full(
-            (self.B, self.max_pages_per_seq), 0, dtype=jnp.int32
-        )
+        self._prefill_counts = None
+        # device-side slot state: each slot's table of pages, by class
+        self.block_tables = {
+            name: jnp.zeros((self.B, self._table_len(name)), jnp.int32)
+            for name in self.pool.classes
+        }
         self.positions = jnp.zeros((self.B,), jnp.int32)
         self.cur_tokens = jnp.zeros((self.B,), jnp.int32)
         self.active_mask = jnp.zeros((self.B,), bool)
@@ -282,6 +466,19 @@ class ContinuousBatchingEngine:
         self.seeds = jnp.zeros((self.B,), jnp.uint32)
         self._build_fns()
 
+    def _table_len(self, name: str) -> int:
+        """Entries of a slot's table of pages of that class."""
+        if self.pool.classes[name].window:
+            return self.pool.ring_pages
+        return self.max_pages_per_seq
+
+    def _refuse_windowed(self, what: str) -> None:
+        if self.windowed:
+            raise tfm.UnsupportedModelFeature(
+                f"{what} moves pages of the `full` class alone and is not "
+                "implemented for a model with a window class of KV page"
+            )
+
     # ------------------------------------------------------------------
     # jitted programs
     # ------------------------------------------------------------------
@@ -290,29 +487,76 @@ class ContinuousBatchingEngine:
         page = self.page
         P_max = self.max_pages_per_seq
         S_max = P_max * page
+        ring = self.pool.ring_pages * page  # tokens a slot's ring holds
+        k_dim = self.pool.k_dim
 
-        def _attention_pages(q, k_pages, v_pages, q_pos):
+        def stored(x):
+            """Keys at the width the pool stores them, or queries to meet
+            them: zeros behind the head's own dims."""
+            if x.shape[-1] == k_dim:
+                return x
+            pad = [(0, 0)] * (x.ndim - 1) + [(0, k_dim - x.shape[-1])]
+            return jnp.pad(x, pad)
+
+        def head_logits(params, h):
+            h = tfm.rms_norm(h, params["ln_f"], cfg.rms_eps)
+            return (h @ params["head"]).astype(jnp.float32)
+
+        def write_token(pool, layer, page_ids, offsets, active, x):
+            """One token a slot at (page, offset), head-major. x: [B, KH,
+            size]; index arrays broadcast to [B, KH]; an inactive slot
+            keeps what the scratch page held."""
+            hidx = jnp.arange(x.shape[1])[None, :]
+            at = (layer, hidx, page_ids[:, None], offsets[:, None])
+            return pool.at[at].set(
+                jnp.where(active[:, None, None], x.astype(pool.dtype), pool[at])
+            )
+
+        def write_pages(pool, layer, page_ids, x):
+            """Whole pages of one sequence. x: [T, KH, size] -> [KH, T,
+            size] -> [KH, pages, page, size] (a prompt-sized transpose,
+            prefill only); scatter indexes broadcast to [KH, pages]."""
+            kh = x.shape[1]
+            xp = jnp.transpose(x, (1, 0, 2)).reshape(
+                kh, -1, page, x.shape[-1]
+            )
+            hidx = jnp.arange(kh)[:, None]
+            return pool.at[layer, hidx, page_ids[None, :]].set(
+                xp.astype(pool.dtype)
+            )
+
+        def _attention_pages(kind, q, k_pages, v_pages, valid, sink):
             """q: [B,H,hd] one token per slot; k/v_pages head-major
-            [KH,B,P,page,hd]; q_pos: [B] query position. The einsums index
-            the head-major layout directly — no materialized transpose."""
-            b = q.shape[0]
-            kh = cfg.n_kv_heads
+            [KH,B,P,page,size]; valid: bool[B, P*page], the keys each query
+            sees. The einsums index the head-major layout directly — no
+            materialized transpose."""
+            b, kh = q.shape[0], kind.kv_heads
             groups = cfg.n_heads // kh
-            ks = k_pages.reshape(kh, b, S_max, cfg.head_dim)
-            vs = v_pages.reshape(kh, b, S_max, cfg.head_dim)
-            qh = q.reshape(b, kh, groups, cfg.head_dim)
+            s = valid.shape[1]
+            ks = k_pages.reshape(kh, b, s, k_dim)
+            vs = v_pages.reshape(kh, b, s, cfg.v_head_dim)
+            qh = stored(q.reshape(b, kh, groups, cfg.head_dim))
             scores = jnp.einsum(
                 "bhgd,hbsd->bhgs",
                 qh.astype(jnp.float32),
                 ks.astype(jnp.float32),
             ) / jnp.sqrt(cfg.head_dim)
-            valid = jnp.arange(S_max)[None, :] <= q_pos[:, None]
             scores = jnp.where(valid[:, None, None, :], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
+            probs = _softmax(
+                scores,
+                None if sink is None else sink.reshape(kh, groups)[None],
+            )
             attn = jnp.einsum(
                 "bhgs,hbsd->bhgd", probs, vs.astype(jnp.float32)
             )
-            return attn.reshape(b, cfg.n_heads * cfg.head_dim)
+            return attn.reshape(b, cfg.n_heads * cfg.v_head_dim)
+
+        def ring_positions(last):
+            """The position each entry of a ring holds once ``last`` is
+            written: the latest one at or before it that falls there.
+            last: int32[...]; returns int32[..., ring]."""
+            at = jnp.arange(ring)
+            return last[..., None] - (last[..., None] - at) % ring
 
         # every program that writes the pool takes it donated and returns
         # it as its last two results: the output aliases the input, so the
@@ -325,51 +569,31 @@ class ContinuousBatchingEngine:
             """One token for every slot. Inactive slots run the same
             math (one trace) but their KV writes are redirected to the
             reserved scratch page 0, so they can never collide with a
-            live slot's pages in the scatter."""
+            live slot's pages in the scatter. Returns the tokens, int32[2]
+            (token-expert pairs computed here and held experts hit, summed
+            over the expert layers; live slots only) and the pool."""
             b = self.B
             h = params["embed"][tokens].astype(cfg.dtype)  # [B, D]
-            angles = tfm.rope_freqs(
-                cfg.head_dim, cfg.max_seq_len, cfg.rope_theta
-            )
-            ang = angles[positions]  # [B, hd/2]
-            page_idx = positions // page
-            page_ids = jnp.take_along_axis(
-                tables, page_idx[:, None], axis=1
-            )[:, 0]  # [B] physical page per slot
-            # inactive slots write the reserved scratch page (0): their
-            # stale tables may point at pages since reallocated to a LIVE
-            # slot, and a duplicate-index scatter could drop its write
-            page_ids = jnp.where(active, page_ids, 0)
-            offsets = jnp.where(active, positions % page, 0)
 
-            def body(carry, layer):
-                h, pk, pv = carry[0], carry[1], carry[2]
-                p = layer
-                x = tfm.rms_norm(h, p["ln1"])
-                q = (x @ p["wq"]).reshape(b, cfg.n_heads, cfg.head_dim)
-                k = (x @ p["wk"]).reshape(b, cfg.n_kv_heads, cfg.head_dim)
-                v = (x @ p["wv"]).reshape(b, cfg.n_kv_heads, cfg.head_dim)
-                q = _rope1(q, ang)
-                k = _rope1(k, ang)
-                li = carry[3]
-                # head-major scatter: index arrays broadcast to [B, KH]
-                hidx = jnp.arange(cfg.n_kv_heads)[None, :]
-                pg_b = page_ids[:, None]
-                off_b = offsets[:, None]
-                pk = pk.at[li, hidx, pg_b, off_b].set(
-                    jnp.where(
-                        active[:, None, None],
-                        k.astype(pk.dtype),
-                        pk[li, hidx, pg_b, off_b],
-                    )
+            def attend(kind, layer, q, k, v, sink, cache):
+                pool_k, pool_v = cache
+                name, table = kind.name, tables[kind.name]
+                pk, pv = pool_k[name], pool_v[name]
+                # a windowed layer's table is the slot's ring of pages
+                at = positions % ring if kind.window else positions
+                page_ids = jnp.take_along_axis(
+                    table, (at // page)[:, None], axis=1
+                )[:, 0]  # [B] physical page per slot
+                # inactive slots write the reserved scratch page (0): their
+                # stale tables may point at pages since reallocated to a
+                # LIVE slot, and a duplicate-index scatter could drop its
+                # write
+                page_ids = jnp.where(active, page_ids, 0)
+                offsets = jnp.where(active, at % page, 0)
+                pk = write_token(
+                    pk, layer, page_ids, offsets, active, stored(k)
                 )
-                pv = pv.at[li, hidx, pg_b, off_b].set(
-                    jnp.where(
-                        active[:, None, None],
-                        v.astype(pv.dtype),
-                        pv[li, hidx, pg_b, off_b],
-                    )
-                )
+                pv = write_token(pv, layer, page_ids, offsets, active, v)
                 if self.use_pallas_attention:
                     from ray_tpu.ops.paged_attention import (
                         paged_attention_decode,
@@ -382,29 +606,33 @@ class ContinuousBatchingEngine:
                     # ZERO data movement
                     attn = paged_attention_decode(
                         qh,
-                        pk[li],
-                        pv[li],
-                        tables,
+                        pk[layer],
+                        pv[layer],
+                        table,
                         positions + 1,
                         page_size=page,
                         interpret=self.pallas_interpret,
                     ).reshape(b, cfg.n_heads * cfg.head_dim)
                 else:
-                    k_pages = pk[li][:, tables]  # [KH, B, P, page, hd]
-                    v_pages = pv[li][:, tables]
-                    attn = _attention_pages(q, k_pages, v_pages, positions)
-                h = h + (attn.astype(cfg.dtype) @ p["wo"])
-                x2 = tfm.rms_norm(h, p["ln2"])
-                y = tfm.swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
-                return (h + y, pk, pv, li + 1), None
+                    if kind.window:
+                        held = ring_positions(positions)
+                        valid = (held >= 0) & (
+                            held > positions[:, None] - kind.window
+                        )
+                    else:
+                        valid = jnp.arange(S_max)[None, :] <= positions[:, None]
+                    k_pages = pk[layer][:, table]  # [KH, B, P, page, hd]
+                    v_pages = pv[layer][:, table]
+                    attn = _attention_pages(
+                        kind, q, k_pages, v_pages, valid, sink
+                    )
+                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
 
-            (h, pool_k, pool_v, _), _ = jax.lax.scan(
-                body,
-                (h, pool_k, pool_v, jnp.int32(0)),
-                params["blocks"],
+            h, (pool_k, pool_v), moe = tfm.run_stack(
+                cfg, params["blocks"], h, positions, (pool_k, pool_v),
+                attend, live=active,
             )
-            h = tfm.rms_norm(h, params["ln_f"])
-            logits = (h @ params["head"]).astype(jnp.float32)
+            logits = head_logits(params, h)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # per-slot key = fold(request seed, absolute position of the
             # token being produced); prefill samples its first token with
@@ -417,94 +645,78 @@ class ContinuousBatchingEngine:
                 )
             )(seeds, positions, logits, temps).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
-            return nxt, pool_k, pool_v
+            return (nxt, moe), pool_k, pool_v
 
-        def _rope1(x, ang):
-            """x: [B, H, hd]; ang: [B, hd/2]."""
-            dtype = x.dtype
-            x = x.astype(jnp.float32)
-            x1, x2 = jnp.split(x, 2, axis=-1)
-            cos = jnp.cos(ang)[:, None, :]
-            sin = jnp.sin(ang)[:, None, :]
-            out = jnp.concatenate(
-                [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1
+        def ring_write(pool, layer, table, first_page, x):
+            """The last pages of a block of whole pages into a slot's
+            ring: page ``n`` of the sequence lies at entry ``n mod
+            ring_pages``. x: [T, KH, size], T a multiple of ``page``, its
+            first token on page ``first_page`` of the sequence."""
+            pages = min(x.shape[0] // page, self.pool.ring_pages)
+            first_page = first_page + x.shape[0] // page - pages
+            at = (first_page + jnp.arange(pages)) % self.pool.ring_pages
+            return write_pages(
+                pool, layer, table[at], x[x.shape[0] - pages * page :]
             )
-            return out.astype(dtype)
 
         @functools.partial(
             jax.jit, static_argnums=(4,), donate_argnames=_POOL
         )
         def prefill(params, pool_k, pool_v, tokens, t_pad, page_ids):
-            """Prefill ONE sequence of (padded) length t_pad; write its KV
-            into the given pages; return last-token logits. tokens:
-            int32[t_pad]; page_ids: int32[t_pad // page]."""
+            """Prefill ONE sequence of (padded) length t_pad from its
+            first token; write its KV into the given pages; return
+            (logits at every position, int32[2] expert counts). tokens:
+            int32[t_pad]; page_ids by class: ``full`` int32[t_pad // page],
+            ``window`` the slot's ring."""
             pos = jnp.arange(t_pad)
             h = params["embed"][tokens][None].astype(cfg.dtype)  # [1,T,D]
-            angles = tfm.rope_freqs(
-                cfg.head_dim, cfg.max_seq_len, cfg.rope_theta
-            )
-            ang = angles[pos][None]
 
-            def body(carry, layer):
-                h, pk, pv, li = carry
-                p = layer
-                x = tfm.rms_norm(h, p["ln1"])
-                q = (x @ p["wq"]).reshape(1, t_pad, cfg.n_heads, cfg.head_dim)
-                k = (x @ p["wk"]).reshape(
-                    1, t_pad, cfg.n_kv_heads, cfg.head_dim
-                )
-                v = (x @ p["wv"]).reshape(
-                    1, t_pad, cfg.n_kv_heads, cfg.head_dim
-                )
-                q = tfm._apply_rope_positions(q, ang)
-                k = tfm._apply_rope_positions(k, ang)
-                # causal self-attention over the prompt
-                groups = cfg.n_heads // cfg.n_kv_heads
-                qh = q.reshape(1, t_pad, cfg.n_kv_heads, groups, cfg.head_dim)
-                scores = jnp.einsum(
-                    "bthgd,bshd->bhgts",
-                    qh.astype(jnp.float32),
-                    k[0][None].astype(jnp.float32),
-                ) / jnp.sqrt(cfg.head_dim)
-                causal = (
-                    jnp.arange(t_pad)[None, :] <= jnp.arange(t_pad)[:, None]
-                )
-                scores = jnp.where(
-                    causal[None, None, None], scores, -1e30
-                )
-                probs = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum(
-                    "bhgts,bshd->bthgd", probs, v[0][None].astype(jnp.float32)
-                ).reshape(1, t_pad, -1)
-                h = h + (attn.astype(cfg.dtype) @ p["wo"])
-                x2 = tfm.rms_norm(h, p["ln2"])
-                y = tfm.swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
-                # write pages head-major: [T,KH,hd] -> [KH,T,hd] ->
-                # [KH, n_pages, page, hd] (prompt-sized transpose, prefill
-                # only); scatter indexes broadcast to [KH, n_pages]
-                kp = jnp.transpose(k[0], (1, 0, 2)).reshape(
-                    cfg.n_kv_heads, -1, page, cfg.head_dim
-                )
-                vp = jnp.transpose(v[0], (1, 0, 2)).reshape(
-                    cfg.n_kv_heads, -1, page, cfg.head_dim
-                )
-                hidx = jnp.arange(cfg.n_kv_heads)[:, None]
-                pk = pk.at[li, hidx, page_ids[None, :]].set(
-                    kp.astype(pk.dtype)
-                )
-                pv = pv.at[li, hidx, page_ids[None, :]].set(
-                    vp.astype(pv.dtype)
-                )
-                return (h + y, pk, pv, li + 1), None
+            def attend(kind, layer, q, k, v, sink, cache):
+                pool_k, pool_v = cache
+                name, kh = kind.name, kind.kv_heads
+                pk, pv = pool_k[name], pool_v[name]
+                if kind.window:
+                    # nothing lies before the prompt: masked, at positions < 0
+                    attn = _window_attention(
+                        q[0], k[0], v[0],
+                        jnp.zeros((kind.window,) + k.shape[2:], k.dtype),
+                        jnp.zeros((kind.window,) + v.shape[2:], v.dtype),
+                        0, kind.window, sink,
+                    )[None]
+                    pk = ring_write(
+                        pk, layer, page_ids[name], 0, stored(k[0])
+                    )
+                    pv = ring_write(pv, layer, page_ids[name], 0, v[0])
+                else:
+                    # causal self-attention over the prompt
+                    groups = cfg.n_heads // kh
+                    qh = q.reshape(1, t_pad, kh, groups, cfg.head_dim)
+                    scores = jnp.einsum(
+                        "bthgd,bshd->bhgts",
+                        qh.astype(jnp.float32),
+                        k[0][None].astype(jnp.float32),
+                    ) / jnp.sqrt(cfg.head_dim)
+                    causal = (
+                        jnp.arange(t_pad)[None, :] <= jnp.arange(t_pad)[:, None]
+                    )
+                    scores = jnp.where(
+                        causal[None, None, None], scores, -1e30
+                    )
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum(
+                        "bhgts,bshd->bthgd", probs,
+                        v[0][None].astype(jnp.float32),
+                    ).reshape(1, t_pad, -1)
+                    pk = write_pages(
+                        pk, layer, page_ids[name], stored(k[0])
+                    )
+                    pv = write_pages(pv, layer, page_ids[name], v[0])
+                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
 
-            (h, pool_k, pool_v, _), _ = jax.lax.scan(
-                body,
-                (h, pool_k, pool_v, jnp.int32(0)),
-                params["blocks"],
+            h, (pool_k, pool_v), moe = tfm.run_stack(
+                cfg, params["blocks"], h, pos[None], (pool_k, pool_v), attend
             )
-            h = tfm.rms_norm(h, params["ln_f"])
-            logits = (h[0] @ params["head"]).astype(jnp.float32)
-            return logits, pool_k, pool_v
+            return (head_logits(params, h[0]), moe), pool_k, pool_v
 
         @functools.partial(
             jax.jit, static_argnums=(4,), donate_argnames=_POOL
@@ -520,89 +732,83 @@ class ContinuousBatchingEngine:
             suffix_page_ids,
         ):
             """Prefill the SUFFIX of a sequence whose first ``hist_len``
-            tokens' KV was restored from the shared prefix cache: write
-            the suffix KV into its pages, then attend over history +
+            tokens' KV is in its pages already (restored from the shared
+            prefix cache, or written by the prompt's earlier chunks):
+            write the suffix KV into its pages, then attend over history +
             suffix by gathering the slot's whole page table (fixed
             shapes — the decode formulation applied to a prompt block;
-            ``hist_len`` is traced, so one program serves every split
-            within a suffix-length bucket). tokens: int32[t_pad] padded
-            suffix; table: int32[P_max]; suffix_page_ids:
-            int32[t_pad // page]. Returns logits over suffix positions."""
+            ``hist_len``, a multiple of ``page``, is traced, so one program
+            serves every split within a suffix-length bucket). A windowed
+            layer reads the window before the suffix out of the slot's
+            ring, then writes the suffix's last pages over it. tokens:
+            int32[t_pad] padded suffix; table by class: ``full``
+            int32[P_max], ``window`` the ring; suffix_page_ids:
+            int32[t_pad // page] of the ``full`` class. Returns (logits
+            over suffix positions, int32[2] expert counts)."""
             pos = hist_len + jnp.arange(t_pad)  # absolute positions
             h = params["embed"][tokens][None].astype(cfg.dtype)
-            angles = tfm.rope_freqs(
-                cfg.head_dim, cfg.max_seq_len, cfg.rope_theta
-            )
-            ang = angles[pos][None]
 
-            def body(carry, layer):
-                h, pk, pv, li = carry
-                p = layer
-                x = tfm.rms_norm(h, p["ln1"])
-                q = (x @ p["wq"]).reshape(
-                    1, t_pad, cfg.n_heads, cfg.head_dim
-                )
-                k = (x @ p["wk"]).reshape(
-                    1, t_pad, cfg.n_kv_heads, cfg.head_dim
-                )
-                v = (x @ p["wv"]).reshape(
-                    1, t_pad, cfg.n_kv_heads, cfg.head_dim
-                )
-                q = tfm._apply_rope_positions(q, ang)
-                k = tfm._apply_rope_positions(k, ang)
-                # scatter the suffix KV into its pages (prefill layout)
-                kp = jnp.transpose(k[0], (1, 0, 2)).reshape(
-                    cfg.n_kv_heads, -1, page, cfg.head_dim
-                )
-                vp = jnp.transpose(v[0], (1, 0, 2)).reshape(
-                    cfg.n_kv_heads, -1, page, cfg.head_dim
-                )
-                hidx = jnp.arange(cfg.n_kv_heads)[:, None]
-                pk = pk.at[li, hidx, suffix_page_ids[None, :]].set(
-                    kp.astype(pk.dtype)
-                )
-                pv = pv.at[li, hidx, suffix_page_ids[None, :]].set(
-                    vp.astype(pv.dtype)
-                )
-                # history + suffix keys via the slot's full table; key
-                # positions past hist_len + q_pos (incl. the scratch
-                # page behind unfilled table slots) are masked
-                ks = pk[li][:, table].reshape(
-                    cfg.n_kv_heads, S_max, cfg.head_dim
-                )
-                vs = pv[li][:, table].reshape(
-                    cfg.n_kv_heads, S_max, cfg.head_dim
-                )
-                groups = cfg.n_heads // cfg.n_kv_heads
-                qh = q[0].reshape(
-                    t_pad, cfg.n_kv_heads, groups, cfg.head_dim
-                )
-                scores = jnp.einsum(
-                    "tkgd,ksd->tkgs",
-                    qh.astype(jnp.float32),
-                    ks.astype(jnp.float32),
-                ) / jnp.sqrt(cfg.head_dim)
-                causal = jnp.arange(S_max)[None, :] <= pos[:, None]
-                scores = jnp.where(
-                    causal[:, None, None, :], scores, -1e30
-                )
-                probs = jax.nn.softmax(scores, axis=-1)
-                attn = jnp.einsum(
-                    "tkgs,ksd->tkgd", probs, vs.astype(jnp.float32)
-                ).reshape(t_pad, -1)
-                h = h + (attn[None].astype(cfg.dtype) @ p["wo"])
-                x2 = tfm.rms_norm(h, p["ln2"])
-                y = tfm.swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
-                return (h + y, pk, pv, li + 1), None
+            def attend(kind, layer, q, k, v, sink, cache):
+                pool_k, pool_v = cache
+                name, kh = kind.name, kind.kv_heads
+                pk, pv = pool_k[name], pool_v[name]
+                if kind.window:
+                    w = kind.window
+                    before = hist_len - w + jnp.arange(w)  # may be < 0
 
-            (h, pool_k, pool_v, _), _ = jax.lax.scan(
-                body,
-                (h, pool_k, pool_v, jnp.int32(0)),
-                params["blocks"],
+                    def history(pool, width):
+                        held = pool[layer][:, table[name]].reshape(
+                            kh, ring, -1
+                        )
+                        return jnp.transpose(
+                            held[:, before % ring, :width], (1, 0, 2)
+                        )
+
+                    attn = _window_attention(
+                        q[0], k[0], v[0], history(pk, cfg.head_dim),
+                        history(pv, cfg.v_head_dim), hist_len, w, sink,
+                    )[None]
+                    pk = ring_write(
+                        pk, layer, table[name], hist_len // page,
+                        stored(k[0]),
+                    )
+                    pv = ring_write(
+                        pv, layer, table[name], hist_len // page, v[0]
+                    )
+                else:
+                    # scatter the suffix KV into its pages (prefill layout)
+                    pk = write_pages(
+                        pk, layer, suffix_page_ids, stored(k[0])
+                    )
+                    pv = write_pages(pv, layer, suffix_page_ids, v[0])
+                    # history + suffix keys via the slot's full table; key
+                    # positions past hist_len + q_pos (incl. the scratch
+                    # page behind unfilled table slots) are masked
+                    ks = pk[layer][:, table[name]].reshape(kh, S_max, k_dim)
+                    vs = pv[layer][:, table[name]].reshape(
+                        kh, S_max, cfg.v_head_dim
+                    )
+                    groups = cfg.n_heads // kh
+                    qh = stored(q[0].reshape(t_pad, kh, groups, cfg.head_dim))
+                    scores = jnp.einsum(
+                        "tkgd,ksd->tkgs",
+                        qh.astype(jnp.float32),
+                        ks.astype(jnp.float32),
+                    ) / jnp.sqrt(cfg.head_dim)
+                    causal = jnp.arange(S_max)[None, :] <= pos[:, None]
+                    scores = jnp.where(
+                        causal[:, None, None, :], scores, -1e30
+                    )
+                    probs = jax.nn.softmax(scores, axis=-1)
+                    attn = jnp.einsum(
+                        "tkgs,ksd->tkgd", probs, vs.astype(jnp.float32)
+                    ).reshape(t_pad, -1)[None]
+                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
+
+            h, (pool_k, pool_v), moe = tfm.run_stack(
+                cfg, params["blocks"], h, pos[None], (pool_k, pool_v), attend
             )
-            h = tfm.rms_norm(h, params["ln_f"])
-            logits = (h[0] @ params["head"]).astype(jnp.float32)
-            return logits, pool_k, pool_v
+            return (head_logits(params, h[0]), moe), pool_k, pool_v
 
         self._decode_step = decode_step
         self._prefill = prefill
@@ -675,9 +881,20 @@ class ContinuousBatchingEngine:
             end=end,
         )
 
-    def _pages_needed(self, req: _Request) -> int:
-        total = len(req.prompt) + req.gen.max_new_tokens
-        return -(-total // self.page)
+    def _pages_needed(self, req: _Request) -> Dict[str, int]:
+        """Pages of each class to reserve at admission: the whole answer's
+        of the class that grows with the context, a ring of the other."""
+        need = self.pool.need(len(req.prompt) + req.gen.max_new_tokens)
+        return {name: min(n, self._table_len(name)) for name, n in need.items()}
+
+    def _tables(self, pages: Dict[str, List[int]]) -> Dict[str, np.ndarray]:
+        """A slot's table of pages, by class; unfilled entries name the
+        scratch page."""
+        tables = {}
+        for name, ids in pages.items():
+            tables[name] = np.zeros(self._table_len(name), np.int32)
+            tables[name][: len(ids)] = ids
+        return tables
 
     def _admit(self) -> None:
         """Fill free slots from the queue while pages are available."""
@@ -690,23 +907,28 @@ class ContinuousBatchingEngine:
         if not self.queue or live == self.B:
             return
         with tracing.span("engine.admit", "engine", live=live) as sp:
-            admitted, pool_stall = self._admit_queued()
-            self.admit_pool_stalls += pool_stall
-            sp.set(admitted=admitted, pool_stall=pool_stall)
+            admitted, short = self._admit_queued()
+            self.admit_pool_stalls += short is not None
+            # pool_stall: 0, or 1 with the class of page that was short
+            sp.set(admitted=admitted, pool_stall=int(short is not None))
+            if short is not None:
+                sp.set(pool_stall_class=short)
 
     def _admit_queued(self):
         """``_admit``'s loop. Returns how many requests it admitted and
-        whether the pool stalled it (1: a free slot and a queued request,
-        and ``alloc`` gave ``None``; else 0)."""
+        the class of page that stalled it (a free slot and a queued
+        request, and the pool had not the pages of that class), else
+        ``None``."""
         admitted = 0
         for si, slot in enumerate(self.slots):
             if slot.active or not self.queue:
                 continue
             req = self.queue[0]
-            need = min(self._pages_needed(req), self.max_pages_per_seq)
+            need = self._pages_needed(req)
             pages = self.pool.alloc(need)
             if pages is None:
-                return admitted, 1  # backpressure: the POOL is the capacity
+                # backpressure: the POOL is the capacity
+                return admitted, self.pool.short(need)
             self.queue.popleft()
             admitted += 1
             req.t_admit = time.perf_counter()
@@ -721,20 +943,22 @@ class ContinuousBatchingEngine:
                 hit = self.prefix_cache.lookup(
                     prompt, max_tokens=((t - 1) // self.page) * self.page
                 )
-            table = np.zeros(self.max_pages_per_seq, np.int32)
-            table[: len(pages)] = pages
+            tables = self._tables(pages)
             if hit is not None:
-                last_logits = self._admit_with_prefix(req, pages, table, hit)
+                last_logits = self._admit_with_prefix(
+                    req, pages["full"], tables, hit
+                )
             else:
-                last_logits = self._prefill_prompt(prompt, pages)
+                last_logits = self._prefill_prompt(prompt, pages, tables)
             if self.prefix_cache is not None:
                 # publish this prompt's full pages for other replicas
                 # (reads the pool AFTER prefill wrote it — the np gather
                 # below is also what synchronizes the device work)
                 self._prefix_insert(
-                    prompt, pages, hit.tokens if hit is not None else 0
+                    prompt, pages["full"], hit.tokens if hit is not None else 0
                 )
             first = self._sample_first(req.gen, last_logits, t)
+            self._settle_prefill_counts()
             req.t_first = time.perf_counter()
             if hit is not None:
                 # np conversions above synced every consumer of the
@@ -746,16 +970,14 @@ class ContinuousBatchingEngine:
             # the prefill already produced token #1, so decode runs
             # max_new-1 steps; the last token is never written back
             slot.max_pos = min(
-                t + req.gen.max_new_tokens - 1, len(pages) * self.page
+                t + req.gen.max_new_tokens - 1, self._capacity(pages)
             )
             slot.pages = pages
             slot.eos = req.gen.eos_token  # parity with LLMEngine.generate_ids
             slot.out = [first]
-            # device state (table was built before prefill — the suffix
-            # path passes the whole row to its gather)
-            self.block_tables = self.block_tables.at[si].set(
-                jnp.asarray(table)
-            )
+            # device state (the tables were built before prefill — the
+            # suffix path passes the whole rows to its gathers)
+            self._set_tables(si, tables)
             self.positions = self.positions.at[si].set(t)
             self.cur_tokens = self.cur_tokens.at[si].set(first)
             self.active_mask = self.active_mask.at[si].set(True)
@@ -764,7 +986,20 @@ class ContinuousBatchingEngine:
                 np.uint32(req.gen.seed & 0xFFFFFFFF)
             )
             self._maybe_finish(si)
-        return admitted, 0
+        return admitted, None
+
+    def _capacity(self, pages: Dict[str, List[int]]) -> int:
+        """Tokens the reserved pages hold: those of the class that grows
+        (a ring holds any length)."""
+        if "full" in pages:
+            return len(pages["full"]) * self.page
+        return self.cfg.max_seq_len
+
+    def _set_tables(self, si: int, tables: Dict[str, np.ndarray]) -> None:
+        self.block_tables = {
+            name: self.block_tables[name].at[si].set(jnp.asarray(row))
+            for name, row in tables.items()
+        }
 
     def _write_pool(self, program):
         """Run ``program(pool_k, pool_v)``, a call of one of the programs
@@ -776,7 +1011,7 @@ class ContinuousBatchingEngine:
         try:
             out = program(k, v)
         except Exception as e:
-            if k.is_deleted() or v.is_deleted():
+            if any(a.is_deleted() for a in jax.tree.leaves((k, v))):
                 raise KVPoolLost(
                     "the KV pool was donated to a program that then failed "
                     f"({type(e).__name__}: {e}); the pages of "
@@ -787,31 +1022,89 @@ class ContinuousBatchingEngine:
         self.pool.k, self.pool.v = out[-2:]
         return out[0]
 
-    def _prefill_prompt(self, prompt, pages):
-        """Run the prefill program over the whole (padded) prompt, its KV
-        written into the first of ``pages``. Returns the last real
-        token's logits."""
+    def _prefill_prompt(self, prompt, pages, tables):
+        """Prefill the whole (padded) prompt, its KV written into the
+        first of ``pages``: one run of the prefill program up to
+        ``max_prefill_tokens``; a longer prompt's head through it and the
+        rest through the history-plus-suffix program in chunks of
+        ``prefill_chunk`` tokens, so that no temporary grows with the
+        square of the length. Returns the last real token's logits."""
         t = len(prompt)
         t_pad = max(self.page, -(-t // self.page) * self.page)
         tokens = np.zeros(t_pad, np.int32)
         tokens[:t] = prompt
-        with tracing.span("engine.prefill", "engine", t_pad=t_pad, hit_tokens=0):
-            logits = self._write_pool(
+        chunk = self.prefill_chunk
+        chunks = max(0, -(-(t_pad - self.max_prefill_tokens) // chunk))
+        head = t_pad - chunks * chunk
+        # page ids go to the programs as host int32 arrays: a list through
+        # ``jnp.asarray(..., dtype=int32)`` compiles a conversion of its
+        # own for every new length
+        page_ids = {
+            name: np.asarray(
+                ids if self.pool.classes[name].window
+                else ids[: head // self.page],
+                np.int32,
+            )
+            for name, ids in pages.items()
+        }
+        with tracing.span(
+            "engine.prefill", "engine", t_pad=t_pad, hit_tokens=0,
+            chunks=1 + chunks,
+        ) as sp:
+            logits, moe = self._write_pool(
                 lambda k, v: self._prefill(
-                    self.params, k, v, jnp.asarray(tokens), t_pad,
-                    jnp.asarray(pages[: t_pad // self.page], dtype=jnp.int32),
+                    self.params, k, v, jnp.asarray(tokens[:head]), head,
+                    page_ids,
                 )
             )
+            pairs = [moe]
+            if chunks:
+                dev_tables = {
+                    n: jnp.asarray(row) for n, row in tables.items()
+                }
+            for at in range(head, t_pad, chunk):
+                logits, moe = self._prefill_chunk(
+                    tokens[at : at + chunk], at, dev_tables, pages
+                )
+                pairs.append(moe)
+            # read once the first token is (``_settle_prefill_counts``)
+            self._prefill_counts = (sp, pairs)
         self.full_prefill_count += 1
-        return logits[t - 1]
+        return logits[(t - 1) - (t_pad - logits.shape[0])]
 
-    def _admit_with_prefix(self, req, pages, table, hit):
+    def _settle_prefill_counts(self) -> None:
+        """``moe_pairs_held`` of the newest ``engine.prefill`` span, read
+        after the wait for the prefill's logits (the ring's record shares
+        the span's args)."""
+        sp, counts = self._prefill_counts or (None, ())
+        self._prefill_counts = None
+        if sp and self.cfg.n_routed_experts:
+            sp.set(moe_pairs_held=sum(int(m[0]) for m in counts))
+
+    def _prefill_chunk(self, tokens, hist_len: int, dev_tables, pages):
+        """One run of the history-plus-suffix program over ``tokens``
+        (padded to whole pages), the sequence's first ``hist_len`` tokens
+        (whole pages) being in its pages already."""
+        t_pad = len(tokens)
+        first = hist_len // self.page
+        suffix_pages = np.asarray(
+            pages.get("full", [])[first : first + t_pad // self.page],
+            np.int32,
+        )
+        return self._write_pool(
+            lambda k, v: self._prefill_suffix(
+                self.params, k, v, jnp.asarray(tokens), t_pad,
+                jnp.int32(hist_len), dev_tables, suffix_pages,
+            )
+        )
+
+    def _admit_with_prefix(self, req, pages, tables, hit):
         """Cache-hit admission: copy the pinned KV views into this
-        engine's pool pages and prefill only the suffix. Returns the
-        last real token's logits."""
-        t = len(req.prompt)
+        engine's pool pages (``pages``: the slot's, of the ``full`` class)
+        and prefill only the suffix. Returns the last real token's
+        logits."""
         hist_pages = hit.tokens // self.page
-        dev_pages = jnp.asarray(pages[:hist_pages], dtype=jnp.int32)
+        dev_pages = np.asarray(pages[:hist_pages], np.int32)
         # device-frame hits are ALREADY jax Arrays (landed straight from
         # the arena page — the device plane removed the intermediate
         # host copy); host-view hits keep the old path, where
@@ -828,22 +1121,16 @@ class ContinuousBatchingEngine:
         suffix = req.prompt[hit.tokens :]
         ts = len(suffix)
         t_pad = max(self.page, -(-ts // self.page) * self.page)
-        suffix_pages = t_pad // self.page
         tokens = np.zeros(t_pad, np.int32)
         tokens[:ts] = suffix
         with tracing.span(
             "engine.prefill", "engine", t_pad=t_pad,
-            hit_tokens=int(hit.tokens),
+            hit_tokens=int(hit.tokens), chunks=1,
         ):
-            logits = self._write_pool(
-                lambda k, v: self._prefill_suffix(
-                    self.params, k, v, jnp.asarray(tokens), t_pad,
-                    jnp.int32(hit.tokens), jnp.asarray(table),
-                    jnp.asarray(
-                        pages[hist_pages : hist_pages + suffix_pages],
-                        dtype=jnp.int32,
-                    ),
-                )
+            logits, _ = self._prefill_chunk(
+                tokens, int(hit.tokens),
+                {n: jnp.asarray(row) for n, row in tables.items()},
+                {"full": pages},
             )
         return logits[ts - 1]
 
@@ -863,7 +1150,7 @@ class ContinuousBatchingEngine:
                 # already published (hot prompt): skip the device→host KV
                 # gather entirely — it's a blocking sync on the admit path
                 return
-            dev = jnp.asarray(pages[:n_pages], dtype=jnp.int32)
+            dev = np.asarray(pages[:n_pages], np.int32)
             from ray_tpu.cluster import device_plane as _dp
 
             if _dp.device_plane_enabled():
@@ -873,11 +1160,11 @@ class ContinuousBatchingEngine:
                 # — the eager np.asarray device→host sync is gone from the
                 # admit path, and lookups on the other side land the pages
                 # back on device with one device_put
-                k = self.pool.k[:, :, dev]
-                v = self.pool.v[:, :, dev]
+                k, v = _gather_pages(self.pool.k, self.pool.v, dev)
             else:
-                k = np.asarray(self.pool.k[:, :, dev])
-                v = np.asarray(self.pool.v[:, :, dev])
+                k, v = map(
+                    np.asarray, _gather_pages(self.pool.k, self.pool.v, dev)
+                )
             self.prefix_cache.insert(prompt[:ins], k, v)
             sp.set(pages=n_pages)
 
@@ -916,6 +1203,7 @@ class ContinuousBatchingEngine:
         frames, so the ship to a decode replica rides the striped
         peer-socket plane and lands with one ``device_put``), host
         copies otherwise (the host-bounce fallback)."""
+        self._refuse_windowed("prefill_extract (the KV hand-off)")
         t = len(prompt)
         if t < 1:
             raise ValueError("prefill_extract needs a non-empty prompt")
@@ -926,7 +1214,7 @@ class ContinuousBatchingEngine:
                 f"prompt of {t} tokens needs {prompt_pages} pages but "
                 f"max_pages_per_seq={self.max_pages_per_seq}"
             )
-        pages = self.pool.alloc(prompt_pages)
+        pages = self.pool.alloc({"full": prompt_pages})
         if pages is None:
             raise MemoryError(
                 "prefill pool exhausted "
@@ -934,19 +1222,21 @@ class ContinuousBatchingEngine:
             )
         try:
             first = self._sample_first(
-                gen, self._prefill_prompt(prompt, pages), t
+                gen,
+                self._prefill_prompt(prompt, pages, self._tables(pages)),
+                t,
             )
-            dev = jnp.asarray(pages, dtype=jnp.int32)
+            dev = np.asarray(pages["full"], np.int32)
             from ray_tpu.cluster import device_plane as _dp
 
             if _dp.device_plane_enabled():
                 # functional jax arrays: these gathers are new buffers,
                 # so freeing the pool pages below cannot alias them
-                k = self.pool.k[:, :, dev]
-                v = self.pool.v[:, :, dev]
+                k, v = _gather_pages(self.pool.k, self.pool.v, dev)
             else:
-                k = np.asarray(self.pool.k[:, :, dev])
-                v = np.asarray(self.pool.v[:, :, dev])
+                k, v = map(
+                    np.asarray, _gather_pages(self.pool.k, self.pool.v, dev)
+                )
         finally:
             self.pool.free(pages)
         manifest = {
@@ -976,6 +1266,7 @@ class ContinuousBatchingEngine:
         geometry or model, no free slot, pool backpressure) — the
         caller falls back to ``submit()``, i.e. a local re-prefill,
         which is token-exact because generation is seed-deterministic."""
+        self._refuse_windowed("adopt_pages (the KV hand-off)")
         if manifest.get("page") != self.page:
             return None
         if manifest.get("model", self.model_id) != self.model_id:
@@ -998,31 +1289,27 @@ class ContinuousBatchingEngine:
         need = max(need, ship_pages)
         if need > self.max_pages_per_seq:
             return None
-        pages = self.pool.alloc(need)
+        pages = self.pool.alloc({"full": need})
         if pages is None:
             return None  # pool backpressure: the POOL is the capacity
         req = self._begin_request(prompt, gen)
         # grafted mid-batch: no queue, no prefill here
         req.t_admit = req.t_first = req.t_submit
         rid = req.req_id
-        dev = jnp.asarray(pages[:ship_pages], dtype=jnp.int32)
+        dev = np.asarray(pages["full"][:ship_pages], np.int32)
         self._write_pool(lambda pk, pv: _scatter_pages(pk, pv, dev, k, v))
-        table = np.zeros(self.max_pages_per_seq, np.int32)
-        table[: len(pages)] = pages
         first = int(manifest["first"])
         slot = self.slots[si]
         slot.active = True
         slot.req_id = rid
         slot.pos = t
         slot.max_pos = min(
-            t + gen.max_new_tokens - 1, len(pages) * self.page
+            t + gen.max_new_tokens - 1, self._capacity(pages)
         )
         slot.pages = pages
         slot.eos = gen.eos_token
         slot.out = [first]
-        self.block_tables = self.block_tables.at[si].set(
-            jnp.asarray(table)
-        )
+        self._set_tables(si, self._tables(pages))
         self.positions = self.positions.at[si].set(t)
         self.cur_tokens = self.cur_tokens.at[si].set(first)
         self.active_mask = self.active_mask.at[si].set(True)
@@ -1052,6 +1339,7 @@ class ContinuousBatchingEngine:
         replica for at most one deadline, never forever."""
         from ray_tpu.config import cfg
 
+        self._refuse_windowed("swap_params (the weights hot-swap)")
         deadline = float(cfg.serve_swap_drain_deadline_s)
         self._swapping = True
         self._swap_started = time.monotonic()
@@ -1128,17 +1416,28 @@ class ContinuousBatchingEngine:
                 decode = tracing.span("engine.decode", "engine")
                 if decode:
                     page = self.page
+                    written = [-(-(s.pos + 1) // page) for s in live]
+                    # pages_reserved / pages_written: of the class that
+                    # grows with the context; full_pages / window_pages:
+                    # pages that hold a live token, by class
                     decode.set(
                         live=len(live),
                         ctx=sum(s.pos + 1 for s in live),
-                        pages_reserved=sum(len(s.pages) for s in live),
-                        pages_written=sum(
-                            -(-(s.pos + 1) // page) for s in live
+                        pages_reserved=sum(
+                            len(s.pages.get("full", ())) for s in live
                         ),
+                        pages_written=sum(written),
                         queued=len(self.queue),
                     )
+                    if self.windowed:
+                        decode.set(
+                            full_pages=sum(written),
+                            window_pages=sum(
+                                min(n, self.pool.ring_pages) for n in written
+                            ),
+                        )
                 with decode:
-                    nxt = self._write_pool(
+                    nxt, moe = self._write_pool(
                         lambda k, v: self._decode_step(
                             self.params, k, v, self.block_tables,
                             self.positions, self.cur_tokens,
@@ -1148,6 +1447,11 @@ class ContinuousBatchingEngine:
                 # where the host waits for the step's tokens
                 with tracing.span("engine.readback", "engine"):
                     nxt_h = np.asarray(nxt)
+                if decode and self.cfg.n_routed_experts:
+                    # the step's two sums are known once its tokens are:
+                    # the ring's record shares the span's args
+                    pairs, hit = np.asarray(moe).tolist()
+                    decode.set(moe_pairs_held=pairs, moe_experts_hit=hit)
                 self.positions = self.positions + jnp.where(
                     self.active_mask, 1, 0
                 )

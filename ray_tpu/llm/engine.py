@@ -52,6 +52,7 @@ class LLMEngine:
         max_len: int = 256,
         tokenizer: Optional[Any] = None,
     ):
+        cfg.require_uniform_dense("LLMEngine")
         self.cfg = cfg
         self.max_len = min(max_len, cfg.max_seq_len)
         self.tokenizer = tokenizer or ByteTokenizer()

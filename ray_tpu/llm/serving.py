@@ -44,8 +44,9 @@ from .engine import GenerationConfig, LLMEngine
 def _params_sig(model_config: Any, params: Optional[Any], name: str) -> str:
     """Cheap weight signature for the shared prefix cache: KV computed
     under different weights must never collide. Hashes the config repr
-    plus a slice of the first parameter leaf (or the default-init
-    marker when params is None)."""
+    plus the head of every parameter leaf and its shape (or the
+    default-init marker when params is None): one leaf alone may be a
+    norm's vector of ones in any set of weights."""
     h = hashlib.sha256(f"{name}:{model_config}".encode())
     if params is None:
         h.update(b"default-init-seed0")
@@ -55,10 +56,19 @@ def _params_sig(model_config: Any, params: Optional[Any], name: str) -> str:
 
         leaves = jax.tree_util.tree_leaves(params)
         h.update(str(len(leaves)).encode())
-        if leaves:
-            first = np.asarray(leaves[0]).ravel()[:256]
-            h.update(first.tobytes())
-            h.update(str(np.asarray(leaves[0]).shape).encode())
+
+        def head(leaf):
+            return leaf[(0,) * (leaf.ndim - 1)][:256] if leaf.ndim else leaf
+
+        # weights on a device give their heads in one dispatch; host
+        # arrays are sliced where they lie
+        on_device = [x for x in leaves if isinstance(x, jax.Array)]
+        heads = iter(jax.jit(lambda xs: [head(x) for x in xs])(on_device))
+        for leaf in leaves:
+            row = next(heads) if isinstance(leaf, jax.Array) else head(
+                np.asarray(leaf))
+            h.update(np.asarray(row).tobytes())
+            h.update(str(np.shape(leaf)).encode())
     return h.hexdigest()[:24]
 
 

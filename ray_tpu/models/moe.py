@@ -70,3 +70,109 @@ def moe_apply(p, x: jax.Array, capacity_factor: float = 1.25) -> jax.Array:
     y = jnp.einsum("ecf,efd->ecd", g * u, p["w_down"])
     out = jnp.einsum("nec,ecd->nd", combine, y.astype(jnp.float32))
     return out.astype(x.dtype).reshape(b, t, d)
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k experts over a published router width, for a holder of
+# some of the experts (the paged serving engine, llm/continuous.py). The
+# Switch layer above stays what the train step runs.
+# ---------------------------------------------------------------------------
+
+
+def init_experts(n_routed: int, held: int, d_model: int, d_ff: int,
+                 n_layers: int, key, dtype):
+    """Weights of ``n_layers`` expert layers that hold ``held`` of
+    ``n_routed`` experts: the router keeps its published width."""
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+
+    def init(k, *shape, scale):
+        return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+    return {
+        "router": init(k1, n_layers, d_model, n_routed, scale=d_model**-0.5),
+        # the correction bias chooses and does not weigh (noaux_tc)
+        "router_bias": jnp.zeros((n_layers, n_routed), jnp.float32),
+        "w_gate": init(k2, n_layers, held, d_model, d_ff, scale=d_model**-0.5),
+        "w_up": init(k3, n_layers, held, d_model, d_ff, scale=d_model**-0.5),
+        "w_down": init(k4, n_layers, held, d_ff, d_model, scale=d_ff**-0.5),
+    }
+
+
+def route(p, x: jax.Array, top_k: int):
+    """Sigmoid scores and selection in float32. x: [N, D]. Returns the
+    chosen experts' ids [N, k] over the router's whole width and their
+    weights [N, k]: the ``top_k`` largest of score + bias, weighed by the
+    score alone, normalised over all the chosen (held by this holder or
+    not)."""
+    logits = jnp.matmul(
+        x.astype(jnp.float32), p["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, weights / jnp.sum(weights, axis=-1, keepdims=True)
+
+
+def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None):
+    """This holder's part of an expert layer: ``sum of w_e * SwiGLU_e(x)``
+    over the experts a token chose that are held here, experts
+    ``held[0] .. held[0] + held[1] - 1`` of the router's width. No token
+    is dropped and nothing stands in for the experts held elsewhere: with
+    ``held = (0, n_routed)`` this is the whole layer, and the parts of
+    all holders add up to it.
+
+    x: [N, D]; ``live``: bool[N], tokens that count (a decode step's
+    inactive slots choose nothing). Token-expert pairs are sorted by held
+    expert and go through one grouped matmul (``lax.ragged_dot``: on the
+    TPU a kernel that visits the rows of each group with that group's
+    weights, so the kernel itself skips an expert no token chose). Under
+    ``lax.scan`` over stacked layers the layer's whole ``[held, D, F]``
+    slice is first copied out of the stack, chosen or not: the traced
+    decode step of the 16-expert cell spends 12.3 ms of 54 on that copy
+    (PERF.md section 5, Open question 13). The rows are a static budget:
+    twice what a uniform router sends here, and all ``N * top_k`` pairs
+    in the branch taken when more than that arrive.
+
+    Returns (out [N, D], pairs held here, held experts hit), the last two
+    int32 counts over live tokens."""
+    n, d = x.shape
+    first, count = held
+    n_routed = p["router"].shape[-1]
+    chosen, weights = route(p, x, top_k)
+    local = chosen - first
+    here = (local >= 0) & (local < count)
+    if live is not None:
+        here = here & live[:, None]
+    # pairs held elsewhere sort behind every held expert's
+    group = jnp.where(here, local, count).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=count + 1)[:count].astype(jnp.int32)
+    n_pairs = jnp.sum(sizes)
+    token = (jnp.arange(n * top_k, dtype=jnp.int32) // top_k)[order]
+    weight = jnp.where(here, weights, 0.0).reshape(-1)[order]
+
+    def run(rows: int):
+        tok, w = token[:rows], weight[:rows]
+        xs = x[tok]
+        gate = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
+        up = jax.lax.ragged_dot(xs, p["w_up"], sizes)
+        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["w_down"], sizes)
+        # rows past the last group belong to no expert: whatever the
+        # kernel left there is not read
+        y = jnp.where(
+            (jnp.arange(rows) < n_pairs)[:, None], y.astype(jnp.float32), 0.0
+        )
+        out = jnp.zeros((n, d), jnp.float32).at[tok].add(y * w[:, None])
+        return out.astype(x.dtype)
+
+    every = n * top_k
+    usual = -(-2 * every * count // n_routed)
+    usual = min(every, -(-usual // 8) * 8)
+    if usual == every:
+        out = run(every)
+    else:
+        out = jax.lax.cond(
+            n_pairs <= usual, lambda: run(usual), lambda: run(every)
+        )
+    return out, n_pairs, jnp.sum(sizes > 0).astype(jnp.int32)

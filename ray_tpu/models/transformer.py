@@ -39,6 +39,38 @@ from ray_tpu.models import moe as moe_mod
 from ray_tpu.util.compile_cache import configure_compile_cache
 
 
+class UnsupportedModelFeature(NotImplementedError):
+    """A path was handed a configuration with a field it does not
+    implement (a layer pattern on the train step, a window class of KV
+    page in the prefix cache, ...). Raised where the path is entered, by
+    the field's name: nothing computes something else instead."""
+
+
+@dataclass(frozen=True)
+class AttnKind:
+    """What one kind of attention layer fixes. ``name`` is also the name
+    of its class of KV page (``PagedKVPool``)."""
+    name: str            # "full" | "window"
+    kv_heads: int
+    rope_theta: float
+    window: int          # 0 = every earlier key
+    sink: bool           # a learned per-head column that takes mass only
+
+
+@dataclass(frozen=True)
+class LayerRun:
+    """Consecutive layers of one kind: ``count`` rows from ``start`` of
+    the kind's stacked weights (``key`` in ``params["blocks"]``; ``None``
+    where the stack is uniform and ``blocks`` is the one stack), and
+    where the run's first layer lies in its class of KV page."""
+    attn: AttnKind
+    experts: bool
+    key: Optional[str]
+    start: int
+    count: int
+    cache_start: int
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     vocab_size: int = 32000
@@ -46,7 +78,7 @@ class ModelConfig:
     n_layers: int = 4
     n_heads: int = 8
     n_kv_heads: int = 4
-    d_ff: int = 1408
+    d_ff: int = 1408            # width of a dense feed-forward layer
     max_seq_len: int = 2048
     rope_theta: float = 10000.0
     n_experts: int = 0          # 0 = dense MLP; >0 = Switch-MoE every layer
@@ -60,14 +92,145 @@ class ModelConfig:
     # trades ~1/3 extra FLOPs for O(n_layers) less residual HBM. The
     # standard TPU memory lever for deep/long-sequence configs.
     remat: bool = False
+    rms_eps: float = 1e-6
+    # -- attention: sizes that need not follow from d_model / n_heads ------
+    head_dim: int = 0           # 0 = d_model // n_heads
+    v_head_dim: int = 0         # 0 = head_dim
+    rotary_dim: int = 0         # leading dims of a head that rotate; 0 = all
+    value_scale: float = 1.0    # values are scaled before attention
+    # -- the stack by position. Empty patterns: every layer full attention
+    # and a dense feed-forward, one uniform stack (the defaults above) ----
+    attn_pattern: Tuple[str, ...] = ()    # "full" | "window", one a layer
+    ffn_pattern: Tuple[str, ...] = ()     # "dense" | "experts", one a layer
+    window: int = 0                       # keys a windowed query sees, itself among them
+    window_kv_heads: int = 0              # 0 = n_kv_heads
+    window_rope_theta: float = 0.0        # 0 = rope_theta
+    window_sink: bool = False
+    # -- expert layers (models/moe.py experts_apply): dropless top-k over
+    # the published router width, this holder's experts computed ----------
+    d_ff_expert: int = 0
+    n_routed_experts: int = 0
+    experts_per_token: int = 0
+    experts_held: Tuple[int, int] = (0, 0)   # (first, count); (0, 0) = all
+
+    def __post_init__(self):
+        derived = {
+            "head_dim": self.head_dim or self.d_model // self.n_heads,
+            "attn_pattern": tuple(self.attn_pattern),
+            "ffn_pattern": tuple(self.ffn_pattern),
+            "experts_held": tuple(self.experts_held),
+        }
+        derived["v_head_dim"] = self.v_head_dim or derived["head_dim"]
+        derived["rotary_dim"] = self.rotary_dim or derived["head_dim"]
+        if derived["experts_held"] == (0, 0):
+            derived["experts_held"] = (0, self.n_routed_experts)
+        for name, value in derived.items():
+            object.__setattr__(self, name, value)
+        for name, allowed in (("attn_pattern", ("full", "window")),
+                              ("ffn_pattern", ("dense", "experts"))):
+            pattern = derived[name]
+            if pattern and (
+                len(pattern) != self.n_layers or set(pattern) - set(allowed)
+            ):
+                raise ValueError(
+                    f"{name} names one of {allowed} for each of "
+                    f"{self.n_layers} layers, got {pattern}"
+                )
+        if "window" in derived["attn_pattern"] and self.window < 1:
+            raise ValueError("a windowed layer needs `window` >= 1")
+        if "experts" in derived["ffn_pattern"]:
+            first, count = derived["experts_held"]
+            if not (
+                0 < self.experts_per_token <= self.n_routed_experts
+                and self.d_ff_expert > 0
+                and 0 <= first and count > 0
+                and first + count <= self.n_routed_experts
+            ):
+                raise ValueError(
+                    "an expert layer needs d_ff_expert, n_routed_experts >= "
+                    "experts_per_token > 0 and experts_held inside the "
+                    f"router's width, got {self}"
+                )
+        if derived["rotary_dim"] % 2 or derived["rotary_dim"] > derived["head_dim"]:
+            raise ValueError("rotary_dim is even and at most head_dim")
 
     @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def uniform(self) -> bool:
+        """One kind of layer throughout: ``params["blocks"]`` is one
+        stack, as every dense configuration's is."""
+        return not self.attn_pattern and not self.ffn_pattern
+
+    def attn_kind(self, name: str) -> AttnKind:
+        if name == "window":
+            return AttnKind(
+                "window", self.window_kv_heads or self.n_kv_heads,
+                self.window_rope_theta or self.rope_theta, self.window,
+                self.window_sink,
+            )
+        return AttnKind("full", self.n_kv_heads, self.rope_theta, 0, False)
+
+    def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
+        """(attention kind, feed-forward kind) of each layer."""
+        attn = self.attn_pattern or ("full",) * self.n_layers
+        ffn = self.ffn_pattern or ("dense",) * self.n_layers
+        return tuple(zip(attn, ffn))
+
+    def layer_runs(self) -> Tuple[LayerRun, ...]:
+        """The stack as runs of consecutive layers of one kind, in order."""
+        runs, in_stack, in_class = [], {}, {}
+        for attn, ffn in self.layer_kinds():
+            key = None if self.uniform else f"{attn}.{ffn}"
+            last = runs[-1] if runs else None
+            if last is not None and last.key == key:
+                runs[-1] = dataclasses.replace(last, count=last.count + 1)
+            else:
+                runs.append(LayerRun(
+                    self.attn_kind(attn), ffn == "experts", key,
+                    in_stack.get(key, 0), 1, in_class.get(attn, 0),
+                ))
+            in_stack[key] = in_stack.get(key, 0) + 1
+            in_class[attn] = in_class.get(attn, 0) + 1
+        return tuple(runs)
+
+    def kv_classes(self) -> Dict[str, Tuple[int, AttnKind]]:
+        """Classes of KV page, by the attention kind that writes them:
+        name -> (layers of that kind, the kind)."""
+        counts: Dict[str, int] = {}
+        for attn, _ in self.layer_kinds():
+            counts[attn] = counts.get(attn, 0) + 1
+        return {n: (c, self.attn_kind(n)) for n, c in sorted(counts.items())}
+
+    def require_uniform_dense(self, path: str) -> None:
+        """For the paths that run the one uniform block with heads of one
+        size (the train step, ``LLMEngine``'s dense cache)."""
+        for name in ("attn_pattern", "ffn_pattern"):
+            if getattr(self, name):
+                raise UnsupportedModelFeature(
+                    f"{path} runs one uniform block; `{name}` is not "
+                    "implemented there (the paged engine, "
+                    "llm/continuous.py, serves a stack by position)"
+                )
+        derived = self.d_model // self.n_heads
+        for name, plain in (("head_dim", derived), ("v_head_dim", derived),
+                            ("rotary_dim", derived), ("value_scale", 1.0)):
+            if getattr(self, name) != plain:
+                raise UnsupportedModelFeature(
+                    f"{path} does not implement `{name}`="
+                    f"{getattr(self, name)}"
+                )
+
+
+def _dense_init(key, *shape, dtype, scale=None):
+    scale = scale or shape[-2] ** -0.5  # fan-in
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
-    """Stacked-layer parameter pytree."""
+    """Stacked-layer parameter pytree. A uniform stack keeps its layers
+    under ``blocks``; a stack by position keeps one stack for each kind of
+    layer under ``blocks["<attention>.<feed-forward>"]``."""
+    if not cfg.uniform:
+        return _init_params_by_kind(cfg, key)
     k = jax.random.split(key, 12)
     d, hd = cfg.d_model, cfg.head_dim
     L = cfg.n_layers
@@ -76,10 +239,7 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     def norm_init(*shape):
         return jnp.ones(shape, dt)
 
-    def dense_init(key, *shape, scale=None):
-        fan_in = shape[-2]
-        scale = scale or fan_in**-0.5
-        return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dt)
+    dense_init = functools.partial(_dense_init, dtype=dt)
 
     blocks = {
         "ln1": norm_init(L, d),
@@ -105,9 +265,50 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     }
 
 
+def _init_params_by_kind(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
+    d, dt = cfg.d_model, cfg.dtype
+    first, held = cfg.experts_held
+
+    dense = functools.partial(_dense_init, dtype=dt)
+    counts: Dict[Tuple[str, str], int] = {}
+    for kind in cfg.layer_kinds():
+        counts[kind] = counts.get(kind, 0) + 1
+    blocks = {}
+    for i, ((attn, ffn), n) in enumerate(sorted(counts.items())):
+        k = jax.random.split(jax.random.fold_in(key, i), 9)
+        kind = cfg.attn_kind(attn)
+        p = {
+            "ln1": jnp.ones((n, d), dt),
+            "ln2": jnp.ones((n, d), dt),
+            "wq": dense(k[0], n, d, cfg.n_heads * cfg.head_dim),
+            "wk": dense(k[1], n, d, kind.kv_heads * cfg.head_dim),
+            "wv": dense(k[2], n, d, kind.kv_heads * cfg.v_head_dim),
+            "wo": dense(k[3], n, cfg.n_heads * cfg.v_head_dim, d),
+        }
+        if kind.sink:
+            p["sink"] = jax.random.normal(k[4], (n, cfg.n_heads), jnp.float32)
+        if ffn == "experts":
+            p["moe"] = moe_mod.init_experts(
+                cfg.n_routed_experts, held, d, cfg.d_ff_expert, n, k[5], dt
+            )
+        else:
+            p["w_gate"] = dense(k[6], n, d, cfg.d_ff)
+            p["w_up"] = dense(k[7], n, d, cfg.d_ff)
+            p["w_down"] = dense(k[8], n, cfg.d_ff, d)
+        blocks[f"{attn}.{ffn}"] = p
+    k = jax.random.split(jax.random.fold_in(key, len(counts)), 2)
+    return {
+        "embed": dense(k[0], cfg.vocab_size, d, scale=0.02),
+        "blocks": blocks,
+        "ln_f": jnp.ones((d,), dt),
+        "head": dense(k[1], d, cfg.vocab_size),
+    }
+
+
 def param_specs(cfg: ModelConfig, pp: int = 1) -> Dict[str, Any]:
     """PartitionSpec tree: Megatron tp sharding; layer axis sharded over pp
     when pipelined (each stage holds its slice of the stack)."""
+    cfg.require_uniform_dense("param_specs (the mesh's sharding rules)")
     lp = "pp" if pp > 1 else None
     blocks = {
         "ln1": P(lp, None),
@@ -212,7 +413,7 @@ def _block(cfg: ModelConfig, p: Dict[str, jax.Array], h: jax.Array,
     """One decoder block. h: [B, T(_local), D]; angles already offset."""
     b, t, d = h.shape
     hd = cfg.head_dim
-    x = rms_norm(h, p["ln1"])
+    x = rms_norm(h, p["ln1"], cfg.rms_eps)
     q = (x @ p["wq"]).reshape(b, t, cfg.n_heads, hd)
     k = (x @ p["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
     v = (x @ p["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
@@ -228,7 +429,7 @@ def _block(cfg: ModelConfig, p: Dict[str, jax.Array], h: jax.Array,
     else:
         attn = _causal_attention(q, k, v, mesh)
     h = h + attn.reshape(b, t, -1) @ p["wo"]
-    x = rms_norm(h, p["ln2"])
+    x = rms_norm(h, p["ln2"], cfg.rms_eps)
     if cfg.n_experts > 0:
         y = moe_mod.moe_apply(p["moe"], x, cfg.expert_capacity_factor)
     else:
@@ -262,6 +463,7 @@ def forward(
 ) -> jax.Array:
     """Logits [B, T, V]. Dispatches to plain / ring-SP / pipelined paths
     based on the mesh shape (pp/sp manual, dp/tp auto)."""
+    cfg.require_uniform_dense("the train step's forward")
     pp = mesh.shape.get("pp", 1) if mesh is not None else 1
     sp = mesh.shape.get("sp", 1) if mesh is not None else 1
     b, t = tokens.shape
@@ -329,8 +531,100 @@ def forward(
         )(stages, h_mb)
         h = h_mb.reshape((b,) + h_mb.shape[2:])
 
-    h = rms_norm(h, params["ln_f"])
+    h = rms_norm(h, params["ln_f"], cfg.rms_eps)
     return (h @ params["head"]).astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# The stack by position, for the paged serving engine (llm/continuous.py):
+# one block function, attention through the caller's cache.
+# ---------------------------------------------------------------------------
+
+
+def rotate(x: jax.Array, ang: jax.Array) -> jax.Array:
+    """Rotary embedding, rotate-half within the leading ``2 * ang.shape[-1]``
+    dims of each head; the rest of the head passes. x: [..., H, hd];
+    ang: [..., rotary_dim / 2] for x's leading dims."""
+    dtype, r = x.dtype, 2 * ang.shape[-1]
+    part = x if r == x.shape[-1] else x[..., :r]
+    x1, x2 = jnp.split(part.astype(jnp.float32), 2, axis=-1)
+    cos = jnp.cos(ang)[..., None, :]
+    sin = jnp.sin(ang)[..., None, :]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1
+    ).astype(dtype)
+    if r == x.shape[-1]:
+        return out
+    return jnp.concatenate([out, x[..., r:]], -1)
+
+
+def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, attend,
+                  live=None):
+    """One layer of the kind ``run`` names: norm, Q K V, rotary, attention
+    through the caller's cache, feed-forward. h: [..., D]; ``ang``: the
+    rotary angles [..., rotary_dim / 2] of h's tokens at the kind's rope
+    base. ``attend(q, k, v, sink)`` gets
+    [..., heads, size] arrays (``sink``: float32[H] or None), writes k and v
+    where the caller keeps them and returns (float32 [..., H * v_head_dim],
+    the caller's cache). Returns (h, that cache, int32[2]: token-expert
+    pairs this holder computed and held experts hit; zeros in a dense
+    layer). ``live``: bool over the leading dims, tokens whose choice of
+    expert counts."""
+    lead, kind = h.shape[:-1], run.attn
+    x = rms_norm(h, p["ln1"], cfg.rms_eps)
+    q = (x @ p["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
+    k = (x @ p["wk"]).reshape(*lead, kind.kv_heads, cfg.head_dim)
+    v = (x @ p["wv"]).reshape(*lead, kind.kv_heads, cfg.v_head_dim)
+    if cfg.value_scale != 1.0:
+        v = v * cfg.value_scale
+    attn, cache = attend(rotate(q, ang), rotate(k, ang), v, p.get("sink"))
+    h = h + (attn.astype(cfg.dtype) @ p["wo"])
+    x2 = rms_norm(h, p["ln2"], cfg.rms_eps)
+    if run.experts:
+        y, pairs, hit = moe_mod.experts_apply(
+            p["moe"], x2.reshape(-1, cfg.d_model),
+            top_k=cfg.experts_per_token, held=cfg.experts_held,
+            live=None if live is None else live.reshape(-1),
+        )
+        return h + y.reshape(h.shape), cache, jnp.stack([pairs, hit])
+    y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
+    return h + y, cache, jnp.zeros((2,), jnp.int32)
+
+
+def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
+              live=None):
+    """Every layer in the pattern's order, each run of one kind a
+    ``lax.scan`` over that kind's stacked weights. ``positions``: int32
+    of h's leading dims. ``attend(kind, layer, q, k, v, sink, cache) -> (attention, cache)``:
+    ``layer`` counts within the kind's class of KV page. Returns (h, cache,
+    the blocks' int32[2] counts summed)."""
+    counts = jnp.zeros((2,), jnp.int32)
+    for run in cfg.layer_runs():
+        stack = blocks if run.key is None else blocks[run.key]
+        if (run.start, run.count) != (0, stack["ln1"].shape[0]):
+            stack = jax.tree.map(
+                lambda a: a[run.start : run.start + run.count], stack
+            )
+
+        ang = rope_freqs(
+            cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
+        )[positions]
+
+        def body(carry, p, run=run, ang=ang):
+            h, cache, layer, counts = carry
+            h, cache, c = decoder_block(
+                cfg, run, p, h, ang,
+                lambda q, k, v, sink: attend(
+                    run.attn, layer, q, k, v, sink, cache
+                ),
+                live,
+            )
+            return (h, cache, layer + 1, counts + c), None
+
+        (h, cache, _, counts), _ = jax.lax.scan(
+            body, (h, cache, jnp.int32(run.cache_start), counts), stack
+        )
+    return h, cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -356,7 +650,7 @@ def _block_with_cache(cfg, p, h, k_cache, v_cache, positions, seq_mask):
     """
     b, t, d = h.shape
     hd = cfg.head_dim
-    x = rms_norm(h, p["ln1"])
+    x = rms_norm(h, p["ln1"], cfg.rms_eps)
     q = (x @ p["wq"]).reshape(b, t, cfg.n_heads, hd)
     k = (x @ p["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
     v = (x @ p["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
@@ -385,7 +679,7 @@ def _block_with_cache(cfg, p, h, k_cache, v_cache, positions, seq_mask):
         "bhgts,bshd->bthgd", probs, v_cache.astype(jnp.float32)
     ).astype(h.dtype)
     h = h + attn.reshape(b, t, -1) @ p["wo"]
-    x = rms_norm(h, p["ln2"])
+    x = rms_norm(h, p["ln2"], cfg.rms_eps)
     if cfg.n_experts > 0:
         y = moe_mod.moe_apply(p["moe"], x, cfg.expert_capacity_factor)
     else:
@@ -414,6 +708,7 @@ def forward_with_cache(
 ):
     """Returns (logits[B, T, V], updated cache). Used for both prefill
     (T = prompt length) and decode (T = 1)."""
+    cfg.require_uniform_dense("forward_with_cache")
     h = params["embed"][tokens].astype(cfg.dtype)
 
     def body(carry, layer):
@@ -427,7 +722,7 @@ def forward_with_cache(
     h, (k_new, v_new) = jax.lax.scan(
         body, h, (params["blocks"], cache["k"], cache["v"])
     )
-    h = rms_norm(h, params["ln_f"])
+    h = rms_norm(h, params["ln_f"], cfg.rms_eps)
     logits = (h @ params["head"]).astype(jnp.float32)
     return logits, {"k": k_new, "v": v_new}
 
@@ -445,6 +740,7 @@ def loss_fn(params, tokens, cfg: ModelConfig, mesh=None, *, num_microbatches=0):
 
 def make_train_step(cfg: ModelConfig, optimizer, mesh=None, *, num_microbatches=0):
     """Returns jittable (params, opt_state, tokens) -> (params, opt_state, loss)."""
+    cfg.require_uniform_dense("make_train_step")
     configure_compile_cache()
     shardings = None
     if mesh is not None:

@@ -127,8 +127,11 @@ class Span:
     carries the ``trace_id`` of ``current()`` and is annotated for the
     profiler. ``tracing.span(...).begin()`` ... ``.end()`` is the
     detached form for an interval that ends elsewhere than it began (a
-    request's life): same ids, ring only. ``set()`` adds args any time
-    before the end; they must be JSON-serializable host values."""
+    request's life): same ids, ring only. ``set()`` adds args; they must
+    be JSON-serializable host values. The ring's record shares the span's
+    args, so a ``set()`` after the end still lands there: a count the
+    device hands over after the interval closed (``engine.decode``'s
+    expert counts, known at the readback) is set then."""
 
     __slots__ = ("name", "cat", "pid", "args", "t0", "_ring", "_outer", "_ann")
 

@@ -175,13 +175,19 @@ def test_pool_writers_copy_no_pool(one_chip, program):
             ((slots,), jnp.int32), ((slots,), jnp.bool_),
             ((slots,), jnp.float32), ((slots,), jnp.uint32),
         )
-        lowered = eng._decode_step.lower(params, pool, pool, *rest)
+        tables, *rest = rest
+        lowered = eng._decode_step.lower(
+            params, {"full": pool}, {"full": pool}, {"full": tables}, *rest
+        )
     else:
         t_pad = 2048  # the longest prompt of the cells
         tokens, pages = _shapes(
             one_chip, ((t_pad,), jnp.int32), ((t_pad // page,), jnp.int32)
         )
-        lowered = eng._prefill.lower(params, pool, pool, tokens, t_pad, pages)
+        lowered = eng._prefill.lower(
+            params, {"full": pool}, {"full": pool}, tokens, t_pad,
+            {"full": pages},
+        )
     compiled = lowered.compile()
     dims = ",".join(map(str, pool_shape))
     copies = re.findall(
@@ -193,6 +199,90 @@ def test_pool_writers_copy_no_pool(one_chip, program):
     assert mem.alias_size_in_bytes == 2 * one_pool
     own = mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert mem.temp_size_in_bytes + own < one_pool
+
+
+# -- a stack by position at the widths of `mimo-v2.5-l7-ep16` ------------------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
+def test_two_page_classes_and_held_experts_copy_no_pool(one_chip, program):
+    """``mimo-v2.5-l7-ep16.mixed``: 64 slots, contexts to 8,192, a full
+    class of 16,384 pages and 64 rings of 9 pages, keys of 192 stored 256
+    wide, 16 of 256 experts held. Every program aliases all four pool
+    arrays and holds no copy of one: with keys stored 192 wide the chip's
+    compiler gave both K pools another layout inside the program and copied
+    each twice a run. The grouped expert matmul is a kernel."""
+    from ray_tpu.llm.continuous import ContinuousBatchingEngine
+    from ray_tpu.models import transformer as tfm
+
+    cfg = tfm.ModelConfig(
+        vocab_size=19072, d_model=4096, n_layers=7, n_heads=64, n_kv_heads=4,
+        d_ff=16384, max_seq_len=8192, rope_theta=1e7, dtype=jnp.bfloat16,
+        rms_eps=1e-5, head_dim=192, v_head_dim=128, rotary_dim=64,
+        value_scale=0.707,
+        attn_pattern=("full",) + ("window",) * 5 + ("full",),
+        ffn_pattern=("dense",) + ("experts",) * 6,
+        window=128, window_kv_heads=8, window_rope_theta=1e4,
+        window_sink=True, d_ff_expert=2048, n_routed_experts=256,
+        experts_per_token=8, experts_held=(0, 16),
+    )
+    slots, page, n_pages = 64, 16, 16384
+    table = cfg.max_seq_len // page
+    eng = ContinuousBatchingEngine(
+        cfg, params={}, max_batch=slots, page_size=page,
+        n_pages=table + 1, max_pages_per_seq=table,
+    )
+    assert (eng.max_prefill_tokens, eng.prefill_chunk) == (2048, 512)
+    assert (eng.pool.ring_pages, eng.pool.k_dim) == (9, 256)
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            tree,
+        )
+
+    params = on_chip(
+        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    )
+    sizes = {"full": (2, 4, n_pages), "window": (5, 8, slots * 9 + 1)}
+    pool_k, pool_v = (
+        {
+            name: jax.ShapeDtypeStruct(
+                lead + (page, width), cfg.dtype, sharding=one_chip
+            )
+            for name, lead in sizes.items()
+        }
+        for width in (256, 128)
+    )
+    ints = lambda *shape: _shapes(one_chip, (shape, jnp.int32))[0]  # noqa: E731
+    tables = {"full": ints(slots, table), "window": ints(slots, 9)}
+    if program == "decode_step":
+        lowered = eng._decode_step.lower(
+            params, pool_k, pool_v, tables, ints(slots), ints(slots),
+            *_shapes(one_chip, ((slots,), jnp.bool_), ((slots,), jnp.float32),
+                     ((slots,), jnp.uint32)),
+        )
+    elif program == "prefill":
+        lowered = eng._prefill.lower(
+            params, pool_k, pool_v, ints(2048), 2048,
+            {"full": ints(2048 // page), "window": ints(9)},
+        )
+    else:
+        lowered = eng._prefill_suffix.lower(
+            params, pool_k, pool_v, ints(512), 512, ints(),
+            {"full": ints(table), "window": ints(9)}, ints(512 // page),
+        )
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    pools = list(pool_k.values()) + list(pool_v.values())
+    for pool in pools:
+        dims = ",".join(map(str, pool.shape))
+        assert not re.findall(rf"= bf16\[{dims}\]\S* copy\(", text), dims
+    assert KERNEL in text  # lax.ragged_dot: the grouped expert matmul
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(2 * p.size for p in pools)
+    # temporaries: the gathered tables of 64 slots, the chunk's scores
+    assert mem.temp_size_in_bytes < 2.5 * 2**30
 
 
 # -- scheduler kernels: the head's real round ---------------------------------

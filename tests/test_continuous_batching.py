@@ -258,7 +258,11 @@ def _spy(monkeypatch, owner, name, seen):
         at = names.index("pool_k")
         before = len(jax.tree.leaves(args[:at]))
         text = program.lower(*args, **kw).compile().as_text()
-        seen.append((args[at], args[at + 1], before, text))
+        # the pool's K and V: a dict of arrays by class of page each
+        seen.append((
+            jax.tree.leaves(args[at]), jax.tree.leaves(args[at + 1]),
+            before, text,
+        ))
         return program(*args, **kw)
 
     monkeypatch.setattr(owner, name, spied)
@@ -318,14 +322,17 @@ def test_every_pool_writer_updates_the_pool_in_place(
     drive(eng, small, monkeypatch, seen)
     assert len(seen) == 1
     old_k, old_v, before, text = seen[0]
-    assert old_k.is_deleted() and old_v.is_deleted()
-    assert not eng.pool.k.is_deleted() and not eng.pool.v.is_deleted()
-    assert np.isfinite(np.asarray(eng.pool.k)).all()
+    assert all(a.is_deleted() for a in old_k + old_v)
+    live = jax.tree.leaves((eng.pool.k, eng.pool.v))
+    assert not any(a.is_deleted() for a in live)
+    assert all(np.isfinite(np.asarray(a)).all() for a in live)
     header = text.split("\n", 1)[0]
     aliased = re.findall(r"\{(\d+)\}: \((\d+), \{\}", header)
-    assert [int(p) for _, p in aliased] == [before, before + 1], header
+    n = len(old_k + old_v)
+    assert [int(p) for _, p in aliased] == list(range(before, before + n)), header
     outs = [int(o) for o, _ in aliased]
-    assert outs[1] == outs[0] + 1  # K and V: the program's last two results
+    # every class's K and V: the program's last results, in their order
+    assert outs == list(range(outs[0], outs[0] + n))
 
 
 def _undonated(eng, monkeypatch):

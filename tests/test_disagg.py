@@ -56,7 +56,7 @@ def _engine(cfg, params, **kw):
 # ---------------------------------------------------------------------------
 def test_pool_double_free_raises(small):
     cfg, _ = small
-    pool = PagedKVPool(cfg, n_pages=8, page=8)
+    pool = PagedKVPool(cfg, n_pages=8, page=8).classes["full"]
     pages = pool.alloc(3)
     pool.free(pages)
     with pytest.raises(ValueError):
@@ -76,7 +76,7 @@ def test_pool_double_free_raises(small):
 
 def test_pool_free_set_tracks_alloc(small):
     cfg, _ = small
-    pool = PagedKVPool(cfg, n_pages=8, page=8)
+    pool = PagedKVPool(cfg, n_pages=8, page=8).classes["full"]
     a = pool.alloc(4)
     b = pool.alloc(3)
     assert not set(a) & set(b)

@@ -1,0 +1,427 @@
+"""A stack by position through the paged engine, at toy size on the CPU:
+windowed and full attention mixed (two classes of KV page), a dense layer
+and then dropless sigmoid-routed experts of which this holder has some,
+against the benchmark's plain reference (``benchmarks/references/
+moe_window_gqa.py``); and the dense configurations through the same block,
+to the bit."""
+import os
+import sys
+import zlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from harness import spec  # noqa: E402
+
+from ray_tpu.llm import continuous  # noqa: E402
+from ray_tpu.llm.continuous import ContinuousBatchingEngine  # noqa: E402
+from ray_tpu.llm.engine import GenerationConfig, LLMEngine  # noqa: E402
+from ray_tpu.models import moe  # noqa: E402
+from ray_tpu.models import transformer as tfm  # noqa: E402
+from ray_tpu.util import tracing  # noqa: E402
+
+# two periods of the pattern after the dense layer, 32 experts of which 8
+# are held, top-4, a window of 8 over pages of 4: the source's key names
+TOY = {
+    "name": "toy-moe-window", "family": "moe_window_gqa",
+    "reference": "moe_window_gqa",
+    "hidden_size": 64, "num_attention_heads": 8, "num_key_value_heads": 2,
+    "swa_num_key_value_heads": 4, "head_dim": 24, "v_head_dim": 16,
+    "partial_rotary_factor": 0.334, "rope_theta": 1e7, "swa_rope_theta": 1e4,
+    "attention_value_scale": 0.707, "sliding_window": 8,
+    "add_swa_attention_sink_bias": True, "add_full_attention_sink_bias": False,
+    "layernorm_epsilon": 1e-5, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "num_hidden_layers": 13,
+    "hybrid_layer_pattern": [0, 1, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 0],
+    "moe_layer_freq": [0] + [1] * 12,
+    "n_routed_experts": 8, "router_width": 32, "experts_held": [8, 8],
+    "num_experts_per_tok": 4, "norm_topk_prob": True,
+    "scoring_func": "sigmoid", "vocab_size": 512, "torch_dtype": "float32",
+    "deployment": {"max_context_tokens": 128},
+}
+PAGE = 4
+# float32 against float32 at `highest`: what the order of the sums leaves
+TOL = 2e-4
+
+
+@pytest.fixture(scope="module")
+def toy():
+    family = spec.load_family(TOY, BENCH)
+    reference = spec.load_reference(TOY, BENCH)
+    return family.model_config(TOY), family.make_weights(TOY, 5), reference
+
+
+@pytest.fixture(autouse=True)
+def small_prefill_programs(monkeypatch):
+    """One prefill program takes 16 tokens at the toy's 8 heads, and the
+    rest of a prompt goes in chunks of 4."""
+    monkeypatch.setattr(continuous, "PREFILL_SCORES_BYTES", 4 * 8 * 16 * 16)
+
+
+def make_engine(toy, **kw):
+    kw = {"max_batch": 3, "page_size": PAGE, "n_pages": 64, **kw}
+    eng = ContinuousBatchingEngine(toy[0], toy[1], **kw)
+    assert (eng.max_prefill_tokens, eng.prefill_chunk) == (16, 4)
+    return eng
+
+
+def reference_logits(toy, tokens, quant=None):
+    """Reference logits at every position of ``tokens``."""
+    t = len(tokens)
+    padded = np.zeros(80, np.int32)  # one length: one compile
+    padded[:t] = tokens
+    return np.asarray(toy[2].reference_logits(
+        toy[1], TOY, jnp.asarray(padded), jnp.arange(80), quant=quant
+    ))[:t]
+
+
+def capture_prefill_logits(eng):
+    """Logits of every run of the two prefill programs, in order."""
+    seen = []
+    for name in ("_prefill", "_prefill_suffix"):
+        program = getattr(eng, name)
+
+        def spied(*a, _program=program, **kw):
+            out = _program(*a, **kw)
+            seen.append(np.asarray(out[0][0]))
+            return out
+
+        setattr(eng, name, spied)
+    return seen
+
+
+# -- (a) prefill, then decode through the paged cache, against the reference --
+
+
+@pytest.mark.parametrize(
+    "lanes", [continuous.LANES, 16],
+    ids=["keys_as_wide_as_the_head", "keys_stored_in_whole_tiles"],
+)
+def test_prefill_then_decode_agrees_with_the_reference(toy, monkeypatch, lanes):
+    """Prompts that span several chunks (one program takes 16 tokens, the
+    rest goes in chunks of 4) and contexts that wrap a slot's ring of
+    3 pages x 4 tokens several times. Logits, not tokens: the prefill's at
+    every prompt position; a decoded token by the reference's logit of it
+    against the reference's best at that position."""
+    monkeypatch.setattr(continuous, "LANES", lanes)
+    eng = make_engine(toy)
+    assert eng.pool.k_dim == (24 if lanes > 24 else 32)
+    assert eng.pool.ring_pages == 3
+    seen = capture_prefill_logits(eng)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (37, 5, 16, 23)]
+    new = 40
+    for prompt in prompts:  # one at a time: the captures are this prompt's
+        del seen[:]
+        (out,) = eng.generate_ids([prompt], GenerationConfig(max_new_tokens=new))
+        assert len(out) == new
+        want = reference_logits(toy, prompt + out)
+        got = np.concatenate(seen)[: len(prompt)]
+        padded = -(-len(prompt) // PAGE) * PAGE
+        assert len(seen) == 1 + max(0, -(-(padded - 16) // 4))
+        np.testing.assert_allclose(got, want[: len(prompt)], atol=TOL, rtol=0)
+        at = want[len(prompt) - 1 : len(prompt) + new - 1]
+        gaps = at.max(-1) - at[np.arange(new), out]
+        assert gaps.max() <= TOL
+    assert eng.pool.free_pages == eng.pool.usable_pages
+
+
+def test_a_batch_of_mixed_lengths_agrees_with_the_reference(toy):
+    """Short and long contexts in one decode batch, admitted as others
+    finish: each sequence's ring and table are its own."""
+    eng = make_engine(toy)
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (3, 41, 18, 9, 30)]
+    outs = eng.generate_ids(prompts, GenerationConfig(max_new_tokens=25))
+    for prompt, out in zip(prompts, outs):
+        want = reference_logits(toy, prompt + out)[len(prompt) - 1 : -1]
+        assert (want.max(-1) - want[np.arange(25), out]).max() <= TOL
+
+
+# -- (b) the share test ---------------------------------------------------------
+
+
+def _expert_layer(toy, held):
+    """Weights of one toy expert layer holding ``held`` of the 32 experts
+    (cut out of one seeded whole layer), and 50 tokens."""
+    whole = moe.init_experts(32, 32, 64, 32, 1, jax.random.PRNGKey(3), jnp.float32)
+    whole = jax.tree.map(lambda a: a[0], whole)
+    whole["router_bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(4), (32,), jnp.float32)
+    first, count = held
+    cut = {
+        k: v[first : first + count] if k.startswith("w_") else v
+        for k, v in whole.items()
+    }
+    y = jax.random.normal(jax.random.PRNGKey(5), (50, 64), jnp.float32)
+    return cut, y
+
+
+SHARES = [(0, 8), (8, 8), (16, 8), (24, 8)]
+
+
+@pytest.mark.parametrize("held", SHARES + [(0, 32)], ids=str)
+def test_program_and_reference_leave_out_the_same_experts(toy, held):
+    p, y = _expert_layer(toy, held)
+    got, pairs, hit = moe.experts_apply(p, y, top_k=4, held=held)
+    want = toy[2]._experts(y, p, 4, held, True, None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    chosen, _ = moe.route(p, y, 4)
+    here = (np.asarray(chosen) >= held[0]) & (np.asarray(chosen) < sum(held))
+    assert int(pairs) == here.sum()
+    assert int(hit) == len(set(np.asarray(chosen)[here].tolist()))
+
+
+def test_the_parts_of_all_shares_add_up_to_the_uncut_layer(toy):
+    """What each of four holders of 8 experts computes of one expert layer,
+    routed over all 32 and normalised over all 4 chosen, adds up to what
+    the uncut reference gives: nothing stands in for the absent holders."""
+    whole, y = _expert_layer(toy, (0, 32))
+    want = toy[2]._experts(y, whole, 4, (0, 32), True, None)
+    parts = [
+        moe.experts_apply(_expert_layer(toy, held)[0], y, top_k=4, held=held)
+        for held in SHARES
+    ]
+    total = sum(np.asarray(out, np.float64) for out, _, _ in parts)
+    np.testing.assert_allclose(total, np.asarray(want), atol=1e-5)
+    assert sum(int(pairs) for _, pairs, _ in parts) == 50 * 4
+    assert all(np.abs(np.asarray(out)).max() > 0 for out, _, _ in parts)
+
+
+def test_more_pairs_than_the_usual_rows_take_the_whole_budget(toy):
+    """A router that sends every token to the held experts: more pairs
+    than twice a uniform router's, so the branch of N * k rows runs, and no
+    token is dropped."""
+    p, y = _expert_layer(toy, (8, 8))
+    p["router_bias"] = jnp.where(
+        (jnp.arange(32) >= 8) & (jnp.arange(32) < 16), 10.0, 0.0)
+    got, pairs, hit = moe.experts_apply(p, y, top_k=4, held=(8, 8))
+    assert int(pairs) == 50 * 4 and int(hit) == 8
+    want = toy[2]._experts(y, p, 4, (8, 8), True, None)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+# -- (c) the control fails the same comparison -------------------------------------
+
+
+def test_the_int8_control_fails_the_comparison(toy):
+    rng = np.random.default_rng(2)
+    tokens = rng.integers(0, 512, 48).tolist()
+    want = reference_logits(toy, tokens)
+    low = reference_logits(toy, tokens, quant="int8")
+    assert np.abs(low - want).max() > 10 * TOL
+    first = low.argmax(-1)
+    gaps = want.max(-1) - want[np.arange(48), first]
+    assert gaps.max() > 10 * TOL  # some token int8 puts first is not the best
+
+
+# -- (d) dense configurations through the new block, to the bit --------------------
+
+# CRC32 of the float32 logits of each prefill / prefill_suffix run, and the
+# served tokens, of the parent commit's engine (6598cc2, its three inlined
+# copies of the block) on this drive, computed by the parent itself
+PARENT = {
+    "bfloat16": [574494207, 3729361566, 2965980353, 4125194175],
+    "float32": [654391985, 3291833733, 537432005, 2138226089],
+}
+PARENT_TOKENS = [
+    [41] * 12,
+    [37, 33, 32, 66, 28, 28, 57, 28, 66, 66, 66, 33],
+    [41, 41, 41, 41, 41, 41, 41, 89, 24, 21, 41, 0],
+    [70, 41, 41, 41, 41, 66, 41, 41, 56, 66, 41, 66],
+]
+
+
+class _Hit:
+    def __init__(self, tokens, k, v):
+        self.tokens, self.k, self.v = tokens, k, v
+
+    def release(self):
+        pass
+
+
+class _ListPrefixCache:
+    def __init__(self, page):
+        self.page, self.entries, self.hits = page, [], 0
+
+    def insert(self, tokens, k, v):
+        self.entries.append((list(tokens), np.asarray(k), np.asarray(v)))
+
+    def lookup(self, prompt, max_tokens):
+        best = None
+        for tokens, k, v in self.entries:
+            n = 0
+            while n < min(len(tokens), max_tokens) and tokens[n] == prompt[n]:
+                n += 1
+            n -= n % self.page
+            if n and (best is None or n > best.tokens):
+                best = _Hit(n, k[:, :, : n // self.page], v[:, :, : n // self.page])
+        self.hits += best is not None
+        return best
+
+    def stats(self):
+        return {}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_dense_configuration_gives_the_parents_logits_to_the_bit(
+    dtype, monkeypatch
+):
+    # whole prompts in one prefill program, as the dense cells run them
+    monkeypatch.setattr(continuous, "PREFILL_SCORES_BYTES", 2**30)
+    cfg = tfm.ModelConfig(
+        vocab_size=97, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
+        d_ff=48, max_seq_len=64, dtype=jnp.dtype(dtype),
+    )
+    params = tfm.init_params(cfg, jax.random.PRNGKey(3))
+    eng = ContinuousBatchingEngine(
+        cfg, params, max_batch=2, page_size=8, n_pages=32,
+        prefix_cache=_ListPrefixCache(8),
+    )
+    seen = capture_prefill_logits(eng)
+    long = [3, 5, 7, 9, 11, 2, 4, 6, 8, 1, 3, 5, 7, 2, 9, 4, 6, 1]
+    gen = GenerationConfig(max_new_tokens=12)
+    outs = eng.generate_ids([long, [4, 8], long[:9]], gen)
+    outs += eng.generate_ids([long[:16] + [9, 9, 9]], gen)  # a prefix hit
+    assert eng.prefix_cache.hits == 2
+    assert outs == PARENT_TOKENS
+    crcs = [zlib.crc32(np.asarray(x, np.float32).tobytes()) for x in seen]
+    assert crcs == PARENT[dtype]
+
+
+def test_rms_eps_is_the_configurations(toy):
+    """The norm's epsilon follows ``ModelConfig.rms_eps``: the default is
+    the 1e-6 every dense configuration has run with."""
+    assert tfm.ModelConfig().rms_eps == 1e-6
+
+    def first_logits(eps):
+        cfg = tfm.ModelConfig(
+            vocab_size=97, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2,
+            d_ff=48, max_seq_len=64, dtype=jnp.float32, rms_eps=eps,
+        )
+        params = tfm.init_params(cfg, jax.random.PRNGKey(3))
+        eng = ContinuousBatchingEngine(
+            cfg, params, max_batch=1, page_size=8, n_pages=16)
+        seen = capture_prefill_logits(eng)
+        eng.generate_ids([[1, 2, 3]], GenerationConfig(max_new_tokens=1))
+        logits = tfm.forward(params, jnp.asarray([[1, 2, 3]]), cfg)
+        np.testing.assert_allclose(seen[0][:3], logits[0], atol=1e-5)
+        return seen[0][:3]
+
+    assert np.abs(first_logits(1e-6) - first_logits(1e-2)).max() > 1e-3
+
+
+# -- (e) the window class: rings reused in place, stalls named ----------------------
+
+
+def test_a_ring_is_reused_however_long_the_context(toy):
+    eng = make_engine(toy, max_batch=2, n_pages=40)
+    window = eng.pool.classes["window"]
+    assert window.usable_pages == 2 * 3
+    gen = GenerationConfig(max_new_tokens=60)
+    eng.submit([1, 2, 3], gen)
+    eng.step()
+    held, full_held = window.free_pages, eng.pool.classes["full"].free_pages
+    assert held == window.usable_pages - 3
+    assert full_held == eng.pool.classes["full"].usable_pages - 16
+    while eng.pending():
+        assert window.free_pages == held
+        eng.step()
+    assert eng.pool.free_pages == eng.pool.usable_pages
+    pages = window.alloc(3)
+    window.free(pages)
+    with pytest.raises(ValueError, match="double free: window page"):
+        window.free(pages)
+    with pytest.raises(ValueError, match="invalid window page"):
+        window.free([0])
+
+
+@pytest.mark.parametrize("short", ["full", "window"])
+def test_a_stalled_admit_names_the_class_that_was_short(toy, short):
+    tracing.SPANS.clear()
+    eng = make_engine(toy, max_batch=2, n_pages=12 if short == "full" else 64)
+    if short == "window":  # one ring left for two slots
+        taken = eng.pool.classes["window"].alloc(3)
+    gen = GenerationConfig(max_new_tokens=20)
+    eng.generate_ids([[5, 6, 7], [5, 6, 8]], gen)
+    admits = [s["args"] for s in tracing.SPANS.slices(cat="engine")
+              if s["name"] == "engine.admit"]
+    stalls = [a for a in admits if a["pool_stall"]]
+    assert stalls and all(a["pool_stall_class"] == short for a in stalls)
+    assert all("pool_stall_class" not in a for a in admits if not a["pool_stall"])
+    assert eng.stats()["admit_pool_stalls"] == len(stalls)
+    if short == "window":
+        eng.pool.classes["window"].free(taken)
+    assert eng.pool.free_pages == eng.pool.usable_pages
+
+
+def test_spans_carry_the_expert_counts_and_the_pages_by_class(toy):
+    tracing.SPANS.clear()
+    eng = make_engine(toy)
+    prompt = list(range(1, 30))
+    eng.generate_ids([prompt, [7, 8]], GenerationConfig(max_new_tokens=30))
+    spans = tracing.SPANS.slices(cat="engine")
+    prefills = [s["args"] for s in spans if s["name"] == "engine.prefill"]
+    # 29 tokens: 32 padded, 16 in the prefill program and four chunks of 4
+    assert [p["chunks"] for p in prefills] == [5, 1]
+    assert all(0 < p["moe_pairs_held"] <= p["t_pad"] * 4 * 12 for p in prefills)
+    decodes = [s["args"] for s in spans if s["name"] == "engine.decode"]
+    assert decodes
+    for d in decodes:
+        assert 0 < d["moe_pairs_held"] <= d["live"] * 4 * 12
+        assert 0 < d["moe_experts_hit"] <= min(8 * 12, d["moe_pairs_held"])
+        assert d["full_pages"] == d["pages_written"]
+        assert 0 < d["window_pages"] <= 3 * d["live"]
+    assert max(d["window_pages"] for d in decodes) == 3 * 2
+    assert max(d["full_pages"] for d in decodes) > 3 * 2
+
+
+# -- what the system cannot do for such a model yet, and says so -------------------
+
+
+@pytest.mark.parametrize(
+    "call",
+    [
+        lambda toy: make_engine(toy, prefix_cache=_ListPrefixCache(PAGE)),
+        lambda toy: make_engine(toy).prefill_extract(
+            [1, 2, 3], GenerationConfig(max_new_tokens=2)),
+        lambda toy: make_engine(toy).adopt_pages({}, None, None),
+        lambda toy: make_engine(toy).swap_params(toy[1]),
+        lambda toy: make_engine(toy, use_pallas_attention=True,
+                                pallas_interpret=True),
+        lambda toy: LLMEngine(toy[0], toy[1]),
+        lambda toy: tfm.forward(toy[1], jnp.zeros((1, 4), jnp.int32), toy[0]),
+        lambda toy: tfm.make_train_step(toy[0], None),
+        lambda toy: ContinuousBatchingEngine(
+            tfm.ModelConfig(n_experts=4, n_layers=1)),
+    ],
+    ids=["prefix_cache", "prefill_extract", "adopt_pages", "swap_params",
+         "pallas_decode", "LLMEngine", "forward", "train_step",
+         "switch_experts"],
+)
+def test_a_path_that_lacks_the_feature_raises_a_typed_error(toy, call):
+    with pytest.raises(tfm.UnsupportedModelFeature):
+        call(toy)
+
+
+def test_params_sig_tells_two_sets_of_weights_apart():
+    """Two deployments of one shape with other weights must not share
+    prefix-cache KV: the first leaf alone is a norm's vector of ones."""
+    from ray_tpu.llm.serving import _params_sig
+
+    cfg = tfm.ModelConfig(vocab_size=97, d_model=32, n_layers=2, n_heads=4,
+                          n_kv_heads=2, d_ff=48, dtype=jnp.float32)
+    a = tfm.init_params(cfg, jax.random.PRNGKey(1))
+    b = tfm.init_params(cfg, jax.random.PRNGKey(2))
+    first = jax.tree_util.tree_leaves(a)[0]
+    assert np.array_equal(first, jax.tree_util.tree_leaves(b)[0])
+    assert _params_sig(cfg, a, "llm") != _params_sig(cfg, b, "llm")
+    assert _params_sig(cfg, a, "llm") == _params_sig(
+        cfg, jax.tree.map(jnp.array, a), "llm")
+    assert _params_sig(cfg, a, "llm") != _params_sig(cfg, a, "other")

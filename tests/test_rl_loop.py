@@ -421,7 +421,7 @@ def test_swap_drain_deadline_force_evicts_and_sheds(monkeypatch):
     )
     # warm the decode compile so the pre-swap steps below emit tokens
     eng.generate_ids([[1, 2, 3]], GenerationConfig(max_new_tokens=1))
-    free_before = len(eng.pool._free)
+    free_before = eng.pool.free_pages
     rid = eng.submit([1, 2, 3, 4], GenerationConfig(max_new_tokens=64))
     eng.step()
     eng.step()  # a couple of tokens in flight before the swap begins
@@ -431,7 +431,7 @@ def test_swap_drain_deadline_force_evicts_and_sheds(monkeypatch):
     out = eng.results.pop(rid)
     assert 0 < len(out) < 64  # partial output recorded, reader unblocks
     assert not any(s.active for s in eng.slots)
-    assert len(eng.pool._free) == free_before  # pages freed
+    assert eng.pool.free_pages == free_before  # pages freed
     assert eng.stats()["swap_force_evicted"] == 1
 
     # typed shed while a drain has outlived its deadline
