@@ -1,10 +1,10 @@
-"""What ``test_run_toy`` and ``test_families`` pin of the program as PR 28
-left it, pinned again for the program since PR 29, whose ``decode_step``
-returns ``((tokens, counts), pool_k, pool_v)`` and whose ``ModelConfig``
-describes a stack by position: one token altered where it is produced
-comes out as ``correct`` false, on a dense toy and on a toy with windowed
-layers and experts (the key names of ``mimo-v2.5-l7-ep16``), and a dense
-configuration's family still builds the model it built.
+"""One token altered where it is produced comes out as ``correct`` false,
+on a dense toy and on a toy with windowed layers and experts (the key names
+of ``mimo-v2.5-l7-ep16``), and a dense configuration's family builds the
+weights and the model it has built since PR 28. The program's
+``decode_step`` returns ``((tokens, counts), pool_k, pool_v)`` and its
+``ModelConfig`` describes a stack by position (PR 29); the cases that
+pinned the program as it was before that went with PR 31.
 
 The windowed toy (``toy_moe/``) runs in float32: at its widths bfloat16
 reads gaps up to 0.77 beside a gross limit of 1.0 and int8's, so the rung
@@ -12,7 +12,9 @@ below would not come out apart. Four seeds (1101-1104, 8 s, 504 tokens,
 CPU): the program's every gap 0; int8's mean gap 0.0136-0.0197 and share
 over 0.03 9.5-12.5 %; limits 0.0009 and 1.2 %, the dense toy's."""
 import os
+import zlib
 
+import numpy as np
 import pytest
 
 import run as bench_run
@@ -105,6 +107,11 @@ def test_a_dense_family_builds_the_model_it_built(name):
             for leaf, dims in DENSE_BLOCK.items()
         },
     }
+    assert {s.dtype for s in jax.tree_util.tree_leaves(shapes)} == {
+        jnp.dtype("bfloat16")}
+    for seed, crc in pin.get("crc", {}).items():  # the seeded weights, bit for bit
+        leaves = jax.tree_util.tree_leaves(fam.make_weights(c, seed))
+        assert sum(zlib.crc32(np.asarray(x).tobytes()) for x in leaves) == crc
     model = fam.model_config(c)
     assert model == tfm.ModelConfig(
         vocab_size=pin["vocab"], d_model=pin["d"], n_layers=pin["layers"],

@@ -1,10 +1,12 @@
 """The whole run at a toy size on the CPU, the look for a chip skipped:
 the rehearsal of ``benchmarks/run.py``, the control, and the timed path
-broken underneath. Each drives ``serve.run`` in this process. The toy cell
-holds the same numbers as the real cells (``test_spec`` checks that), with
-limits of its own, set as theirs are from twelve toy seeds (1101-1112, 8 s,
-504 tokens a run, CPU): the program's mean gap 0.0001-0.0003 and share over
-0.03 0-0.4 %, int8's 0.0024-0.0058 and 3.6-6.3 %; limits 0.0009 and 1.2 %."""
+broken underneath (a dropped token, an answer that ends short by itself;
+the altered token is ``test_fault_toy``'s). Each drives ``serve.run`` in
+this process. The toy cell holds the same numbers as the real cells
+(``test_spec`` checks that), with limits of its own, set as theirs are from
+twelve toy seeds (1101-1112, 8 s, 504 tokens a run, CPU): the program's
+mean gap 0.0001-0.0003 and share over 0.03 0-0.4 %, int8's 0.0024-0.0058
+and 3.6-6.3 %; limits 0.0009 and 1.2 %."""
 import json
 import os
 
@@ -71,39 +73,6 @@ def test_control_comes_out_not_correct(seed):
     assert c["control_correct"]["value"] is False
     assert c["control_tail_share"]["value"] > c["tail_share"]["limit"]
     assert c["control_mean_gap"]["value"] > c["mean_gap"]["limit"]
-
-
-def test_one_altered_token_comes_out_not_correct(monkeypatch):
-    """The timed path broken underneath: one token of the whole run, a live
-    slot's at the twentieth decode step or the first after it that has
-    one, is altered where it is produced."""
-    from ray_tpu.llm.continuous import ContinuousBatchingEngine
-
-    real = ContinuousBatchingEngine._build_fns
-    calls = {"n": 0}
-
-    def broken_build(engine):
-        real(engine)
-        decode = engine._decode_step
-
-        def altered(*a, **kw):
-            nxt, k, v = decode(*a, **kw)
-            calls["n"] += 1
-            live = [i for i, s in enumerate(engine.slots) if s.active]
-            if calls["n"] >= 20 and live and not calls.get("altered"):
-                i = live[0]
-                nxt = nxt.at[i].set((nxt[i] + 1) % engine.cfg.vocab_size)
-                calls["altered"] = True
-            return nxt, k, v
-
-        engine._decode_step = altered
-
-    monkeypatch.setattr(ContinuousBatchingEngine, "_build_fns", broken_build)
-    res = toy_run(31, seconds=4.0)
-    assert calls["altered"]
-    assert res["correct"] is False
-    c = res["compared"]
-    assert c["gross_gaps"] == {"value": 1, "limit": 0, "over": check.GROSS_OVER}
 
 
 def test_dropped_token_comes_out_not_correct(monkeypatch):

@@ -4,10 +4,11 @@ import os
 import numpy as np
 import pytest
 
-from harness import stats, traffic
+from harness import metrics, served, spec, stats, traffic
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-TRAFFIC = os.path.join(os.path.dirname(HERE), "traffic")
+BENCH = os.path.dirname(HERE)
+TRAFFIC = os.path.join(BENCH, "traffic")
 
 
 def mix(name):
@@ -54,8 +55,9 @@ def test_chat_matches_the_issue():
 
 def test_reasoning_and_rag_match_the_issue():
     s = traffic.schedule(mix("reasoning"), 50)
-    assert len(s) == 89
-    assert sum(1 for r in s if r.due < -9.9) == 48  # the opening burst
+    assert len(s) == 300  # 5.0 / s over 10 s of ramp-in and 50 s
+    assert sum(1 for r in s if r.due < -9.95) == 48  # the opening burst, 1 ms apart
+    assert sum(r.max_new for r in s) == 196_973
     p = [r.prompt_len for r in s]
     o = [r.max_new for r in s]
     assert min(p) >= 113 and max(p) <= 512
@@ -94,3 +96,108 @@ def test_order_seed_is_the_median_order_of_a_replay(name, token_s):
     ranked = sorted(counts, key=counts.get)
     assert ranked.index(m["arrivals"]["order_seed"]) in (19, 20, 21, 22)
     assert counts[ranked[-1]] > 1.05 * counts[ranked[0]]
+
+
+def replay_slots(schedule, slots, step_s, seconds, prefill_s_per_ktok=0.05):
+    """A plain replay of a schedule through ``slots`` slots at a fixed step
+    interval: a replay, not a measurement. Every step each live slot gives
+    one token; a free slot takes the oldest request that is due, whose
+    prefill holds every slot up (``prefill_s_per_ktok`` of its prompt) and
+    gives its first token. Returns the tokens stamped inside the window,
+    the requests due and not yet taken up at the close, and the most
+    requests in flight (offered less finished) at any step."""
+    reqs = sorted(schedule, key=lambda r: r.due)
+    t, due, queue, live = reqs[0].due, 0, [], []
+    tokens = finished = in_flight = 0
+    while t < seconds:
+        while due < len(reqs) and reqs[due].due <= t:
+            queue.append(reqs[due])
+            due += 1
+        while queue and len(live) < slots:
+            r = queue.pop(0)
+            t += prefill_s_per_ktok * r.prompt_len / 1000.0
+            tokens += 0 <= t < seconds
+            live.append(r.max_new - 1)
+        tokens += len(live) * (0 <= t < seconds)
+        live = [left - 1 for left in live]
+        finished += live.count(0)
+        live = [left for left in live if left > 0]
+        in_flight = max(in_flight, due - finished)
+        t += step_s
+    return {"tokens": tokens, "queued": len(queue), "in_flight": in_flight}
+
+
+# the parent's measured step interval, then what a v5e allows this model
+# soon: PR 30's decode kernel read 11.3 ms (ledger, PR 30)
+REASONING_STEP_S = (0.0366, 0.030, 0.025, 0.020, 0.015, 0.0113, 0.011)
+
+
+def test_reasoning_schedule_outlasts_the_engine():
+    """A cell judged on ``tokens_per_s`` above its knee needs a schedule
+    that outlasts the engine: as the step interval shortens the tokens
+    inside the window never fall and requests are still queued at the
+    close. The schedule it replaced (89 requests) reads 834 tokens/s at
+    36.6 ms and 610 at 11.3 with none queued, which is what refused PR 30.
+    At the slowest interval the requests in flight stay under the router's
+    ``serve_admission_max_inflight``, past which it sheds."""
+    from ray_tpu.config import cfg
+
+    s = traffic.schedule(mix("reasoning"), 50)
+    runs = [replay_slots(s, 32, step_s, 50) for step_s in REASONING_STEP_S]
+    counts = [r["tokens"] for r in runs]
+    assert counts == sorted(counts) and counts[-1] > 3 * counts[0]
+    assert all(r["queued"] > 0 for r in runs)
+    assert runs[0]["queued"] > 0.6 * len(s)
+    assert runs[0]["in_flight"] <= cfg.serve_admission_max_inflight - 10
+    assert replay_slots(s, 32, 0.009, 50)["queued"] == 0  # spent by here
+    m = mix("reasoning")
+    old = traffic.schedule(
+        dict(m, arrivals=dict(m["arrivals"], rate_per_s=1.48, order_seed=0)), 50)
+    spent = [replay_slots(old, 32, step_s, 50) for step_s in (0.0366, 0.0113)]
+    assert spent[1]["tokens"] < 0.75 * spent[0]["tokens"]
+    assert spent[0]["queued"] == spent[1]["queued"] == 0
+
+
+def test_reasoning_order_seed_is_the_median_order_at_a_full_batch():
+    """``reasoning``'s ``order_seed`` by the same rule as the others',
+    replayed through its 32 slots at the measured step interval. At a full
+    batch the order decides only how many admissions hold the slots up, so
+    the forty orders lie within a hundredth of each other: what the order
+    moved in the spent schedule (a tenth) is gone."""
+    m = mix("reasoning")
+
+    def tokens(order):
+        s = traffic.schedule(
+            dict(m, arrivals=dict(m["arrivals"], order_seed=order)), 50)
+        return replay_slots(s, 32, REASONING_STEP_S[0], 50)["tokens"]
+
+    counts = {o: tokens(o) for o in range(40)}
+    ranked = sorted(counts, key=lambda o: (counts[o], o))
+    assert ranked.index(m["arrivals"]["order_seed"]) in (19, 20, 21, 22)
+    assert counts[ranked[-1]] < 1.01 * counts[ranked[0]]
+
+
+def test_schedule_unspent_reader_on_a_toy_run():
+    """Four requests sent by the close: two with a token inside the window,
+    one whose first token came after the close, one with none. One sent
+    after the close and one never sent are not counted."""
+    read = spec.load_reader("schedule_unspent_pct", BENCH)
+
+    def client(sent, stamps):
+        return served.Client(traffic.Request(0, 0.0, 8, 4), 0.0, np.zeros(8),
+                             sent=sent, stamps=stamps)
+
+    def run(clients):
+        return metrics.Run(
+            cfg={}, mix={}, base=BENCH, peaks={}, t_open=100.0, t_close=150.0,
+            setup_s=1.0, clients=clients, decode_log=[], prefill_log=[],
+            window_compiles=0, memory_peak_bytes=None)
+
+    clients = [
+        client(95.0, [96.0, 120.0]), client(140.0, [149.9]),
+        client(149.0, [150.0, 150.1]), client(149.5, []),
+        client(150.2, []), client(None, []),
+    ]
+    assert read(run(clients)) == pytest.approx(50.0)
+    assert read(run(clients[:2])) == 0.0  # a spent schedule reads 0
+    assert read(run(clients[4:])) is None  # nothing sent: nothing to read
