@@ -318,7 +318,7 @@ def model_bench() -> dict:
         train_loss=train_loss,
     )
 
-    # --- paged decode: kernel vs gather at the engine's defaults ---------
+    # --- paged decode at the engine's defaults ----------------------------
     from ray_tpu.llm.continuous import ContinuousBatchingEngine
     from ray_tpu.llm.engine import GenerationConfig
 
@@ -327,44 +327,39 @@ def model_bench() -> dict:
     gen = GenerationConfig(max_new_tokens=512, temperature=0.0)
     prompts = [list(range(1, 97)) for _ in range(8)]
 
-    def decode_rate(use_pallas: bool) -> float:
-        # the kernel runs compiled or the tier fails: a rate is never
-        # reported from an interpreted kernel
-        eng = ContinuousBatchingEngine(
-            dcfg, dparams, use_pallas_attention=use_pallas
-        )  # defaults: max_batch=8, page_size=16, n_pages=256
-        for p in prompts:
-            eng.submit(p, gen)
-        eng.step()  # admit all 8 slots + first decode (compiles)
-        # device-chained decode: token t's output feeds token t+1 with no
-        # host readback inside the timed loop
-        pk, pv = eng.pool.k, eng.pool.v
-        toks_d, pos = eng.cur_tokens, eng.positions
-        n_dec = 256
-        # the step takes the pool donated: rebind it from every call
-        (warm, _), pk, pv = eng._decode_step(  # warm the chained shapes
+    # the engine reads the platform: the Pallas paged-attention kernel on a
+    # TPU, the XLA gather elsewhere (ops/paged_attention.py)
+    eng = ContinuousBatchingEngine(
+        dcfg, dparams
+    )  # defaults: max_batch=8, page_size=16, n_pages=256
+    for p in prompts:
+        eng.submit(p, gen)
+    eng.step()  # admit all 8 slots + first decode (compiles)
+    # device-chained decode: token t's output feeds token t+1 with no
+    # host readback inside the timed loop
+    pk, pv = eng.pool.k, eng.pool.v
+    toks_d, pos = eng.cur_tokens, eng.positions
+    n_dec = 256
+    # the step takes the pool donated: rebind it from every call
+    (warm, _), pk, pv = eng._decode_step(  # warm the chained shapes
+        eng.params, pk, pv, eng.block_tables, pos, toks_d,
+        eng.active_mask, eng.temps, eng.seeds,
+    )
+    np.asarray(warm)
+    t0 = time.perf_counter()
+    for _ in range(n_dec):
+        (toks_d, _), pk, pv = eng._decode_step(
             eng.params, pk, pv, eng.block_tables, pos, toks_d,
             eng.active_mask, eng.temps, eng.seeds,
         )
-        np.asarray(warm)
-        t0 = time.perf_counter()
-        for _ in range(n_dec):
-            (toks_d, _), pk, pv = eng._decode_step(
-                eng.params, pk, pv, eng.block_tables, pos, toks_d,
-                eng.active_mask, eng.temps, eng.seeds,
-            )
-            pos = pos + 1
-        # final-token readback forces the whole device-chained sequence
-        np.asarray(toks_d)
-        return 8 * n_dec / (time.perf_counter() - t0)
-
-    gather_rate = decode_rate(False)
-    pallas_rate = decode_rate(True)
+        pos = pos + 1
+    # final-token readback forces the whole device-chained sequence
+    np.asarray(toks_d)
     out.update(
-        decode_tokens_per_s=round(max(gather_rate, pallas_rate), 1),
-        decode_tokens_per_s_gather=round(gather_rate, 1),
-        decode_tokens_per_s_pallas=round(pallas_rate, 1),
-        paged_kernel_speedup_vs_gather=round(pallas_rate / gather_rate, 3),
+        decode_tokens_per_s=round(
+            8 * n_dec / (time.perf_counter() - t0), 1
+        ),
+        decode_attention_path=eng._attn_kernel or "xla gather",
     )
     return out
 

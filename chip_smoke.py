@@ -73,7 +73,6 @@ class Sizes:
     # serve
     max_batch: int
     n_pages: int
-    pallas_pages: int
     prompt_lens: tuple
     new_tokens: int
     # train
@@ -90,7 +89,7 @@ FULL = Sizes(
     # the widest configuration the repo names (bench.py model_bench)
     vocab=32_000, d_model=2048, n_layers=12, n_heads=16, n_kv_heads=16,
     d_ff=5504, max_seq_len=1024, dtype="bfloat16",
-    max_batch=8, n_pages=2048, pallas_pages=256,
+    max_batch=8, n_pages=2048,
     # five prefill buckets of 16-token pages: 64, 128, 256, 512, 768
     prompt_lens=(64, 120, 128, 250, 256, 500, 512, 768), new_tokens=64,
     batch=8, seq=1024, steps=4, mesh_steps=3,
@@ -101,7 +100,7 @@ TOY = Sizes(
     sim_nodes=64, sim_demands=3000, solve_nodes=64, solve_shapes=16,
     vocab=512, d_model=128, n_layers=2, n_heads=4, n_kv_heads=4,
     d_ff=256, max_seq_len=256, dtype="bfloat16",
-    max_batch=4, n_pages=64, pallas_pages=32,
+    max_batch=4, n_pages=64,
     prompt_lens=(16, 30, 32, 60, 64, 100, 112, 128), new_tokens=8,
     batch=4, seq=256, steps=4, mesh_steps=2,
     cluster_tasks=40,
@@ -592,7 +591,8 @@ def serve_phase(sz: Sizes, on_chip: bool, clock: CompileClock, dev) -> None:
         "requests": len(outs),
         "prompt_lens": list(sz.prompt_lens),
         "new_tokens_each": sz.new_tokens,
-        "attention_path": "xla-gather (engine default)",
+        # the engine reads the platform: the Pallas kernel on a TPU
+        "attention_path": "pallas kernel" if on_chip else "xla gather",
         "pool": f"{sz.n_pages} pages x 16 tokens",
         "pool_bytes": 2 * cfg.n_layers * cfg.n_kv_heads * sz.n_pages * 16
         * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize,
@@ -603,25 +603,35 @@ def serve_phase(sz: Sizes, on_chip: bool, clock: CompileClock, dev) -> None:
     ref = _check_against_reference(cfg, params, prompts, outs, "serve")
     emit("serve.requests", **served, **ref)
 
-    # -- the Pallas decode kernel, compiled, at the pool it fits -----------
+    # -- the Pallas decode kernel against the XLA formulation, same pool ----
     short = [p[:48] for p in prompts[: sz.max_batch]]
     gen = GenerationConfig(max_new_tokens=min(sz.new_tokens, 16))
     per_path = {}
-    for name, use_pallas in (("gather", False), ("pallas", True)):
+    # on the chip the engine takes the compiled kernel by itself; the
+    # rehearsal on the CPU runs it interpreted, through the tests' hook,
+    # which also holds the chip's engine to the gather for the comparison
+    for name, kernel in (
+        ("gather", None), ("pallas", "compiled" if on_chip else "interpret"),
+    ):
         eng = ContinuousBatchingEngine(
             cfg, params, max_batch=sz.max_batch, page_size=16,
-            n_pages=sz.pallas_pages, tokenizer=tok,
-            use_pallas_attention=use_pallas, pallas_interpret=not on_chip,
+            n_pages=sz.n_pages, tokenizer=tok,
         )
+        check(
+            eng._attn_kernel == ("compiled" if on_chip else None),
+            f"the engine chose {eng._attn_kernel!r} for its decode attention",
+        )
+        eng._attn_kernel = kernel
         per_path[name] = eng.generate_ids(short, gen)
-        if use_pallas and on_chip:
+        if kernel and on_chip:
             text = eng._decode_step.lower(
                 eng.params, eng.pool.k, eng.pool.v, eng.block_tables,
                 eng.positions, eng.cur_tokens, eng.active_mask, eng.temps,
                 eng.seeds,
             ).compile().as_text()
             check(
-                "tpu_custom_call" in text,
+                "tpu_custom_call" in text
+                and "paged_attention_decode" in text,
                 "the Pallas decode step holds no compiled kernel",
             )
         del eng
@@ -638,7 +648,7 @@ def serve_phase(sz: Sizes, on_chip: bool, clock: CompileClock, dev) -> None:
     )
     emit(
         "serve.pallas_decode",
-        pool=f"{sz.pallas_pages} pages x 16 tokens",
+        pool=f"{sz.n_pages} pages x 16 tokens",
         compiled_kernel=on_chip, interpreted=not on_chip,
         tokens_equal_to_gather_path=same, **pallas_ref,
     )

@@ -5,10 +5,9 @@ The serving tier the reference delegates to vLLM-class engines
 rebuilt TPU-first in the JetStream/PagedAttention mold:
 
 - **Paged KV pool**: one device buffer of fixed-size pages
-  ``[n_layers, kv_heads, n_pages, page, head_dim]`` (head-major, so the
-  Pallas decode kernel slices a head's pool without any transpose)
-  shared by every sequence; a per-slot block table maps logical
-  positions to pages. All
+  ``[n_layers, kv_heads, n_pages, page, head_dim]`` (head-major, so one
+  DMA of the decode kernel brings a page of every head) shared by every
+  sequence; a per-slot block table maps logical positions to pages. All
   shapes static — XLA compiles exactly two programs (per prefill bucket):
   one prefill, one decode step.
 - **Continuous batching**: B decode slots; requests admit into free slots
@@ -16,10 +15,12 @@ rebuilt TPU-first in the JetStream/PagedAttention mold:
   the live batch size. Admission backpressures on free pages — the pool,
   not the batch, is the capacity.
 - **Decode step**: one token for ALL active slots per jit call; the KV
-  write is a per-slot scatter into (page, offset) and attention gathers
-  each slot's pages back into a contiguous [S_max] view (the TPU-friendly
-  formulation of paged attention: gathers + one big einsum, no dynamic
-  shapes).
+  write is a per-slot scatter into (page, offset). On a TPU, attention
+  over the ``full`` class of page is the Pallas kernel of
+  ``ops/paged_attention.py``, which reads the pages that hold live
+  positions out of the pool where it lies; where there is no TPU, and for
+  a windowed layer's ring, attention gathers each slot's table into a
+  contiguous view (gathers + one big einsum, no dynamic shapes).
 
 Reference files for parity intent: vllm paged attention + continuous
 batching scheduler; JetStream's slot/page design is the public TPU
@@ -167,8 +168,8 @@ class PagedKVPool:
 
     ``k`` and ``v`` map a class's name to its array
     ``[layers of the class, KV heads, pages, page, head size]``, head-major
-    (the Pallas decode kernel and the gather path both read per-head
-    slices without a transpose); K and V heads may differ in size, and K's
+    (the Pallas decode kernel and the gather path both read it without a
+    transpose); K and V heads may differ in size, and K's
     is ``k_dim = stored_key_width(head_dim)``."""
 
     def __init__(self, cfg: tfm.ModelConfig, n_pages: int, page: int,
@@ -340,8 +341,6 @@ class ContinuousBatchingEngine:
         n_pages: int = 256,
         max_pages_per_seq: Optional[int] = None,
         tokenizer: Optional[Any] = None,
-        use_pallas_attention: bool = False,
-        pallas_interpret: bool = False,
         prefix_cache: Optional[Any] = None,
         model_id: str = "base",
     ):
@@ -360,32 +359,6 @@ class ContinuousBatchingEngine:
                 "with prefix_cache=False"
             )
         configure_compile_cache()
-        if use_pallas_attention and (
-            self.windowed
-            or cfg.v_head_dim != cfg.head_dim
-            or stored_key_width(cfg.head_dim) != cfg.head_dim
-        ):
-            raise tfm.UnsupportedModelFeature(
-                "use_pallas_attention=True: the paged-decode kernel reads "
-                "one class of page with K and V heads of one size"
-            )
-        if use_pallas_attention and not pallas_interpret:
-            from ray_tpu.ops import paged_attention as pa
-
-            staged = pa.staged_vmem_bytes(
-                n_pages, page_size, cfg.head_dim, cfg.dtype
-            )
-            if staged > pa.SCOPED_VMEM_BYTES:
-                raise ValueError(
-                    "use_pallas_attention=True: the paged-decode kernel "
-                    "stages one head's whole pool slice in VMEM, "
-                    f"{staged / 2**20:.1f} MiB for n_pages={n_pages} x "
-                    f"page_size={page_size} x head_dim={cfg.head_dim} x "
-                    f"{jnp.dtype(cfg.dtype).name} (K and V, double-"
-                    f"buffered), over the {pa.SCOPED_VMEM_BYTES / 2**20:.0f} "
-                    "MiB a kernel may use; shrink the pool or leave the "
-                    "default gather path on"
-                )
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
@@ -406,12 +379,13 @@ class ContinuousBatchingEngine:
             max(1, self.max_prefill_tokens // 4 // page_size) * page_size
         )
         self.tokenizer = tokenizer or ByteTokenizer()
-        # opt-in Pallas paged-attention decode (ops/paged_attention.py);
-        # the XLA gather formulation stays the default. The pool is
-        # head-major, so the kernel slices per-head pool views with zero
-        # data movement (real-TPU profiling decides the default flip)
-        self.use_pallas_attention = use_pallas_attention
-        self.pallas_interpret = pallas_interpret
+        # how decode_step attends over the ``full`` class of page: on a TPU
+        # the Pallas kernel (ops/paged_attention.py), elsewhere the XLA
+        # gather. Read from the platform, set by no caller; the CPU tests
+        # put "interpret" here to run the kernel interpreted
+        self._attn_kernel = (
+            "compiled" if jax.default_backend() == "tpu" else None
+        )
         # optional cross-replica prefix/KV cache (serve.prefix_cache):
         # page-aligned prompt prefixes restore from pinned shm views and
         # only the suffix pays prefill compute
@@ -569,14 +543,21 @@ class ContinuousBatchingEngine:
             """One token for every slot. Inactive slots run the same
             math (one trace) but their KV writes are redirected to the
             reserved scratch page 0, so they can never collide with a
-            live slot's pages in the scatter. Returns the tokens, int32[2]
+            live slot's pages in the scatter. Returns the tokens, int32[6]
             (token-expert pairs computed here and held experts hit, summed
-            over the expert layers; live slots only) and the pool."""
+            over the expert layers, live slots only; then of the attention
+            layers of the ``full`` class how many ran in the Pallas kernel
+            and how many there were, the pages the kernel walked and the
+            table entries of those layers, which the gather would have
+            read) and the pool."""
             b = self.B
             h = params["embed"][tokens].astype(cfg.dtype)  # [B, D]
+            # positions a slot's query sees, itself among them; none if idle
+            lengths = jnp.where(active, positions + 1, 0)
+            live_pages = jnp.sum(-(-lengths // page))
 
             def attend(kind, layer, q, k, v, sink, cache):
-                pool_k, pool_v = cache
+                pool_k, pool_v, walked = cache
                 name, table = kind.name, tables[kind.name]
                 pk, pv = pool_k[name], pool_v[name]
                 # a windowed layer's table is the slot's ring of pages
@@ -594,25 +575,22 @@ class ContinuousBatchingEngine:
                     pk, layer, page_ids, offsets, active, stored(k)
                 )
                 pv = write_token(pv, layer, page_ids, offsets, active, v)
-                if self.use_pallas_attention:
+                # separate paths by the layer's kind: a ring's mask and
+                # sink are another computation, not other parameters of the
+                # kernel's. The kernel reads the pool where it lies, after
+                # the scatter; the layer is its operand, not a slice
+                in_kernel = bool(self._attn_kernel) and not kind.window
+                if in_kernel:
                     from ray_tpu.ops.paged_attention import (
                         paged_attention_decode,
                     )
 
-                    kh = cfg.n_kv_heads
-                    groups = cfg.n_heads // kh
-                    qh = q.reshape(b, kh, groups, cfg.head_dim)
-                    # pool is head-major: the kernel slices per head with
-                    # ZERO data movement
+                    qh = q.reshape(b, kind.kv_heads, -1, cfg.head_dim)
                     attn = paged_attention_decode(
-                        qh,
-                        pk[layer],
-                        pv[layer],
-                        table,
-                        positions + 1,
-                        page_size=page,
-                        interpret=self.pallas_interpret,
-                    ).reshape(b, cfg.n_heads * cfg.head_dim)
+                        stored(qh), pk, pv, layer, table, lengths,
+                        scale=cfg.head_dim**-0.5,
+                        interpret=self._attn_kernel == "interpret",
+                    ).reshape(b, cfg.n_heads * cfg.v_head_dim)
                 else:
                     if kind.window:
                         held = ring_positions(positions)
@@ -626,10 +604,17 @@ class ContinuousBatchingEngine:
                     attn = _attention_pages(
                         kind, q, k_pages, v_pages, valid, sink
                     )
-                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
+                if not kind.window:
+                    walked = walked + jnp.stack([
+                        int(in_kernel), 1, in_kernel * live_pages, table.size
+                    ]).astype(jnp.int32)
+                return attn, (
+                    {**pool_k, name: pk}, {**pool_v, name: pv}, walked
+                )
 
-            h, (pool_k, pool_v), moe = tfm.run_stack(
-                cfg, params["blocks"], h, positions, (pool_k, pool_v),
+            h, (pool_k, pool_v, walked), moe = tfm.run_stack(
+                cfg, params["blocks"], h, positions,
+                (pool_k, pool_v, jnp.zeros((4,), jnp.int32)),
                 attend, live=active,
             )
             logits = head_logits(params, h)
@@ -645,7 +630,7 @@ class ContinuousBatchingEngine:
                 )
             )(seeds, positions, logits, temps).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
-            return (nxt, moe), pool_k, pool_v
+            return (nxt, jnp.concatenate([moe, walked])), pool_k, pool_v
 
         def ring_write(pool, layer, table, first_page, x):
             """The last pages of a block of whole pages into a slot's
@@ -1437,21 +1422,30 @@ class ContinuousBatchingEngine:
                             ),
                         )
                 with decode:
-                    nxt, moe = self._write_pool(
+                    nxt, counts = self._write_pool(
                         lambda k, v: self._decode_step(
                             self.params, k, v, self.block_tables,
                             self.positions, self.cur_tokens,
                             self.active_mask, self.temps, self.seeds,
                         )
                     )
+                if decode:
+                    counts.copy_to_host_async()  # beside the tokens' copy
                 # where the host waits for the step's tokens
                 with tracing.span("engine.readback", "engine"):
                     nxt_h = np.asarray(nxt)
-                if decode and self.cfg.n_routed_experts:
-                    # the step's two sums are known once its tokens are:
-                    # the ring's record shares the span's args
-                    pairs, hit = np.asarray(moe).tolist()
-                    decode.set(moe_pairs_held=pairs, moe_experts_hit=hit)
+                if decode:
+                    # the step's sums are known once its tokens are: the
+                    # ring's record shares the span's args
+                    pairs, hit, in_kernel, full, walked, entries = (
+                        np.asarray(counts).tolist()
+                    )
+                    decode.set(
+                        attn_kernel_layers=in_kernel, attn_full_layers=full,
+                        attn_pages_walked=walked, attn_table_entries=entries,
+                    )
+                    if self.cfg.n_routed_experts:
+                        decode.set(moe_pairs_held=pairs, moe_experts_hit=hit)
                 self.positions = self.positions + jnp.where(
                     self.active_mask, 1, 0
                 )

@@ -86,136 +86,31 @@ def test_flash_attention(one_chip, t, direction):
     assert text.count(f'"{KERNEL}"') == want
 
 
-# -- paged decode -------------------------------------------------------------
+# -- the serving engine's programs at the benchmark's geometries ---------------
+
+PAGE = 16
 
 
-def _paged_args(sharding, n_pages):
-    # chip_smoke's widths: 8 slots, 16 KV heads x 128, 16-token pages,
-    # max_seq_len 1024 -> 64 table entries a slot
-    return _shapes(
-        sharding,
-        ((8, 16, 1, 128), jnp.bfloat16),
-        ((16, n_pages, 16, 128), jnp.bfloat16),
-        ((16, n_pages, 16, 128), jnp.bfloat16),
-        ((8, 64), jnp.int32),
-        ((8,), jnp.int32),
-    )
-
-
-def test_paged_decode_at_the_smoke_geometry(one_chip):
-    from ray_tpu.ops.paged_attention import paged_attention_decode
-
-    text = _compile(
-        paged_attention_decode, *_paged_args(one_chip, 256), page_size=16
-    ).as_text()
-    assert KERNEL in text
-
-
-def test_paged_decode_refuses_a_deployment_sized_pool(one_chip):
-    """Today's limit, pinned: the kernel stages one head's whole pool slice
-    in VMEM, so a 4,096-page pool (64 MiB staged against 16 MiB) does not
-    compile. The PR that makes the kernel walk pages in HBM flips this
-    test — and ``ContinuousBatchingEngine``'s construction check with it."""
-    from ray_tpu.ops import paged_attention as pa
-
-    assert pa.staged_vmem_bytes(4096, 16, 128, jnp.bfloat16) == 64 * 2**20
-    assert pa.staged_vmem_bytes(1024, 16, 128, jnp.bfloat16) == (
-        pa.SCOPED_VMEM_BYTES
-    )
-    with pytest.raises(Exception, match="vmem"):
-        _compile(
-            pa.paged_attention_decode,
-            *_paged_args(one_chip, 4096),
-            page_size=16,
-        )
-    # and the largest pool the engine's check lets through does compile
-    _compile(
-        pa.paged_attention_decode, *_paged_args(one_chip, 1024), page_size=16
-    )
-
-
-# -- the serving engine's pool writers at the benchmark's pool geometry --------
-
-
-@pytest.mark.parametrize("program", ["decode_step", "prefill"])
-def test_pool_writers_copy_no_pool(one_chip, program):
-    """``mistral-7b-v0.3-l16``'s cells: 32 slots, a [16,8,2048,16,128] bf16
-    pool (1 GiB of K, 1 GiB of V), contexts to 2,560. A program that writes
-    a few rows of the pool and returns it must alias it, not copy it: no
-    ``copy`` of the pool's shape in the optimised module, and temporaries
-    plus results that alias no operand smaller than one pool. Undonated,
-    the same programs held ``copy.101``/``copy.102`` and 2 GiB of results
-    of their own, 7 and 12 ms a step on the chip (PERF.md, PR 27)."""
-    from ray_tpu.llm.continuous import ContinuousBatchingEngine
+def _model(name):
+    """The three configurations' widths, slots and pages of the full class
+    (``benchmarks/configs``), contexts to ``max_seq_len``."""
     from ray_tpu.models import transformer as tfm
 
-    cfg = tfm.ModelConfig(
-        vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
-        n_kv_heads=8, d_ff=14336, max_seq_len=2560, rope_theta=1e6,
-        dtype=jnp.bfloat16,
-    )
-    slots, page, n_pages = 32, 16, 2048
-    table = cfg.max_seq_len // page
-    # the programs close over the geometry of a slot, not over the pool's
-    # size: a pool of one slot's pages keeps the engine built here small
-    eng = ContinuousBatchingEngine(
-        cfg, params={}, max_batch=slots, page_size=page,
-        n_pages=table + 1, max_pages_per_seq=table,
-    )
-    params = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))),
-    )
-    pool_shape = (cfg.n_layers, cfg.n_kv_heads, n_pages, page, cfg.head_dim)
-    (pool,) = _shapes(one_chip, (pool_shape, cfg.dtype))
-    if program == "decode_step":
-        rest = _shapes(
-            one_chip,
-            ((slots, table), jnp.int32), ((slots,), jnp.int32),
-            ((slots,), jnp.int32), ((slots,), jnp.bool_),
-            ((slots,), jnp.float32), ((slots,), jnp.uint32),
-        )
-        tables, *rest = rest
-        lowered = eng._decode_step.lower(
-            params, {"full": pool}, {"full": pool}, {"full": tables}, *rest
-        )
-    else:
-        t_pad = 2048  # the longest prompt of the cells
-        tokens, pages = _shapes(
-            one_chip, ((t_pad,), jnp.int32), ((t_pad // page,), jnp.int32)
-        )
-        lowered = eng._prefill.lower(
-            params, {"full": pool}, {"full": pool}, tokens, t_pad,
-            {"full": pages},
-        )
-    compiled = lowered.compile()
-    dims = ",".join(map(str, pool_shape))
-    copies = re.findall(
-        rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text()
-    )
-    assert not copies
-    mem = compiled.memory_analysis()
-    one_pool = 2 * pool.size  # bytes of K alone
-    assert mem.alias_size_in_bytes == 2 * one_pool
-    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert mem.temp_size_in_bytes + own < one_pool
-
-
-# -- a stack by position at the widths of `mimo-v2.5-l7-ep16` ------------------
-
-
-@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
-def test_two_page_classes_and_held_experts_copy_no_pool(one_chip, program):
-    """``mimo-v2.5-l7-ep16.mixed``: 64 slots, contexts to 8,192, a full
-    class of 16,384 pages and 64 rings of 9 pages, keys of 192 stored 256
-    wide, 16 of 256 experts held. Every program aliases all four pool
-    arrays and holds no copy of one: with keys stored 192 wide the chip's
-    compiler gave both K pools another layout inside the program and copied
-    each twice a run. The grouped expert matmul is a kernel."""
-    from ray_tpu.llm.continuous import ContinuousBatchingEngine
-    from ray_tpu.models import transformer as tfm
-
-    cfg = tfm.ModelConfig(
+    if name == "mistral":  # 8 KV heads, groups of 4, table 160
+        return tfm.ModelConfig(
+            vocab_size=32768, d_model=4096, n_layers=16, n_heads=32,
+            n_kv_heads=8, d_ff=14336, max_seq_len=2560, rope_theta=1e6,
+            dtype=jnp.bfloat16,
+        ), 32, 2048
+    if name == "internlm2":  # 8 KV heads, groups of 2, table 96
+        return tfm.ModelConfig(
+            vocab_size=92544, d_model=2048, n_layers=24, n_heads=16,
+            n_kv_heads=8, d_ff=8192, max_seq_len=1536, rope_theta=1e6,
+            dtype=jnp.bfloat16,
+        ), 32, 2560
+    # mimo-v2.5-l7-ep16: a full class of 4 KV heads, groups of 16, keys of
+    # 192 stored 256 wide, values 128, table 512; 64 rings of 9 pages
+    return tfm.ModelConfig(
         vocab_size=19072, d_model=4096, n_layers=7, n_heads=64, n_kv_heads=4,
         d_ff=16384, max_seq_len=8192, rope_theta=1e7, dtype=jnp.bfloat16,
         rms_eps=1e-5, head_dim=192, v_head_dim=128, rotary_dim=64,
@@ -225,63 +120,196 @@ def test_two_page_classes_and_held_experts_copy_no_pool(one_chip, program):
         window=128, window_kv_heads=8, window_rope_theta=1e4,
         window_sink=True, d_ff_expert=2048, n_routed_experts=256,
         experts_per_token=8, experts_held=(0, 16),
+    ), 64, 16384
+
+
+class _Deployment:
+    """An engine built as on the chip, and its operands as shapes there:
+    the pools at the cell's size, a table a class, the weights."""
+
+    def __init__(self, name, sharding):
+        from ray_tpu.llm.continuous import ContinuousBatchingEngine
+        from ray_tpu.models import transformer as tfm
+
+        self.cfg, self.slots, n_pages = _model(name)
+        cfg, slots = self.cfg, self.slots
+        table = cfg.max_seq_len // PAGE
+        # the programs close over the geometry of a slot, not over the
+        # pool's size: a pool of one slot's pages keeps the engine small.
+        # The engine reads the platform to choose its decode attention, and
+        # the attached backend here is the CPU
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            self.eng = ContinuousBatchingEngine(
+                cfg, params={}, max_batch=slots, page_size=PAGE,
+                n_pages=table + 1, max_pages_per_seq=table,
+            )
+        assert self.eng._attn_kernel == "compiled"
+
+        def shape(dims, dtype):
+            return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+        self.shape = shape
+        self.params = jax.tree.map(
+            lambda x: shape(x.shape, x.dtype),
+            jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))),
+        )
+        self.pool_k, self.pool_v, self.tables = {}, {}, {}
+        for cls, (layers, kind) in cfg.kv_classes().items():
+            n = slots * self.eng.pool.ring_pages + 1 if kind.window else n_pages
+            lead = (layers, kind.kv_heads, n, PAGE)
+            self.pool_k[cls] = shape(lead + (self.eng.pool.k_dim,), cfg.dtype)
+            self.pool_v[cls] = shape(lead + (cfg.v_head_dim,), cfg.dtype)
+            self.tables[cls] = shape(
+                (slots, self.eng._table_len(cls)), jnp.int32
+            )
+        self.pools = [*self.pool_k.values(), *self.pool_v.values()]
+        self._decode = None
+
+    def ints(self, *dims):
+        return self.shape(dims, jnp.int32)
+
+    def decode_step(self):
+        """``decode_step`` compiled, once a deployment."""
+        if self._decode is None:
+            n = (self.slots,)
+            self._decode = self.eng._decode_step.lower(
+                self.params, self.pool_k, self.pool_v, self.tables,
+                self.ints(*n), self.ints(*n), self.shape(n, jnp.bool_),
+                self.shape(n, jnp.float32), self.shape(n, jnp.uint32),
+            ).compile()
+        return self._decode
+
+    def copies_of_a_pool(self, text):
+        return [
+            dims for dims in {",".join(map(str, p.shape)) for p in self.pools}
+            if re.findall(rf"= bf16\[{dims}\]\S* copy\(", text)
+        ]
+
+
+@pytest.fixture(scope="module")
+def deployment(one_chip):
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = _Deployment(name, one_chip)
+        return made[name]
+
+    return get
+
+
+@pytest.mark.parametrize("name", ["mistral", "internlm2", "mixed"])
+def test_decode_step_reads_live_pages_where_they_lie(deployment, name):
+    """``decode_step`` at the three configurations' real geometries holds
+    the paged-attention kernel, handed the pool whole, and nothing of the
+    size of a gathered table ``[KH, B, P, page, .]`` (the copies and their
+    feeding fusions were 4.5-5.9 s of 10 on the chip, PERF.md PR 31) nor of
+    one layer's slice of the pool ``[KH, N, page, .]`` (1.4 s in
+    reasoning): K and V are read page by page inside the kernel."""
+    d = deployment(name)
+    text = d.decode_step().as_text()
+    kh, pool = d.cfg.n_kv_heads, d.pool_k["full"]
+    kernels = [
+        line for line in text.splitlines()
+        if f'custom_call_target="{KERNEL}"' in line
+        and "paged_attention_decode" in line
+    ]
+    whole = "bf16[" + ",".join(map(str, pool.shape)) + "]"
+    assert kernels and all(whole in line for line in kernels)
+    slots, table = d.tables["full"].shape
+    rows = slots * table
+    for what, dims in {
+        "a gathered table": rf"{kh},{slots},{table},{PAGE},\d+",
+        "a gathered table, flat": rf"{rows},{kh},{PAGE},\d+",
+        "a layer's slice of the pool": rf"{kh},{pool.shape[2]},{PAGE},\d+",
+    }.items():
+        assert not re.findall(rf"bf16\[{dims}\]", text), what
+    assert not d.copies_of_a_pool(text)
+
+
+@pytest.mark.parametrize(
+    "kv_heads, table", [(16, 64), (32, 128)], ids=["smoke-16x128", "32x128"]
+)
+def test_paged_decode_sizes_its_buffers_to_the_pages_width(
+    one_chip, kv_heads, table
+):
+    """chip_smoke's widths (8 slots, 16 KV heads x 128 with groups of 1,
+    64 table entries) and twice as wide: 64 pages a buffer would be 16 and
+    32 MiB of VMEM, which the chip's compiler refuses ("Ran out of memory in
+    memory space vmem", as chip_smoke met it); the kernel takes fewer pages
+    a chunk from its operands' shapes."""
+    from ray_tpu.ops.paged_attention import paged_attention_decode
+
+    pool = ((12, kv_heads, 2048, 16, 128), jnp.bfloat16)
+    args = _shapes(
+        one_chip, ((8, kv_heads, 1, 128), jnp.bfloat16), pool, pool,
+        ((), jnp.int32), ((8, table), jnp.int32), ((8,), jnp.int32),
     )
-    slots, page, n_pages = 64, 16, 16384
-    table = cfg.max_seq_len // page
-    eng = ContinuousBatchingEngine(
-        cfg, params={}, max_batch=slots, page_size=page,
-        n_pages=table + 1, max_pages_per_seq=table,
-    )
+    text = paged_attention_decode.lower(*args, scale=128**-0.5).compile().as_text()
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill"])
+def test_pool_writers_copy_no_pool(deployment, program):
+    """``mistral-7b-v0.3-l16``'s cells: 32 slots, a [16,8,2048,16,128] bf16
+    pool (1 GiB of K, 1 GiB of V), contexts to 2,560. A program that writes
+    a few rows of the pool and returns it must alias it, not copy it: no
+    ``copy`` of the pool's shape in the optimised module, and temporaries
+    plus results that alias no operand smaller than one pool. Undonated,
+    the same programs held ``copy.101``/``copy.102`` and 2 GiB of results
+    of their own, 7 and 12 ms a step on the chip (PERF.md, PR 27)."""
+    d = deployment("mistral")
+    if program == "decode_step":
+        compiled = d.decode_step()
+    else:
+        t_pad = 2048  # the longest prompt of the cells
+        compiled = d.eng._prefill.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(t_pad), t_pad,
+            {"full": d.ints(t_pad // PAGE)},
+        ).compile()
+    assert not d.copies_of_a_pool(compiled.as_text())
+    mem = compiled.memory_analysis()
+    one_pool = 2 * d.pool_k["full"].size  # bytes of K alone
+    assert mem.alias_size_in_bytes == 2 * one_pool
+    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes + own < one_pool
+
+
+# -- a stack by position at the widths of `mimo-v2.5-l7-ep16` ------------------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
+def test_two_page_classes_and_held_experts_copy_no_pool(deployment, program):
+    """``mimo-v2.5-l7-ep16.mixed``: 64 slots, contexts to 8,192, a full
+    class of 16,384 pages and 64 rings of 9 pages, keys of 192 stored 256
+    wide, 16 of 256 experts held. Every program aliases all four pool
+    arrays and holds no copy of one: with keys stored 192 wide the chip's
+    compiler gave both K pools another layout inside the program and copied
+    each twice a run. The grouped expert matmul is a kernel."""
+    d = deployment("mixed")
+    eng, table = d.eng, d.tables["full"].shape[1]
     assert (eng.max_prefill_tokens, eng.prefill_chunk) == (2048, 512)
     assert (eng.pool.ring_pages, eng.pool.k_dim) == (9, 256)
-
-    def on_chip(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-            tree,
-        )
-
-    params = on_chip(
-        jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
-    )
-    sizes = {"full": (2, 4, n_pages), "window": (5, 8, slots * 9 + 1)}
-    pool_k, pool_v = (
-        {
-            name: jax.ShapeDtypeStruct(
-                lead + (page, width), cfg.dtype, sharding=one_chip
-            )
-            for name, lead in sizes.items()
-        }
-        for width in (256, 128)
-    )
-    ints = lambda *shape: _shapes(one_chip, (shape, jnp.int32))[0]  # noqa: E731
-    tables = {"full": ints(slots, table), "window": ints(slots, 9)}
     if program == "decode_step":
-        lowered = eng._decode_step.lower(
-            params, pool_k, pool_v, tables, ints(slots), ints(slots),
-            *_shapes(one_chip, ((slots,), jnp.bool_), ((slots,), jnp.float32),
-                     ((slots,), jnp.uint32)),
-        )
+        compiled = d.decode_step()
     elif program == "prefill":
-        lowered = eng._prefill.lower(
-            params, pool_k, pool_v, ints(2048), 2048,
-            {"full": ints(2048 // page), "window": ints(9)},
-        )
+        compiled = eng._prefill.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(2048), 2048,
+            {"full": d.ints(2048 // PAGE), "window": d.ints(9)},
+        ).compile()
     else:
-        lowered = eng._prefill_suffix.lower(
-            params, pool_k, pool_v, ints(512), 512, ints(),
-            {"full": ints(table), "window": ints(9)}, ints(512 // page),
-        )
-    compiled = lowered.compile()
+        compiled = eng._prefill_suffix.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(512), 512, d.ints(),
+            {"full": d.ints(table), "window": d.ints(9)},
+            d.ints(512 // PAGE),
+        ).compile()
     text = compiled.as_text()
-    pools = list(pool_k.values()) + list(pool_v.values())
-    for pool in pools:
-        dims = ",".join(map(str, pool.shape))
-        assert not re.findall(rf"= bf16\[{dims}\]\S* copy\(", text), dims
+    assert not d.copies_of_a_pool(text)
     assert KERNEL in text  # lax.ragged_dot: the grouped expert matmul
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == sum(2 * p.size for p in pools)
-    # temporaries: the gathered tables of 64 slots, the chunk's scores
+    assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools)
+    # temporaries: the rings' gathered tables of 64 slots, the chunk's scores
     assert mem.temp_size_in_bytes < 2.5 * 2**30
 
 

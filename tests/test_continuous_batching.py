@@ -118,27 +118,59 @@ def test_long_prompt_multiple_pages(small):
     )
 
 
-def test_pallas_attention_matches_gather_path(small):
-    """The Pallas paged-attention decode (interpret mode) is a drop-in for
-    the XLA gather path: identical greedy tokens."""
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+def test_decode_kernel_matches_gather_path(small, temperature):
+    """The Pallas paged-attention decode (interpret mode) gives the XLA
+    gather path's tokens slot for slot, with slots that idle, fill and end
+    at different steps; and the step counts what it walked."""
+    from ray_tpu.util import tracing
+
     cfg, params = small
-    base = ContinuousBatchingEngine(
-        cfg, params, max_batch=3, page_size=8, n_pages=48
-    )
-    pallas = ContinuousBatchingEngine(
-        cfg,
-        params,
-        max_batch=3,
-        page_size=8,
-        n_pages=48,
-        use_pallas_attention=True,
-        pallas_interpret=True,
-    )
-    prompts = [[2, 4, 6, 8], [1, 3, 5], [7]]
-    gen = GenerationConfig(max_new_tokens=10, temperature=0.0)
-    assert pallas.generate_ids(prompts, gen) == base.generate_ids(
-        prompts, gen
-    )
+    prompts = [[2, 4, 6, 8], [1, 3, 5], [7], list(range(1, 40)), [9, 9]]
+    gen = GenerationConfig(max_new_tokens=10, temperature=temperature, seed=3)
+
+    def run(kernel):
+        eng = ContinuousBatchingEngine(
+            cfg, params, max_batch=3, page_size=8, n_pages=48
+        )
+        assert eng._attn_kernel is None  # no TPU here: the XLA formulation
+        eng._attn_kernel = kernel
+        tracing.SPANS.clear()
+        out = eng.generate_ids(prompts, gen)
+        steps = [
+            s["args"] for s in tracing.SPANS.slices(cat="engine")
+            if s["name"] == "engine.decode"
+        ]
+        return out, steps, eng
+
+    want, gathered, _ = run(None)
+    got, walked, eng = run("interpret")
+    assert got == want
+    table = 3 * eng.max_pages_per_seq  # entries a layer's gather reads
+    for a in gathered:
+        assert (a["attn_kernel_layers"], a["attn_full_layers"]) == (0, 2)
+        assert (a["attn_pages_walked"], a["attn_table_entries"]) == (0, 2 * table)
+    assert walked
+    for a in walked:
+        assert a["attn_kernel_layers"] == a["attn_full_layers"] == cfg.n_layers
+        assert a["attn_pages_walked"] == cfg.n_layers * a["pages_written"]
+        assert a["attn_table_entries"] == cfg.n_layers * table
+        assert a["attn_pages_walked"] < a["attn_table_entries"]
+
+
+def test_no_caller_chooses_the_attention_path(small, monkeypatch):
+    """The platform chooses: the kernel on a TPU, the XLA formulation
+    elsewhere; the constructor has no argument for it."""
+    import inspect
+
+    cfg, params = small
+    names = inspect.signature(ContinuousBatchingEngine.__init__).parameters
+    assert not [n for n in names if "pallas" in n or "kernel" in n or "attention" in n]
+    with pytest.raises(TypeError):
+        ContinuousBatchingEngine(cfg, params, use_pallas_attention=True)
+    assert ContinuousBatchingEngine(cfg, params)._attn_kernel is None
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ContinuousBatchingEngine(cfg, params)._attn_kernel == "compiled"
 
 
 def test_concurrent_callers_share_one_engine(small):
@@ -182,20 +214,6 @@ def test_concurrent_callers_share_one_engine(small):
     assert not any(t.is_alive() for t in threads), "a caller never returned"
     assert got == want
     assert eng.pool.free_pages == eng.pool.usable_pages
-
-
-def test_pallas_pool_beyond_vmem_raises_at_construction(small):
-    """The paged-decode kernel stages one head's whole pool slice in VMEM:
-    a pool past that is refused where it is asked for, with the sizes,
-    not by the compiler at the first decode step."""
-    cfg = tfm.ModelConfig(
-        vocab_size=96, d_model=2048, n_layers=1, n_heads=16, n_kv_heads=16,
-        d_ff=64, max_seq_len=128,
-    )  # head_dim 128, bf16
-    with pytest.raises(ValueError, match=r"64\.0 MiB for n_pages=4096.*16 MiB"):
-        ContinuousBatchingEngine(
-            cfg, params={}, n_pages=4096, use_pallas_attention=True
-        )
 
 
 # ---------------------------------------------------------------------------

@@ -382,6 +382,33 @@ def test_spans_carry_the_expert_counts_and_the_pages_by_class(toy):
     assert max(d["full_pages"] for d in decodes) > 3 * 2
 
 
+@pytest.mark.parametrize("kernel", [None, "interpret"], ids=["gather", "kernel"])
+def test_full_layers_take_the_kernel_and_rings_their_own_path(toy, kernel):
+    """Separate paths by layer kind: with the Pallas decode kernel
+    (interpreted) the three full layers read live pages (keys of 24 beside
+    values of 16, four KV heads' groups of four), the ten windowed layers
+    keep their ring gather, and the tokens are the XLA formulation's slot
+    for slot; the step counts which layers ran where."""
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (3, 41, 18, 9)]
+    gen = GenerationConfig(max_new_tokens=12)
+    want = make_engine(toy).generate_ids(prompts, gen)
+    eng = make_engine(toy)
+    assert eng._attn_kernel is None
+    eng._attn_kernel = kernel
+    tracing.SPANS.clear()
+    assert eng.generate_ids(prompts, gen) == want
+    decodes = [s["args"] for s in tracing.SPANS.slices(cat="engine")
+               if s["name"] == "engine.decode"]
+    assert decodes
+    entries = 3 * 3 * eng.max_pages_per_seq  # full layers x slots x table
+    for d in decodes:
+        assert d["attn_full_layers"] == 3
+        assert d["attn_table_entries"] == entries
+        assert d["attn_kernel_layers"] == (3 if kernel else 0)
+        assert d["attn_pages_walked"] == (3 * d["full_pages"] if kernel else 0)
+
+
 # -- what the system cannot do for such a model yet, and says so -------------------
 
 
@@ -393,8 +420,6 @@ def test_spans_carry_the_expert_counts_and_the_pages_by_class(toy):
             [1, 2, 3], GenerationConfig(max_new_tokens=2)),
         lambda toy: make_engine(toy).adopt_pages({}, None, None),
         lambda toy: make_engine(toy).swap_params(toy[1]),
-        lambda toy: make_engine(toy, use_pallas_attention=True,
-                                pallas_interpret=True),
         lambda toy: LLMEngine(toy[0], toy[1]),
         lambda toy: tfm.forward(toy[1], jnp.zeros((1, 4), jnp.int32), toy[0]),
         lambda toy: tfm.make_train_step(toy[0], None),
@@ -402,7 +427,7 @@ def test_spans_carry_the_expert_counts_and_the_pages_by_class(toy):
             tfm.ModelConfig(n_experts=4, n_layers=1)),
     ],
     ids=["prefix_cache", "prefill_extract", "adopt_pages", "swap_params",
-         "pallas_decode", "LLMEngine", "forward", "train_step",
+         "LLMEngine", "forward", "train_step",
          "switch_experts"],
 )
 def test_a_path_that_lacks_the_feature_raises_a_typed_error(toy, call):
