@@ -6,8 +6,8 @@ names, and checks every answer against a plain reference:
 
   sched    the head's scheduling loop over a 1,024-node synthetic fleet with
            the scheduler kernels on the chip, then the README quick start
-  serve    serve.run(build_llm_deployment(engine="continuous")) answering
-           eight requests, then a short Pallas-decode run
+  serve    serve.run(build_llm_deployment(...)) answering eight requests,
+           then a short Pallas-decode run
   train    JaxTrainer taking four make_train_step steps
   cluster  a multi-process Cluster whose head (this process) holds the chip
 
@@ -86,7 +86,7 @@ class Sizes:
 
 FULL = Sizes(
     sim_nodes=1024, sim_demands=100_000, solve_nodes=8192, solve_shapes=1024,
-    # the widest configuration the repo names (bench.py model_bench)
+    # the widest configuration of the train step the repo names
     vocab=32_000, d_model=2048, n_layers=12, n_heads=16, n_kv_heads=16,
     d_ff=5504, max_seq_len=1024, dtype="bfloat16",
     max_batch=8, n_pages=2048,
@@ -558,7 +558,7 @@ def serve_phase(sz: Sizes, on_chip: bool, clock: CompileClock, dev) -> None:
     try:
         serve.run(
             build_llm_deployment(
-                cfg, params, name="llm", engine="continuous",
+                cfg, params, name="llm",
                 max_batch=sz.max_batch, page_size=16, n_pages=sz.n_pages,
                 tokenizer=tok,
             )
@@ -685,7 +685,7 @@ def _optimizer():
     import jax.numpy as jnp
     import optax
 
-    return optax.adam(3e-4, mu_dtype=jnp.bfloat16)  # as bench.py model_bench
+    return optax.adam(3e-4, mu_dtype=jnp.bfloat16)
 
 
 def _train_loop(config: Dict[str, Any]) -> None:

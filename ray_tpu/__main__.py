@@ -2,7 +2,7 @@
 
 Cluster daemons (``start --head`` / ``start --address``), cluster status,
 job submission against a live cluster (dashboard/modules/job/ analog), and
-the in-process conveniences (local job run, bench).
+the in-process convenience of a local job run.
 """
 from __future__ import annotations
 
@@ -149,13 +149,6 @@ def cmd_job_ctl(args) -> int:
     return 0
 
 
-def cmd_bench(args) -> int:
-    import bench
-
-    bench.main()
-    return 0
-
-
 def cmd_config(args) -> int:
     """Print every declared knob: name, env override, type, value, doc."""
     import json as _json
@@ -221,8 +214,6 @@ def main() -> int:
         if name != "list":
             jc.add_argument("job_id")
 
-    sub.add_parser("bench")
-
     cf = sub.add_parser(
         "config", help="dump the typed config registry (ray_config_def analog)"
     )
@@ -241,8 +232,6 @@ def main() -> int:
         if args.job_command == "submit":
             return cmd_job_submit(args)
         return cmd_job_ctl(args)
-    if args.command == "bench":
-        return cmd_bench(args)
     return 1
 
 

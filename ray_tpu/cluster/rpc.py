@@ -338,6 +338,12 @@ _OPTIONS = [
     ("grpc.max_send_message_length", _MAX_MSG),
     ("grpc.max_receive_message_length", _MAX_MSG),
     ("grpc.so_reuseport", 0),
+    # a channel's connection state is its own. In gRPC's process-wide pool
+    # a new channel to an address inherits the reconnect backoff (up to two
+    # minutes) of any older channel to it, so a client of a fresh server on
+    # a reused port failed UNAVAILABLE at once, the breaker opened, and the
+    # head declared a live node dead
+    ("grpc.use_local_subchannel_pool", 1),
 ]
 
 

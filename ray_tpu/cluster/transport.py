@@ -255,20 +255,28 @@ class DataPlaneServer:
             ).start()
 
     def _drop_conn(self, conn: NetSocket) -> None:
+        # closed under the lock: a connection that is in _conns has its
+        # descriptor, so _sever_all may shut it down
         with self._lock:
             self._conns.pop(id(conn), None)
-        conn.close()
+            conn.close()
+
+    def _sever_all(self) -> int:
+        """Shut down every live connection; each serving thread then wakes
+        from its recv and closes its own (caller holds the lock)."""
+        victims = list(self._conns.values())
+        self._conns.clear()
+        for c in victims:
+            c.sever()
+        return len(victims)
 
     def chaos_drop(self) -> int:
         """Sever every live data connection (peer_conn_drop fault): the
         senders' in-flight stripes fail and must resume, not restart."""
         with self._lock:
-            victims = list(self._conns.values())
-            self._conns.clear()
-            self.stats["chaos_drops"] += len(victims)
-        for c in victims:
-            c.close()
-        return len(victims)
+            dropped = self._sever_all()
+            self.stats["chaos_drops"] += dropped
+        return dropped
 
     def close(self) -> None:
         """Exactly-once teardown (idempotent like every close here)."""
@@ -277,10 +285,7 @@ class DataPlaneServer:
         self._closed = True
         self._listener.close()
         with self._lock:
-            victims = list(self._conns.values())
-            self._conns.clear()
-        for c in victims:
-            c.close()
+            self._sever_all()
         try:
             import os
 

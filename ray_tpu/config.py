@@ -988,12 +988,6 @@ define(
     "many deltas since the last flush (finished streams always flush).",
 )
 define(
-    "serve_drain_timeout_s",
-    30.0,
-    "Graceful-drain budget for a retiring replica: in-flight streams "
-    "finish within this before the replica is killed anyway.",
-)
-define(
     "serve_slo_ttft_ms",
     0.0,
     "Target p50 time-to-first-token for SLO autoscaling (ms); sustained "

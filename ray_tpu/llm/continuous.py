@@ -804,11 +804,6 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     @_locked
     def submit(self, prompt: List[int], gen: GenerationConfig) -> int:
-        if gen.top_k:
-            raise NotImplementedError(
-                "per-slot top_k is not supported by the continuous engine "
-                "(temperature sampling and greedy are); use LLMEngine"
-            )
         if self._swapping and self._swap_started is not None:
             from ray_tpu.config import cfg
 
@@ -958,7 +953,7 @@ class ContinuousBatchingEngine:
                 t + req.gen.max_new_tokens - 1, self._capacity(pages)
             )
             slot.pages = pages
-            slot.eos = req.gen.eos_token  # parity with LLMEngine.generate_ids
+            slot.eos = req.gen.eos_token
             slot.out = [first]
             # device state (the tables were built before prefill — the
             # suffix path passes the whole rows to its gathers)
@@ -1556,7 +1551,6 @@ class ContinuousBatchingEngine:
             gen = GenerationConfig(
                 max_new_tokens=gen.max_new_tokens,
                 temperature=gen.temperature,
-                top_k=gen.top_k,
                 seed=gen.seed,
                 eos_token=getattr(self.tokenizer, "eos", None),
             )

@@ -12,7 +12,8 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 import ray_tpu
-from .engine import GenerationConfig, LLMEngine
+from .continuous import ContinuousBatchingEngine
+from .engine import GenerationConfig
 
 
 @dataclass
@@ -28,14 +29,22 @@ class LLMProcessor:
         cfg = self.model_config
         params = self.params
         gen = self.generation
-        max_len = self.max_len
-        engine_holder: Dict[str, LLMEngine] = {}
+        # a pool for batch_size contexts of max_len tokens (prompt and
+        # answer), and the scratch page
+        page = 16
+        ctx_pages = -(-min(self.max_len, cfg.max_seq_len) // page)
+        engine_kw = dict(
+            max_batch=self.batch_size, page_size=page,
+            n_pages=self.batch_size * ctx_pages + 1,
+            max_pages_per_seq=ctx_pages,
+        )
+        engine_holder: Dict[str, ContinuousBatchingEngine] = {}
 
         def infer(batch: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
             # engine is constructed once per worker and reused across blocks
             if "engine" not in engine_holder:
-                engine_holder["engine"] = LLMEngine(
-                    cfg, params, max_len=max_len
+                engine_holder["engine"] = ContinuousBatchingEngine(
+                    cfg, params, **engine_kw
                 )
             engine = engine_holder["engine"]
             prompts = [str(p) for p in batch["prompt"]]

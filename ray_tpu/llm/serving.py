@@ -7,9 +7,9 @@ application whose replicas each hold an engine; requests are
 
 Serving-plane integration (PR 8):
 
-- replicas of a ``continuous`` deployment share prefilled KV through
-  the node's shm arena (:mod:`ray_tpu.serve.prefix_cache`) — a repeated
-  prompt prefix is a pinned read-only view copy-in, not a prefill;
+- replicas share prefilled KV through the node's shm arena
+  (:mod:`ray_tpu.serve.prefix_cache`) — a repeated prompt prefix is a
+  pinned read-only view copy-in, not a prefill;
 - streams are **resumable**: generation is per-request deterministic
   (seeded), so ``stream_to`` honors ``resume_from=n`` by regenerating
   and skipping the first ``n`` tokens — the router uses this to fail a
@@ -38,7 +38,8 @@ from typing import Any, Dict, Optional
 import ray_tpu
 import ray_tpu.serve as serve
 from ray_tpu.util import tracing
-from .engine import GenerationConfig, LLMEngine
+from .continuous import ContinuousBatchingEngine
+from .engine import GenerationConfig
 
 
 def _params_sig(model_config: Any, params: Optional[Any], name: str) -> str:
@@ -86,8 +87,9 @@ def build_llm_deployment(
     *,
     name: str = "llm",
     num_replicas: int = 1,
-    max_len: int = 256,
-    engine: str = "dense",  # "dense" | "continuous" (paged KV)
+    # the one engine; the parameter stands only because
+    # benchmarks/harness/served.py passes it from the configurations' files
+    engine: str = "continuous",
     max_batch: int = 8,
     page_size: int = 16,
     n_pages: int = 256,
@@ -107,14 +109,10 @@ def build_llm_deployment(
     variants: Optional[Dict[str, Any]] = None,
     base_model_id: str = "base",
 ):
-    if engine not in ("dense", "continuous"):
+    if engine != "continuous":
         raise ValueError(
-            f"unknown engine {engine!r}; expected 'dense' or 'continuous'"
-        )
-    if (prefill_replicas or variants) and engine != "continuous":
-        raise ValueError(
-            "prefill/decode disaggregation and model multiplexing "
-            "require engine='continuous' (paged KV)"
+            f"unknown engine {engine!r}; the paged engine 'continuous' is "
+            "the only one"
         )
     model_sig = _params_sig(model_config, params, name)
     models = (
@@ -122,8 +120,6 @@ def build_llm_deployment(
     )
 
     def _make_engine(model_id: str):
-        from .continuous import ContinuousBatchingEngine
-
         cache = None
         if prefix_cache:
             from ray_tpu.serve.prefix_cache import cache_from_cfg
@@ -147,9 +143,9 @@ def build_llm_deployment(
     @serve.deployment(
         name=name,
         num_replicas=num_replicas,
-        # continuous-engine generation is per-request deterministic
-        # (seeded sampling), so streams can fail over mid-flight
-        resumable_streams=(engine == "continuous"),
+        # generation is per-request deterministic (seeded sampling), so
+        # streams can fail over mid-flight
+        resumable_streams=True,
         stats_method="serve_stats",
         slo=slo,
         prefill_deployment=prefill_dep_name,
@@ -157,22 +153,14 @@ def build_llm_deployment(
     )
     class LLMServer:
         def __init__(self):
-            if engine == "continuous":
-                self.engine = _make_engine(base_model_id)
-            else:
-                self.engine = LLMEngine(
-                    model_config, params, max_len=max_len,
-                    tokenizer=tokenizer,
-                )
+            self.engine = _make_engine(base_model_id)
             self._tokens_out = 0
             # hot-swap plane: base + variant weights by model id; the
             # node WeightsHub (shm arena) is probed first so same-node
             # siblings pull sealed device frames instead of re-reading
             # the closure capture
             self._variants = dict(variants or {})
-            self._variants[base_model_id] = getattr(
-                self.engine, "params", params
-            )
+            self._variants[base_model_id] = self.engine.params
             self._hub = None
             if variants:
                 from ray_tpu.serve.model_store import hub_from_node
@@ -195,11 +183,7 @@ def build_llm_deployment(
             model = (
                 request.get("model") if isinstance(request, dict) else None
             )
-            if (
-                model
-                and hasattr(self.engine, "swap_params")
-                and model != self.engine.model_id
-            ):
+            if model and model != self.engine.model_id:
                 self.swap_weights({"model": model})
 
         def swap_weights(self, request) -> dict:
@@ -316,10 +300,6 @@ def build_llm_deployment(
             ``.options(num_returns="streaming")`` and iterate the
             ObjectRefGenerator — each decoded token text seals as its own
             object with normal object-plane semantics."""
-            if not hasattr(self.engine, "stream_ids"):
-                raise TypeError(
-                    "token streaming requires engine='continuous'"
-                )
             self._ensure_model(request)
             gen = _gen_from_request(request)
             prompt = self.engine.tokenizer.encode(request["prompt"])
@@ -333,10 +313,6 @@ def build_llm_deployment(
             PushWriter cross-host, relay actor legacy). ``resume_from=n``
             regenerates deterministically and skips the first n tokens —
             the router's mid-stream failover path."""
-            if not hasattr(self.engine, "stream_ids"):
-                writer.write("streaming requires engine='continuous'")
-                writer.close_channel()
-                return 0
             self._ensure_model(request)
             gen = _gen_from_request(request)
             skip = max(0, int(request.get("resume_from", 0)))
@@ -355,11 +331,7 @@ def build_llm_deployment(
                     if isinstance(request, dict)
                     else None
                 )
-                if (
-                    handoff
-                    and not skip
-                    and hasattr(self.engine, "adopt_pages")
-                ):
+                if handoff and not skip:
                     rid = self._adopt_handoff(handoff)
                 tokens = (
                     self.engine.stream_rid(rid)
@@ -425,11 +397,6 @@ def build_llm_deployment(
             return os.getpid()
 
         def serve_stats(self) -> dict:
-            stats = (
-                self.engine.stats()
-                if hasattr(self.engine, "stats")
-                else {}
-            )
             return {
                 "pid": os.getpid(),
                 "tokens_out": self._tokens_out,
@@ -449,7 +416,7 @@ def build_llm_deployment(
                     if self._handoff_s > 0
                     else None
                 ),
-                **stats,
+                **self.engine.stats(),
             }
 
         def _start_agent_reporter(self) -> None:
@@ -459,7 +426,7 @@ def build_llm_deployment(
             from ray_tpu.cluster import worker as worker_mod
 
             w = getattr(worker_mod, "_CURRENT_WORKER", None)
-            if w is None or not hasattr(self.engine, "stats"):
+            if w is None:
                 return
             # weakref: the reporter must not keep a killed replica's
             # engine alive (or the thread running) past the actor's

@@ -202,7 +202,8 @@ class ModelConfig:
 
     def require_uniform_dense(self, path: str) -> None:
         """For the paths that run the one uniform block with heads of one
-        size (the train step, ``LLMEngine``'s dense cache)."""
+        size: the train step (``param_specs``, ``forward``,
+        ``make_train_step``)."""
         for name in ("attn_pattern", "ffn_pattern"):
             if getattr(self, name):
                 raise UnsupportedModelFeature(
@@ -625,106 +626,6 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
             body, (h, cache, jnp.int32(run.cache_start), counts), stack
         )
     return h, cache, counts
-
-
-# ---------------------------------------------------------------------------
-# KV-cache inference path (prefill + single-token decode), the engine core
-# for ray_tpu.llm. Cache layout: {"k","v"}: f32[L, B, S_max, Hkv, Dh].
-# ---------------------------------------------------------------------------
-
-
-def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int):
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-    }
-
-
-def _block_with_cache(cfg, p, h, k_cache, v_cache, positions, seq_mask):
-    """One block over ``h`` [B, T, D] writing K/V into the cache slice and
-    attending over cache[:, :S_max] with a position mask.
-
-    positions: int32[B, T] absolute position of each input token.
-    seq_mask: bool[B, S_max] which cache slots are valid *after* this write.
-    """
-    b, t, d = h.shape
-    hd = cfg.head_dim
-    x = rms_norm(h, p["ln1"], cfg.rms_eps)
-    q = (x @ p["wq"]).reshape(b, t, cfg.n_heads, hd)
-    k = (x @ p["wk"]).reshape(b, t, cfg.n_kv_heads, hd)
-    v = (x @ p["wv"]).reshape(b, t, cfg.n_kv_heads, hd)
-    angles = rope_freqs(hd, cfg.max_seq_len, cfg.rope_theta)  # [S,D/2]
-    ang = angles[positions]  # [B, T, D/2]
-    q = _apply_rope_positions(q, ang)
-    k = _apply_rope_positions(k, ang)
-    # scatter k/v into the cache at each token's position
-    bidx = jnp.arange(b)[:, None].repeat(t, 1)
-    k_cache = k_cache.at[bidx, positions].set(k)
-    v_cache = v_cache.at[bidx, positions].set(v)
-    # attention: q attends to all cached positions <= its own
-    s_max = k_cache.shape[1]
-    groups = cfg.n_heads // cfg.n_kv_heads
-    qh = q.reshape(b, t, cfg.n_kv_heads, groups, hd)
-    scores = jnp.einsum(
-        "bthgd,bshd->bhgts", qh.astype(jnp.float32),
-        k_cache.astype(jnp.float32),
-    ) / jnp.sqrt(hd)
-    k_pos = jnp.arange(s_max)
-    causal = k_pos[None, None, :] <= positions[:, :, None]  # [B,T,S]
-    valid = causal & seq_mask[:, None, :]
-    scores = jnp.where(valid[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    attn = jnp.einsum(
-        "bhgts,bshd->bthgd", probs, v_cache.astype(jnp.float32)
-    ).astype(h.dtype)
-    h = h + attn.reshape(b, t, -1) @ p["wo"]
-    x = rms_norm(h, p["ln2"], cfg.rms_eps)
-    if cfg.n_experts > 0:
-        y = moe_mod.moe_apply(p["moe"], x, cfg.expert_capacity_factor)
-    else:
-        y = swiglu(x, p["w_gate"], p["w_up"], p["w_down"])
-    return h + y, k_cache, v_cache
-
-
-def _apply_rope_positions(x, ang):
-    """x: [B, T, H, D]; ang: [B, T, D/2]."""
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-    return out.astype(dtype)
-
-
-def forward_with_cache(
-    params,
-    tokens: jax.Array,      # int32[B, T]
-    positions: jax.Array,   # int32[B, T]
-    cache,                  # from init_kv_cache
-    seq_mask: jax.Array,    # bool[B, S_max] valid slots incl. these tokens
-    cfg: ModelConfig,
-):
-    """Returns (logits[B, T, V], updated cache). Used for both prefill
-    (T = prompt length) and decode (T = 1)."""
-    cfg.require_uniform_dense("forward_with_cache")
-    h = params["embed"][tokens].astype(cfg.dtype)
-
-    def body(carry, layer):
-        h = carry
-        p, kc, vc = layer
-        h, kc, vc = _block_with_cache(
-            cfg, p, h, kc, vc, positions, seq_mask
-        )
-        return h, (kc, vc)
-
-    h, (k_new, v_new) = jax.lax.scan(
-        body, h, (params["blocks"], cache["k"], cache["v"])
-    )
-    h = rms_norm(h, params["ln_f"], cfg.rms_eps)
-    logits = (h @ params["head"]).astype(jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
 
 
 def loss_fn(params, tokens, cfg: ModelConfig, mesh=None, *, num_microbatches=0):

@@ -241,4 +241,12 @@ int rtpu_net_close(int fd) {
   return close(fd) == 0 ? 0 : -errno;
 }
 
+// Sever a connection another thread may be blocked on, keeping the
+// descriptor: that thread's recv returns 0 and the peer sees the FIN now.
+// close() alone does neither while a call is in flight on the socket, and
+// frees the number for reuse under the blocked thread's next call.
+int rtpu_net_shutdown(int fd) {
+  return shutdown(fd, SHUT_RDWR) == 0 ? 0 : -errno;
+}
+
 }  // extern "C"

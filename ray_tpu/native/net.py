@@ -69,6 +69,8 @@ def _load_native():
     ]
     lib.rtpu_net_close.restype = ctypes.c_int
     lib.rtpu_net_close.argtypes = [ctypes.c_int]
+    lib.rtpu_net_shutdown.restype = ctypes.c_int
+    lib.rtpu_net_shutdown.argtypes = [ctypes.c_int]
     return lib
 
 
@@ -272,6 +274,24 @@ class NetSocket:
                 self._sock.close()
             except OSError:
                 pass
+
+    def sever(self) -> None:
+        """Shut both directions down and keep the descriptor: the way for
+        a thread that does not own the connection to end it. The owner,
+        blocked in a recv, wakes with a closed connection and closes it;
+        the peer sees the end at once. ``close`` from another thread does
+        neither while the owner's call is in flight, and frees the
+        descriptor's number for reuse under the owner's next call. The
+        caller makes sure the owner cannot ``close`` meanwhile."""
+        if self._closed:
+            return
+        if self._fd is not None:
+            native_lib().rtpu_net_shutdown(self._fd)
+        else:
+            try:
+                self._sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass  # the peer is gone already
 
     @property
     def closed(self) -> bool:

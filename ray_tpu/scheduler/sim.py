@@ -11,9 +11,9 @@ a simulated cluster stops being real, so that is the seam).
 This is how the 10k-node × 1M-pending-task scale target (ROADMAP items
 1/3) is measured reproducibly on any host: delivered placements/s
 end-to-end through ``head._schedule_batch``, plus the round-latency
-percentiles over the run's window. ``bench.py``'s ``sim_sched`` tier runs
-it in both pipeline modes and publishes the ratio; tests run it small and
-assert zero placement divergence between the modes on identical streams.
+percentiles over the run's window. ``run_sim_pair`` runs it in both
+pipeline modes and gives the ratio; tests run it small and assert zero
+placement divergence between the modes on identical streams.
 
 Health checking is inert by construction: a node that never appears in
 ``head._last_report`` reads as gap 0 (the agent-report liveness contract
@@ -55,9 +55,9 @@ def build_demand_maps(
     large_frac: float = 0.0,
     cpu_scale: float = 1.0,
 ) -> List[Dict[str, float]]:
-    """The bench workload's CPU/memory mixture (bench.py build_demands),
-    minus the TPU slice — the fill-once sim asserts full delivery, so
-    every shape must be cluster-placeable. ``large_frac`` > 0 skews the
+    """A CPU/memory mixture of task and actor shapes, with no TPU slice:
+    the fill-once sim asserts full delivery, so every shape must be
+    cluster-placeable. ``large_frac`` > 0 skews the
     stream with LARGE_SHAPE requests (doubled over the final fifth of
     the stream, so the tail arrives against an already-fragmented
     cluster); ``cpu_scale`` scales the small shapes up so a churn run
@@ -392,7 +392,7 @@ def run_sim_pair(
     """Pipelined + synchronous runs over the SAME demand stream on the
     same host: the speedup ratio and the divergence count (both modes
     must place every spec, on identical nodes per spec when the stream
-    is deterministic). This is the bench tier's workhorse.
+    is deterministic).
 
     A throwaway warmup run at the same node geometry populates the
     process-wide jit cache first — without it the sync run (which goes

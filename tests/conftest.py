@@ -12,9 +12,8 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # geometry, re-armed by every HeadServer's first sync) competes with the
 # tests for the 1-2 cores CI runs on, and its interpreter-exit joins
 # (scheduler/device._drain_prewarms) add up to ~30s of teardown tail to
-# the suite. bench.py disables it for the sim tiers for the same reason;
-# the persistent XLA compile cache keeps the inline first-touch compiles
-# cheap across runs. Production keeps prewarm ON.
+# the suite. The persistent XLA compile cache keeps the inline first-touch
+# compiles cheap across runs. Production keeps prewarm ON.
 os.environ.setdefault("RAY_TPU_SCHED_PREWARM", "0")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:

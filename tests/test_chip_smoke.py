@@ -83,17 +83,3 @@ def test_refuses_to_run_without_a_tpu():
     assert proc.returncode != 0, proc.stdout
     assert '"ok": true' not in proc.stdout
     assert "not a TPU" in proc.stderr
-
-
-def test_bench_peak_flops_knows_the_device_or_raises():
-    """bench.py's MFU denominator comes from ``device_kind`` alone; a kind
-    it does not know is an error, not an assumed v5e."""
-    import bench
-
-    class Device:
-        device_kind = "TPU v5 lite"
-
-    assert bench._peak_flops(Device()) == (197e12, "v5 lite")
-    Device.device_kind = "cpu"
-    with pytest.raises(ValueError, match="device_kind 'cpu'"):
-        bench._peak_flops(Device())

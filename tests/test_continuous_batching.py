@@ -1,23 +1,35 @@
 """Continuous batching + paged KV cache engine.
 
-Correctness bar: greedy outputs must MATCH the dense-cache LLMEngine
-token-for-token (same params, same prompts) — the paged layout is a
-memory-management change, not a math change. Plus: staggered admission,
+Correctness bar: every greedy token is the first choice of the benchmark's
+plain reference (``benchmarks/references/dense_gqa.py``: float32, no cache,
+nothing of the program) over the served sequence, compared as
+``benchmarks/harness/check.py`` compares. Plus: staggered admission,
 page-pool backpressure, and page reuse across more requests than the
 pool holds at once.
 """
 import inspect
+import os
 import re
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.llm import continuous
-from ray_tpu.llm.continuous import ContinuousBatchingEngine
-from ray_tpu.llm.engine import GenerationConfig, LLMEngine
-from ray_tpu.models import transformer as tfm
+BENCH = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from harness import check, spec  # noqa: E402
+
+from ray_tpu.llm import continuous  # noqa: E402
+from ray_tpu.llm.continuous import ContinuousBatchingEngine  # noqa: E402
+from ray_tpu.llm.engine import GenerationConfig  # noqa: E402
+from ray_tpu.models import transformer as tfm  # noqa: E402
+
+# float32 against float32 at `highest`: what the order of the sums leaves
+TOL = 2e-4
 
 
 @pytest.fixture(scope="module")
@@ -30,15 +42,33 @@ def small():
         n_kv_heads=2,
         d_ff=128,
         max_seq_len=128,
-        dtype=jnp.float32,  # exact parity with the dense engine
+        dtype=jnp.float32,  # the reference's own type
     )
     params = tfm.init_params(cfg, jax.random.PRNGKey(7))
     return cfg, params
 
 
-def test_matches_dense_engine_greedy(small):
+def assert_reference_first_choices(small, prompts, served):
+    """Teacher-forced over each served sequence (``check.output_gaps``): at
+    every served token the reference's best logit less its logit of that
+    token is under ``TOL``."""
     cfg, params = small
-    dense = LLMEngine(cfg, params, max_len=96)
+    src = {
+        "name": "small", "reference": "dense_gqa",
+        "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads,
+        "rope_theta": cfg.rope_theta, "rms_norm_eps": cfg.rms_eps,
+    }
+    sample = [{"prompt": p, "ids": ids} for p, ids in zip(prompts, served)]
+    gaps, _ = check.output_gaps(
+        src, params, sample, spec.load_reference(src, BENCH), control=False
+    )
+    assert len(gaps) == sum(len(ids) for ids in served)
+    assert max(gaps) <= TOL, (prompts, served, gaps)
+
+
+def test_greedy_tokens_are_the_plain_references_first_choices(small):
+    cfg, params = small
     paged = ContinuousBatchingEngine(
         cfg, params, max_batch=4, page_size=8, n_pages=64
     )
@@ -49,24 +79,23 @@ def test_matches_dense_engine_greedy(small):
         [2],
     ]
     gen = GenerationConfig(max_new_tokens=12, temperature=0.0)
-    want = dense.generate_ids(prompts, gen)
     got = paged.generate_ids(prompts, gen)
-    assert got == want
+    assert [len(o) for o in got] == [12] * 4
+    assert_reference_first_choices(small, prompts, got)
 
 
 def test_continuous_admission_interleaves(small):
     """More requests than slots: later requests join as earlier finish —
     and the interleaving does not change any request's output."""
     cfg, params = small
-    dense = LLMEngine(cfg, params, max_len=96)
     paged = ContinuousBatchingEngine(
         cfg, params, max_batch=2, page_size=8, n_pages=32
     )
     prompts = [[i + 1, i + 2, i + 3] for i in range(6)]
     gen = GenerationConfig(max_new_tokens=8, temperature=0.0)
-    want = dense.generate_ids(prompts, gen)
     got = paged.generate_ids(prompts, gen)
-    assert got == want
+    assert [len(o) for o in got] == [8] * 6
+    assert_reference_first_choices(small, prompts, got)
     # pool fully reclaimed
     assert paged.pool.free_pages == paged.pool.usable_pages
     assert paged.stats()["active_slots"] == 0
@@ -106,16 +135,15 @@ def test_eos_stops_early(small):
 
 def test_long_prompt_multiple_pages(small):
     cfg, params = small
-    dense = LLMEngine(cfg, params, max_len=128)
     paged = ContinuousBatchingEngine(
         cfg, params, max_batch=2, page_size=8, n_pages=64
     )
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 90, size=37).tolist()]
     gen = GenerationConfig(max_new_tokens=6, temperature=0.0)
-    assert paged.generate_ids(prompts, gen) == dense.generate_ids(
-        prompts, gen
-    )
+    got = paged.generate_ids(prompts, gen)
+    assert len(got[0]) == 6
+    assert_reference_first_choices(small, prompts, got)
 
 
 @pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])

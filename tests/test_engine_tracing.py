@@ -349,7 +349,7 @@ def test_a_requests_spans_share_one_trace_id_from_router_to_engine(how):
     ray_tpu.init(num_nodes=1, resources_per_node={"CPU": 4})
     try:
         serve.run(build_llm_deployment(
-            cfg, name="traced", engine="continuous", max_batch=2,
+            cfg, name="traced", max_batch=2,
             page_size=PAGE, n_pages=32, prefix_cache=False,
         ))
         router = serve.get_router("traced")

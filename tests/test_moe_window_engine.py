@@ -21,7 +21,7 @@ from harness import spec  # noqa: E402
 
 from ray_tpu.llm import continuous  # noqa: E402
 from ray_tpu.llm.continuous import ContinuousBatchingEngine  # noqa: E402
-from ray_tpu.llm.engine import GenerationConfig, LLMEngine  # noqa: E402
+from ray_tpu.llm.engine import GenerationConfig  # noqa: E402
 from ray_tpu.models import moe  # noqa: E402
 from ray_tpu.models import transformer as tfm  # noqa: E402
 from ray_tpu.util import tracing  # noqa: E402
@@ -420,15 +420,13 @@ def test_full_layers_take_the_kernel_and_rings_their_own_path(toy, kernel):
             [1, 2, 3], GenerationConfig(max_new_tokens=2)),
         lambda toy: make_engine(toy).adopt_pages({}, None, None),
         lambda toy: make_engine(toy).swap_params(toy[1]),
-        lambda toy: LLMEngine(toy[0], toy[1]),
         lambda toy: tfm.forward(toy[1], jnp.zeros((1, 4), jnp.int32), toy[0]),
         lambda toy: tfm.make_train_step(toy[0], None),
         lambda toy: ContinuousBatchingEngine(
             tfm.ModelConfig(n_experts=4, n_layers=1)),
     ],
     ids=["prefix_cache", "prefill_extract", "adopt_pages", "swap_params",
-         "LLMEngine", "forward", "train_step",
-         "switch_experts"],
+         "forward", "train_step", "switch_experts"],
 )
 def test_a_path_that_lacks_the_feature_raises_a_typed_error(toy, call):
     with pytest.raises(tfm.UnsupportedModelFeature):

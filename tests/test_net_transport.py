@@ -160,10 +160,39 @@ def test_probe_survives_stale_pooled_connection(served):
     store, srv, link = served
     store.put_bytes(OID_B, b"p" * (1 << 16))
     assert bytes(tp.fetch_bytes(link, OID_B)) == b"p" * (1 << 16)
-    srv.chaos_drop()  # kills the server end of the pooled connection
-    time.sleep(0.05)
+    srv.chaos_drop()  # shuts down the server end of the pooled connection
     assert bytes(tp.fetch_bytes(link, OID_B)) == b"p" * (1 << 16)
-    assert srv.stats["stripes_served"] == 2
+    # the serving thread counts a stripe after its send has returned
+    _wait_for(lambda: srv.stats["stripes_served"] == 2, msg="second stripe")
+    assert srv.stats["connections_accepted"] == 2  # one redial
+
+
+@pytest.mark.parametrize("native", [True, False], ids=["c", "python"])
+def test_sever_reaches_the_peer_while_the_server_waits(
+    request, monkeypatch, native
+):
+    """A sever comes from a thread that does not own the connection, while
+    the serving thread is blocked reading the next request. The pooled
+    client end sees the end at once, without having to write first, and
+    the serving thread is done with its descriptor: a close alone left
+    both for the client's next request, and the number free for reuse
+    under the serving thread's next read."""
+    from ray_tpu.native.net import NetClosedError
+
+    if not native:
+        monkeypatch.setenv("RAY_TPU_NATIVE_NET", "0")
+    store, srv, link = request.getfixturevalue("served")
+    store.put_bytes(OID_B, b"s" * 1024)
+    assert bytes(tp.fetch_bytes(link, OID_B)) == b"s" * 1024
+    (pooled,) = link._idle
+    assert pooled.native == native
+    (serving,) = srv._conns.values()
+    assert srv.chaos_drop() == 1
+    pooled.set_timeout(10.0)
+    with pytest.raises(NetClosedError):
+        pooled.recv_exact(1)
+    # a sever leaves the close to the owner, which has woken to do it
+    _wait_for(lambda: serving.closed, msg="the serving thread to close")
 
 
 def test_resume_mid_stripe_after_chaos_sever(served, monkeypatch):

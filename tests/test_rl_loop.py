@@ -579,7 +579,6 @@ def test_rl_triple_chaos_soak(tmp_path):
             mcfg,
             name="rl-policy",
             num_replicas=2,
-            engine="continuous",
             max_batch=2,
             page_size=8,
             n_pages=64,

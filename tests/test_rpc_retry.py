@@ -179,3 +179,28 @@ def test_breaker_shared_across_clients_to_same_peer(monkeypatch):
     c1.close()
     c2.close()
     srv.stop()
+
+
+def test_a_fresh_server_on_a_reused_port_is_reached_at_once():
+    """A client that outlived its server is reconnecting with backoff. A
+    new server takes the port (ephemeral ports come round again in a long
+    process); a new client's first call reaches it, whatever the old
+    client's channel is waiting for."""
+    old_srv = RpcServer({"Echo": lambda r: "old"})
+    port = old_srv.port
+    stale = RpcClient(old_srv.address)
+    fresh = srv = None
+    try:
+        assert stale.call("Echo", timeout=5.0) == "old"
+        old_srv.stop(0)
+        with pytest.raises(RpcError):  # a failed connect: backoff begins
+            stale.call("Echo", timeout=2.0)
+        srv = RpcServer({"Echo": lambda r: "new"}, port=port)
+        fresh = RpcClient(srv.address)
+        assert fresh.call("Echo", timeout=5.0) == "new"
+    finally:
+        for c in (stale, fresh):
+            if c is not None:
+                c.close()
+        if srv is not None:
+            srv.stop()

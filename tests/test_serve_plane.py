@@ -569,7 +569,6 @@ def test_replica_kill_mid_stream_recovers():
             mcfg,
             name="chaos-llm",
             num_replicas=2,
-            engine="continuous",
             max_batch=2,
             page_size=8,
             n_pages=64,

@@ -316,14 +316,13 @@ def _run_cluster_shuffle():
 
 
 @pytest.mark.parametrize("native_net", ["1", "0"])
-def test_shuffle_content_exact_under_transport_killswitch(native_net):
+def test_shuffle_content_exact_under_transport_killswitch(
+    native_net, monkeypatch
+):
     """Socket plane on AND chunked-RPC fallback (RAY_TPU_NATIVE_NET=0):
     identical, content-exact shuffle output either way."""
-    os.environ["RAY_TPU_NATIVE_NET"] = native_net
-    try:
-        rows, counts = _run_cluster_shuffle()
-    finally:
-        os.environ.pop("RAY_TPU_NATIVE_NET", None)
+    monkeypatch.setenv("RAY_TPU_NATIVE_NET", native_net)
+    rows, counts = _run_cluster_shuffle()
     assert np.array_equal(np.sort(rows), np.arange(20000, dtype=np.float64))
     assert counts == {i: 200 for i in range(10)}
 
