@@ -627,7 +627,7 @@ def serve_phase(sz: Sizes, on_chip: bool, clock: CompileClock, dev) -> None:
             text = eng._decode_step.lower(
                 eng.params, eng.pool.k, eng.pool.v, eng.block_tables,
                 eng.positions, eng.cur_tokens, eng.active_mask, eng.temps,
-                eng.seeds,
+                eng.seeds, eng.pool.state,
             ).compile().as_text()
             check(
                 "tpu_custom_call" in text

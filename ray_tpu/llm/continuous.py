@@ -21,6 +21,10 @@ rebuilt TPU-first in the JetStream/PagedAttention mold:
   positions out of the pool where it lies; where there is no TPU, and for
   a windowed layer's ring, attention gathers each slot's table into a
   contiguous view (gathers + one big einsum, no dynamic shapes).
+- **State by slot**: a layer that is no attention (a gated short
+  convolution) keeps no K and V but a few columns of its own input for
+  each sequence: fixed size, indexed by slot, beside the pools
+  (``PagedKVPool.state``), with no pages to reserve or to wait for.
 
 Reference files for parity intent: vllm paged attention + continuous
 batching scheduler; JetStream's slot/page design is the public TPU
@@ -140,15 +144,20 @@ LANES = 128  # a TPU vector register's lanes: the tile of an array's last dim
 PREFILL_SCORES_BYTES = 2**30
 
 
-def stored_key_width(head_dim: int) -> int:
-    """Width a key is stored at: a head wider than one tile of ``LANES``
-    takes whole tiles (192 -> 256, zeros behind the key). The chip's
-    compiler gives a pool whose rows are one and a half tiles wide another
-    layout inside the step than at its ends and copies the whole pool
-    twice a program; rows of whole tiles keep one layout, in place."""
-    if head_dim <= LANES:
-        return head_dim
-    return -(-head_dim // LANES) * LANES
+def stored_width(size: int, kernel: bool = False) -> int:
+    """Width a key or a value is stored at: a head wider than one tile of
+    ``LANES`` takes whole tiles (192 -> 256, zeros behind the head's own
+    dims). The chip's compiler gives a pool whose rows are one and a half
+    tiles wide another layout inside the step than at its ends and copies
+    the whole pool twice a program; rows of whole tiles keep one layout, in
+    place. Where the Pallas decode kernel reads the pool (``kernel``: on a
+    TPU) a narrower head takes a whole tile too (64 -> 128): the chip lays
+    a row of 64 out in 128 lanes whatever it is declared as, and Mosaic
+    refuses the page's DMA out of it ("slice shape along dimension 4 must
+    be aligned to tiling (128), but is 64")."""
+    if size <= LANES and not kernel:
+        return size
+    return -(-size // LANES) * LANES
 
 
 class PagedKVPool:
@@ -169,13 +178,21 @@ class PagedKVPool:
     ``k`` and ``v`` map a class's name to its array
     ``[layers of the class, KV heads, pages, page, head size]``, head-major
     (the Pallas decode kernel and the gather path both read it without a
-    transpose); K and V heads may differ in size, and K's
-    is ``k_dim = stored_key_width(head_dim)``."""
+    transpose); K and V heads may differ in size, and are stored
+    ``k_dim = stored_width(head_dim, kernel)`` and ``v_dim`` wide.
+
+    ``state`` holds what the layers that are no attention keep for a
+    sequence, by slot and not by page: ``state["conv"]``
+    ``[convolution layers, conv_kernel - 1, max_batch, d_model]``, the last
+    columns of each layer's gated input, the oldest first. It has no free
+    list: a slot's columns are its occupant's from the prefill that wrote
+    them, nothing is reserved and nothing can run short."""
 
     def __init__(self, cfg: tfm.ModelConfig, n_pages: int, page: int,
-                 max_batch: int = 0):
+                 max_batch: int = 0, kernel: bool = False):
         self.page = page
-        self.k_dim = stored_key_width(cfg.head_dim)
+        self.k_dim = stored_width(cfg.head_dim, kernel)
+        self.v_dim = stored_width(cfg.v_head_dim, kernel)
         self.ring_pages = 0
         self.classes: Dict[str, _PageClass] = {}
         self.k: Dict[str, jax.Array] = {}
@@ -188,7 +205,13 @@ class PagedKVPool:
             self.classes[name] = _PageClass(kind, n)
             shape = (layers, kind.kv_heads, n, page)
             self.k[name] = jnp.zeros(shape + (self.k_dim,), cfg.dtype)
-            self.v[name] = jnp.zeros(shape + (cfg.v_head_dim,), cfg.dtype)
+            self.v[name] = jnp.zeros(shape + (self.v_dim,), cfg.dtype)
+        self.state: Dict[str, jax.Array] = {}
+        if cfg.state_layers:
+            self.state["conv"] = jnp.zeros(
+                (cfg.state_layers, cfg.conv_kernel - 1, max_batch, cfg.d_model),
+                cfg.dtype,
+            )
 
     @property
     def n_pages(self) -> int:
@@ -228,7 +251,8 @@ class PagedKVPool:
             self.classes[name].free(ids)
 
 
-_POOL = ("pool_k", "pool_v")  # the operands every pool writer donates
+# the operands every program that writes a sequence's memory donates
+_POOL = ("pool_k", "pool_v", "state")
 
 
 class KVPoolLost(RuntimeError):
@@ -237,7 +261,7 @@ class KVPoolLost(RuntimeError):
     the engine cannot serve until it is built again."""
 
 
-@functools.partial(jax.jit, donate_argnames=_POOL)
+@functools.partial(jax.jit, donate_argnames=("pool_k", "pool_v"))
 def _scatter_pages(pool_k, pool_v, pages, k, v):
     """Write whole pages that were computed elsewhere (a prefix-cache hit,
     a prefill worker's handoff) into the ``full`` class of the pool. k, v:
@@ -352,17 +376,28 @@ class ContinuousBatchingEngine:
                 "`n_routed_experts`)"
             )
         self.windowed = "window" in cfg.kv_classes()
-        if self.windowed and prefix_cache is not None:
+        self.stateful = cfg.state_layers > 0
+        if (self.windowed or self.stateful) and prefix_cache is not None:
             raise tfm.UnsupportedModelFeature(
                 "the shared prefix cache holds pages of the `full` class "
-                "alone; a model with a window class of KV page is served "
-                "with prefix_cache=False"
+                "alone; a model with a window class of KV page "
+                "(`attn_pattern` \"window\") or with state by slot "
+                "(`attn_pattern` \"conv\") is served with prefix_cache=False"
             )
         configure_compile_cache()
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
-        self.pool = PagedKVPool(cfg, n_pages, page_size, max_batch)
+        # how decode_step attends over the ``full`` class of page: on a TPU
+        # the Pallas kernel (ops/paged_attention.py), elsewhere the XLA
+        # gather. Read from the platform, set by no caller; the CPU tests
+        # put "interpret" here to run the kernel interpreted
+        self._attn_kernel = (
+            "compiled" if jax.default_backend() == "tpu" else None
+        )
+        self.pool = PagedKVPool(
+            cfg, n_pages, page_size, max_batch, kernel=bool(self._attn_kernel)
+        )
         self.max_pages_per_seq = min(
             max_pages_per_seq
             or (min(cfg.max_seq_len, n_pages * page_size) // page_size),
@@ -379,13 +414,6 @@ class ContinuousBatchingEngine:
             max(1, self.max_prefill_tokens // 4 // page_size) * page_size
         )
         self.tokenizer = tokenizer or ByteTokenizer()
-        # how decode_step attends over the ``full`` class of page: on a TPU
-        # the Pallas kernel (ops/paged_attention.py), elsewhere the XLA
-        # gather. Read from the platform, set by no caller; the CPU tests
-        # put "interpret" here to run the kernel interpreted
-        self._attn_kernel = (
-            "compiled" if jax.default_backend() == "tpu" else None
-        )
         # optional cross-replica prefix/KV cache (serve.prefix_cache):
         # page-aligned prompt prefixes restore from pinned shm views and
         # only the suffix pays prefill compute
@@ -395,6 +423,7 @@ class ContinuousBatchingEngine:
             if params is not None
             else tfm.init_params(cfg, jax.random.PRNGKey(0))
         )
+        cfg.require_blocks_by_run(self.params["blocks"])
         self._lock = threading.RLock()
         self.slots = [_Slot() for _ in range(self.B)]
         self.queue: deque = deque()
@@ -447,11 +476,16 @@ class ContinuousBatchingEngine:
         return self.max_pages_per_seq
 
     def _refuse_windowed(self, what: str) -> None:
-        if self.windowed:
-            raise tfm.UnsupportedModelFeature(
-                f"{what} moves pages of the `full` class alone and is not "
-                "implemented for a model with a window class of KV page"
-            )
+        """For the paths that move a sequence as pages of the ``full``
+        class: what else a sequence holds would be left behind."""
+        for has, field in ((self.windowed, "a window class of KV page"),
+                           (self.stateful, "state by slot (`attn_pattern` "
+                                           "\"conv\")")):
+            if has:
+                raise tfm.UnsupportedModelFeature(
+                    f"{what} moves pages of the `full` class alone and is "
+                    f"not implemented for a model with {field}"
+                )
 
     # ------------------------------------------------------------------
     # jitted programs
@@ -462,19 +496,51 @@ class ContinuousBatchingEngine:
         P_max = self.max_pages_per_seq
         S_max = P_max * page
         ring = self.pool.ring_pages * page  # tokens a slot's ring holds
-        k_dim = self.pool.k_dim
+        k_dim, v_dim = self.pool.k_dim, self.pool.v_dim
+        taps = cfg.conv_kernel - 1  # columns a convolution layer keeps
 
-        def stored(x):
-            """Keys at the width the pool stores them, or queries to meet
-            them: zeros behind the head's own dims."""
-            if x.shape[-1] == k_dim:
+        def stored(x, width=k_dim):
+            """Keys (or values, ``width=v_dim``) at the width the pool
+            stores them, or queries to meet them: zeros behind the head's
+            own dims."""
+            if x.shape[-1] == width:
                 return x
-            pad = [(0, 0)] * (x.ndim - 1) + [(0, k_dim - x.shape[-1])]
+            pad = [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])]
             return jnp.pad(x, pad)
 
-        def head_logits(params, h):
-            h = tfm.rms_norm(h, params["ln_f"], cfg.rms_eps)
-            return (h @ params["head"]).astype(jnp.float32)
+        # The two prefill programs carry through the stack the rows of ONE
+        # slot, ``[convolution layers, taps, D]``, and put them into the
+        # state once, after the last layer: with the whole state in the
+        # layers' scan the chip's compiler gave it another layout inside
+        # the program and copied it in and out.
+        def slot_rows(state, slot):
+            """``[convolution layers, taps, D]``; None of a model without."""
+            if not self.stateful:
+                return None
+            return jax.lax.dynamic_slice_in_dim(state["conv"], slot, 1, 2)[:, :, 0]
+
+        def put_slot_rows(state, slot, rows):
+            if rows is None:
+                return state
+            return {"conv": jax.lax.dynamic_update_slice_in_dim(
+                state["conv"], rows[:, :, None], slot, 2
+            )}
+
+        def shift_sequence(layer, s, cache, true_len):
+            """``run_stack``'s ``shift`` for ONE sequence's block of
+            tokens. s: [1, T, D]; ``cache["rows"][layer]``: [taps, D], the
+            columns before the block's first token. Leaves there the
+            ``taps`` columns before position ``true_len`` of the block (of
+            a padded block's real tokens, the last): what the padding
+            holds is never state."""
+            rows, t = cache["rows"], s.shape[1]
+            ext = jnp.concatenate([rows[layer].astype(s.dtype), s[0]], 0)
+            earlier = tuple(ext[j : j + t][None] for j in range(taps))
+            end = jax.lax.dynamic_slice_in_dim(ext, true_len, taps, 0)
+            rows = jax.lax.dynamic_update_index_in_dim(
+                rows, end.astype(rows.dtype), layer, 0
+            )
+            return earlier, {**cache, "rows": rows}
 
         def write_token(pool, layer, page_ids, offsets, active, x):
             """One token a slot at (page, offset), head-major. x: [B, KH,
@@ -508,7 +574,7 @@ class ContinuousBatchingEngine:
             groups = cfg.n_heads // kh
             s = valid.shape[1]
             ks = k_pages.reshape(kh, b, s, k_dim)
-            vs = v_pages.reshape(kh, b, s, cfg.v_head_dim)
+            vs = v_pages.reshape(kh, b, s, v_dim)
             qh = stored(q.reshape(b, kh, groups, cfg.head_dim))
             scores = jnp.einsum(
                 "bhgd,hbsd->bhgs",
@@ -523,7 +589,7 @@ class ContinuousBatchingEngine:
             attn = jnp.einsum(
                 "bhgs,hbsd->bhgd", probs, vs.astype(jnp.float32)
             )
-            return attn.reshape(b, cfg.n_heads * cfg.v_head_dim)
+            return attn[..., : cfg.v_head_dim].reshape(b, -1)
 
         def ring_positions(last):
             """The position each entry of a ring holds once ``last`` is
@@ -533,12 +599,18 @@ class ContinuousBatchingEngine:
             return last[..., None] - (last[..., None] - at) % ring
 
         # every program that writes the pool takes it donated and returns
-        # it as its last two results: the output aliases the input, so the
-        # scatter is in place and nothing of the pool's size is copied
+        # it as its second and third results: the output aliases the input,
+        # so the scatter is in place and nothing of the pool's size is
+        # copied. The state by slot of a model that has any goes the same
+        # way, as the last operand and a fourth result
+        def results(first, cache):
+            out = (first, cache["k"], cache["v"])
+            return out + (cache["state"],) if self.stateful else out
+
         @functools.partial(jax.jit, donate_argnames=_POOL)
         def decode_step(
             params, pool_k, pool_v, tables, positions, tokens, active,
-            temps, seeds,
+            temps, seeds, state,
         ):
             """One token for every slot. Inactive slots run the same
             math (one trace) but their KV writes are redirected to the
@@ -549,15 +621,18 @@ class ContinuousBatchingEngine:
             layers of the ``full`` class how many ran in the Pallas kernel
             and how many there were, the pages the kernel walked and the
             table entries of those layers, which the gather would have
-            read) and the pool."""
+            read; with state by slot two more: the layers that keep it and
+            the rows of it written, one a layer a live slot), the pool and
+            the state."""
             b = self.B
             h = params["embed"][tokens].astype(cfg.dtype)  # [B, D]
             # positions a slot's query sees, itself among them; none if idle
             lengths = jnp.where(active, positions + 1, 0)
             live_pages = jnp.sum(-(-lengths // page))
+            live_slots = jnp.sum(active)
 
             def attend(kind, layer, q, k, v, sink, cache):
-                pool_k, pool_v, walked = cache
+                pool_k, pool_v, walked = cache["k"], cache["v"], cache["walked"]
                 name, table = kind.name, tables[kind.name]
                 pk, pv = pool_k[name], pool_v[name]
                 # a windowed layer's table is the slot's ring of pages
@@ -574,7 +649,9 @@ class ContinuousBatchingEngine:
                 pk = write_token(
                     pk, layer, page_ids, offsets, active, stored(k)
                 )
-                pv = write_token(pv, layer, page_ids, offsets, active, v)
+                pv = write_token(
+                    pv, layer, page_ids, offsets, active, stored(v, v_dim)
+                )
                 # separate paths by the layer's kind: a ring's mask and
                 # sink are another computation, not other parameters of the
                 # kernel's. The kernel reads the pool where it lies, after
@@ -590,7 +667,7 @@ class ContinuousBatchingEngine:
                         stored(qh), pk, pv, layer, table, lengths,
                         scale=cfg.head_dim**-0.5,
                         interpret=self._attn_kernel == "interpret",
-                    ).reshape(b, cfg.n_heads * cfg.v_head_dim)
+                    )[..., : cfg.v_head_dim].reshape(b, -1)
                 else:
                     if kind.window:
                         held = ring_positions(positions)
@@ -608,16 +685,39 @@ class ContinuousBatchingEngine:
                     walked = walked + jnp.stack([
                         int(in_kernel), 1, in_kernel * live_pages, table.size
                     ]).astype(jnp.int32)
-                return attn, (
-                    {**pool_k, name: pk}, {**pool_v, name: pv}, walked
-                )
+                return attn, {
+                    **cache, "k": {**pool_k, name: pk},
+                    "v": {**pool_v, name: pv}, "walked": walked,
+                }
 
-            h, (pool_k, pool_v, walked), moe = tfm.run_stack(
+            def shift(layer, s, cache):
+                """Every slot's columns before its token, and the slot's
+                state moved on by one: an inactive slot keeps what its row
+                held, which no live slot reads."""
+                conv = cache["state"]["conv"]
+                held = conv[layer]  # [taps, B, D]
+                moved = jnp.concatenate(
+                    [held[1:], s[None].astype(held.dtype)], 0
+                )
+                moved = jnp.where(active[None, :, None], moved, held)
+                wrote = jnp.stack([1, live_slots]).astype(jnp.int32)
+                conv = jax.lax.dynamic_update_index_in_dim(conv, moved, layer, 0)
+                return tuple(held), {
+                    **cache, "state": {"conv": conv},
+                    "stated": cache["stated"] + wrote,
+                }
+
+            h, cache, moe = tfm.run_stack(
                 cfg, params["blocks"], h, positions,
-                (pool_k, pool_v, jnp.zeros((4,), jnp.int32)),
-                attend, live=active,
+                {"k": pool_k, "v": pool_v, "state": state,
+                 "walked": jnp.zeros((4,), jnp.int32),
+                 "stated": jnp.zeros((2,), jnp.int32)},
+                attend, live=active, shift=shift,
             )
-            logits = head_logits(params, h)
+            counts = [moe, cache["walked"]]
+            if self.stateful:
+                counts.append(cache["stated"])
+            logits = tfm.head_logits(cfg, params, h)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # per-slot key = fold(request seed, absolute position of the
             # token being produced); prefill samples its first token with
@@ -630,7 +730,7 @@ class ContinuousBatchingEngine:
                 )
             )(seeds, positions, logits, temps).astype(jnp.int32)
             nxt = jnp.where(temps > 0.0, sampled, greedy)
-            return (nxt, jnp.concatenate([moe, walked])), pool_k, pool_v
+            return results((nxt, jnp.concatenate(counts)), cache)
 
         def ring_write(pool, layer, table, first_page, x):
             """The last pages of a block of whole pages into a slot's
@@ -645,19 +745,27 @@ class ContinuousBatchingEngine:
             )
 
         @functools.partial(
-            jax.jit, static_argnums=(4,), donate_argnames=_POOL
+            jax.jit, static_argnames=("t_pad",), donate_argnames=_POOL
         )
-        def prefill(params, pool_k, pool_v, tokens, t_pad, page_ids):
+        def prefill(
+            params, pool_k, pool_v, tokens, t_pad, page_ids, state, slot,
+            true_len,
+        ):
             """Prefill ONE sequence of (padded) length t_pad from its
-            first token; write its KV into the given pages; return
-            (logits at every position, int32[2] expert counts). tokens:
-            int32[t_pad]; page_ids by class: ``full`` int32[t_pad // page],
-            ``window`` the slot's ring."""
+            first token; write its KV into the given pages and, of a model
+            with state by slot, the state of its first ``true_len`` tokens
+            (the real ones; the host picks their last row of the logits)
+            into row ``slot``; return (logits at every position, int32[2]
+            expert counts). tokens: int32[t_pad]; page_ids by class:
+            ``full`` int32[t_pad // page], ``window`` the slot's ring."""
             pos = jnp.arange(t_pad)
             h = params["embed"][tokens][None].astype(cfg.dtype)  # [1,T,D]
 
+            def shift(layer, s, cache):
+                return shift_sequence(layer, s, cache, true_len)
+
             def attend(kind, layer, q, k, v, sink, cache):
-                pool_k, pool_v = cache
+                pool_k, pool_v = cache["k"], cache["v"]
                 name, kh = kind.name, kind.kv_heads
                 pk, pv = pool_k[name], pool_v[name]
                 if kind.window:
@@ -671,7 +779,9 @@ class ContinuousBatchingEngine:
                     pk = ring_write(
                         pk, layer, page_ids[name], 0, stored(k[0])
                     )
-                    pv = ring_write(pv, layer, page_ids[name], 0, v[0])
+                    pv = ring_write(
+                        pv, layer, page_ids[name], 0, stored(v[0], v_dim)
+                    )
                 else:
                     # causal self-attention over the prompt
                     groups = cfg.n_heads // kh
@@ -695,16 +805,26 @@ class ContinuousBatchingEngine:
                     pk = write_pages(
                         pk, layer, page_ids[name], stored(k[0])
                     )
-                    pv = write_pages(pv, layer, page_ids[name], v[0])
-                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
+                    pv = write_pages(
+                        pv, layer, page_ids[name], stored(v[0], v_dim)
+                    )
+                return attn, {
+                    **cache, "k": {**pool_k, name: pk},
+                    "v": {**pool_v, name: pv},
+                }
 
-            h, (pool_k, pool_v), moe = tfm.run_stack(
-                cfg, params["blocks"], h, pos[None], (pool_k, pool_v), attend
+            # nothing lies before the prompt, whoever held the slot
+            rows = jax.tree.map(jnp.zeros_like, slot_rows(state, slot))
+            h, cache, moe = tfm.run_stack(
+                cfg, params["blocks"], h, pos[None],
+                {"k": pool_k, "v": pool_v, "rows": rows}, attend,
+                shift=shift,
             )
-            return (head_logits(params, h[0]), moe), pool_k, pool_v
+            cache["state"] = put_slot_rows(state, slot, cache["rows"])
+            return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
 
         @functools.partial(
-            jax.jit, static_argnums=(4,), donate_argnames=_POOL
+            jax.jit, static_argnames=("t_pad",), donate_argnames=_POOL
         )
         def prefill_suffix(
             params,
@@ -715,6 +835,9 @@ class ContinuousBatchingEngine:
             hist_len,
             table,
             suffix_page_ids,
+            state,
+            slot,
+            true_len,
         ):
             """Prefill the SUFFIX of a sequence whose first ``hist_len``
             tokens' KV is in its pages already (restored from the shared
@@ -725,7 +848,11 @@ class ContinuousBatchingEngine:
             ``hist_len``, a multiple of ``page``, is traced, so one program
             serves every split within a suffix-length bucket). A windowed
             layer reads the window before the suffix out of the slot's
-            ring, then writes the suffix's last pages over it. tokens:
+            ring, then writes the suffix's last pages over it. A
+            convolution layer takes row ``slot`` of its state, which the
+            sequence's earlier run left there, as the columns before the
+            suffix, and leaves there the state of the suffix's first
+            ``true_len`` tokens. tokens:
             int32[t_pad] padded suffix; table by class: ``full``
             int32[P_max], ``window`` the ring; suffix_page_ids:
             int32[t_pad // page] of the ``full`` class. Returns (logits
@@ -733,8 +860,11 @@ class ContinuousBatchingEngine:
             pos = hist_len + jnp.arange(t_pad)  # absolute positions
             h = params["embed"][tokens][None].astype(cfg.dtype)
 
+            def shift(layer, s, cache):
+                return shift_sequence(layer, s, cache, true_len)
+
             def attend(kind, layer, q, k, v, sink, cache):
-                pool_k, pool_v = cache
+                pool_k, pool_v = cache["k"], cache["v"]
                 name, kh = kind.name, kind.kv_heads
                 pk, pv = pool_k[name], pool_v[name]
                 if kind.window:
@@ -758,21 +888,22 @@ class ContinuousBatchingEngine:
                         stored(k[0]),
                     )
                     pv = ring_write(
-                        pv, layer, table[name], hist_len // page, v[0]
+                        pv, layer, table[name], hist_len // page,
+                        stored(v[0], v_dim),
                     )
                 else:
                     # scatter the suffix KV into its pages (prefill layout)
                     pk = write_pages(
                         pk, layer, suffix_page_ids, stored(k[0])
                     )
-                    pv = write_pages(pv, layer, suffix_page_ids, v[0])
+                    pv = write_pages(
+                        pv, layer, suffix_page_ids, stored(v[0], v_dim)
+                    )
                     # history + suffix keys via the slot's full table; key
                     # positions past hist_len + q_pos (incl. the scratch
                     # page behind unfilled table slots) are masked
                     ks = pk[layer][:, table[name]].reshape(kh, S_max, k_dim)
-                    vs = pv[layer][:, table[name]].reshape(
-                        kh, S_max, cfg.v_head_dim
-                    )
+                    vs = pv[layer][:, table[name]].reshape(kh, S_max, v_dim)
                     groups = cfg.n_heads // kh
                     qh = stored(q[0].reshape(t_pad, kh, groups, cfg.head_dim))
                     scores = jnp.einsum(
@@ -787,13 +918,20 @@ class ContinuousBatchingEngine:
                     probs = jax.nn.softmax(scores, axis=-1)
                     attn = jnp.einsum(
                         "tkgs,ksd->tkgd", probs, vs.astype(jnp.float32)
-                    ).reshape(t_pad, -1)[None]
-                return attn, ({**pool_k, name: pk}, {**pool_v, name: pv})
+                    )[..., : cfg.v_head_dim].reshape(t_pad, -1)[None]
+                return attn, {
+                    **cache, "k": {**pool_k, name: pk},
+                    "v": {**pool_v, name: pv},
+                }
 
-            h, (pool_k, pool_v), moe = tfm.run_stack(
-                cfg, params["blocks"], h, pos[None], (pool_k, pool_v), attend
+            rows = slot_rows(state, slot)
+            h, cache, moe = tfm.run_stack(
+                cfg, params["blocks"], h, pos[None],
+                {"k": pool_k, "v": pool_v, "rows": rows}, attend,
+                shift=shift,
             )
-            return (head_logits(params, h[0]), moe), pool_k, pool_v
+            cache["state"] = put_slot_rows(state, slot, cache["rows"])
+            return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
 
         self._decode_step = decode_step
         self._prefill = prefill
@@ -929,7 +1067,7 @@ class ContinuousBatchingEngine:
                     req, pages["full"], tables, hit
                 )
             else:
-                last_logits = self._prefill_prompt(prompt, pages, tables)
+                last_logits = self._prefill_prompt(prompt, pages, tables, si)
             if self.prefix_cache is not None:
                 # publish this prompt's full pages for other replicas
                 # (reads the pool AFTER prefill wrote it — the np gather
@@ -982,16 +1120,17 @@ class ContinuousBatchingEngine:
         }
 
     def _write_pool(self, program):
-        """Run ``program(pool_k, pool_v)``, a call of one of the programs
-        that write the pool, and rebind the pool from its last two
-        results. This is the only place that holds the pool's arrays: the
-        programs take them donated, so the arrays passed in are dead once
-        the call is made. Returns the program's first result."""
-        k, v = self.pool.k, self.pool.v
+        """Run ``program(pool_k, pool_v, state)``, a call of one of the
+        programs that write the pool or the state by slot, and rebind
+        them from its results after the first. This is the only place that
+        holds their arrays: the programs take them donated, so the arrays
+        passed in are dead once the call is made. Returns the program's
+        first result."""
+        k, v, state = self.pool.k, self.pool.v, self.pool.state
         try:
-            out = program(k, v)
+            out = program(k, v, state)
         except Exception as e:
-            if any(a.is_deleted() for a in jax.tree.leaves((k, v))):
+            if any(a.is_deleted() for a in jax.tree.leaves((k, v, state))):
                 raise KVPoolLost(
                     "the KV pool was donated to a program that then failed "
                     f"({type(e).__name__}: {e}); the pages of "
@@ -999,12 +1138,21 @@ class ContinuousBatchingEngine:
                     "lost and this engine must be rebuilt"
                 ) from e
             raise
-        self.pool.k, self.pool.v = out[-2:]
+        self.pool.k, self.pool.v, *state = out[1:]
+        if state:
+            (self.pool.state,) = state
         return out[0]
 
-    def _prefill_prompt(self, prompt, pages, tables):
+    def _scatter(self, pages, k_src, v_src) -> None:
+        """Whole pages computed elsewhere into the ``full`` class."""
+        self._write_pool(
+            lambda k, v, _: (None, *_scatter_pages(k, v, pages, k_src, v_src))
+        )
+
+    def _prefill_prompt(self, prompt, pages, tables, slot: int = 0):
         """Prefill the whole (padded) prompt, its KV written into the
-        first of ``pages``: one run of the prefill program up to
+        first of ``pages`` and the state of its true end into row ``slot``
+        of the state by slot: one run of the prefill program up to
         ``max_prefill_tokens``; a longer prompt's head through it and the
         rest through the history-plus-suffix program in chunks of
         ``prefill_chunk`` tokens, so that no temporary grows with the
@@ -1028,13 +1176,18 @@ class ContinuousBatchingEngine:
             for name, ids in pages.items()
         }
         with tracing.span(
-            "engine.prefill", "engine", t_pad=t_pad, hit_tokens=0,
-            chunks=1 + chunks,
+            "engine.prefill", "engine", t_pad=t_pad, true_len=t,
+            hit_tokens=0, chunks=1 + chunks,
         ) as sp:
+            if self.stateful:
+                # rows of state written: one a convolution layer a run of
+                # a program, the last run's at the prompt's true end
+                sp.set(state_written=self.cfg.state_layers * (1 + chunks))
+            slot = np.int32(slot)
             logits, moe = self._write_pool(
-                lambda k, v: self._prefill(
+                lambda k, v, state: self._prefill(
                     self.params, k, v, jnp.asarray(tokens[:head]), head,
-                    page_ids,
+                    page_ids, state, slot, np.int32(min(t, head)),
                 )
             )
             pairs = [moe]
@@ -1044,7 +1197,8 @@ class ContinuousBatchingEngine:
                 }
             for at in range(head, t_pad, chunk):
                 logits, moe = self._prefill_chunk(
-                    tokens[at : at + chunk], at, dev_tables, pages
+                    tokens[at : at + chunk], at, dev_tables, pages, slot,
+                    min(t - at, chunk),
                 )
                 pairs.append(moe)
             # read once the first token is (``_settle_prefill_counts``)
@@ -1061,10 +1215,12 @@ class ContinuousBatchingEngine:
         if sp and self.cfg.n_routed_experts:
             sp.set(moe_pairs_held=sum(int(m[0]) for m in counts))
 
-    def _prefill_chunk(self, tokens, hist_len: int, dev_tables, pages):
+    def _prefill_chunk(self, tokens, hist_len: int, dev_tables, pages,
+                       slot, true_len: int):
         """One run of the history-plus-suffix program over ``tokens``
-        (padded to whole pages), the sequence's first ``hist_len`` tokens
-        (whole pages) being in its pages already."""
+        (padded to whole pages; the first ``true_len`` are real), the
+        sequence's first ``hist_len`` tokens (whole pages) being in its
+        pages, and their state in row ``slot``, already."""
         t_pad = len(tokens)
         first = hist_len // self.page
         suffix_pages = np.asarray(
@@ -1072,9 +1228,10 @@ class ContinuousBatchingEngine:
             np.int32,
         )
         return self._write_pool(
-            lambda k, v: self._prefill_suffix(
+            lambda k, v, state: self._prefill_suffix(
                 self.params, k, v, jnp.asarray(tokens), t_pad,
-                jnp.int32(hist_len), dev_tables, suffix_pages,
+                jnp.int32(hist_len), dev_tables, suffix_pages, state, slot,
+                np.int32(true_len),
             )
         )
 
@@ -1095,22 +1252,20 @@ class ContinuousBatchingEngine:
             k_src = jnp.asarray(np.asarray(k_src))
         if isinstance(v_src, np.ndarray):
             v_src = jnp.asarray(np.asarray(v_src))
-        self._write_pool(
-            lambda k, v: _scatter_pages(k, v, dev_pages, k_src, v_src)
-        )
+        self._scatter(dev_pages, k_src, v_src)
         suffix = req.prompt[hit.tokens :]
         ts = len(suffix)
         t_pad = max(self.page, -(-ts // self.page) * self.page)
         tokens = np.zeros(t_pad, np.int32)
         tokens[:ts] = suffix
         with tracing.span(
-            "engine.prefill", "engine", t_pad=t_pad,
+            "engine.prefill", "engine", t_pad=t_pad, true_len=ts,
             hit_tokens=int(hit.tokens), chunks=1,
         ):
             logits, _ = self._prefill_chunk(
                 tokens, int(hit.tokens),
                 {n: jnp.asarray(row) for n, row in tables.items()},
-                {"full": pages},
+                {"full": pages}, np.int32(0), ts,
             )
         return logits[ts - 1]
 
@@ -1277,7 +1432,7 @@ class ContinuousBatchingEngine:
         req.t_admit = req.t_first = req.t_submit
         rid = req.req_id
         dev = np.asarray(pages["full"][:ship_pages], np.int32)
-        self._write_pool(lambda pk, pv: _scatter_pages(pk, pv, dev, k, v))
+        self._scatter(dev, k, v)
         first = int(manifest["first"])
         slot = self.slots[si]
         slot.active = True
@@ -1409,19 +1564,20 @@ class ContinuousBatchingEngine:
                         pages_written=sum(written),
                         queued=len(self.queue),
                     )
+                    if self.windowed or self.stateful:
+                        decode.set(full_pages=sum(written))
                     if self.windowed:
                         decode.set(
-                            full_pages=sum(written),
                             window_pages=sum(
                                 min(n, self.pool.ring_pages) for n in written
                             ),
                         )
                 with decode:
                     nxt, counts = self._write_pool(
-                        lambda k, v: self._decode_step(
+                        lambda k, v, state: self._decode_step(
                             self.params, k, v, self.block_tables,
                             self.positions, self.cur_tokens,
-                            self.active_mask, self.temps, self.seeds,
+                            self.active_mask, self.temps, self.seeds, state,
                         )
                     )
                 if decode:
@@ -1432,13 +1588,18 @@ class ContinuousBatchingEngine:
                 if decode:
                     # the step's sums are known once its tokens are: the
                     # ring's record shares the span's args
-                    pairs, hit, in_kernel, full, walked, entries = (
+                    pairs, hit, in_kernel, full, walked, entries, *stated = (
                         np.asarray(counts).tolist()
                     )
                     decode.set(
                         attn_kernel_layers=in_kernel, attn_full_layers=full,
                         attn_pages_walked=walked, attn_table_entries=entries,
                     )
+                    if stated:
+                        decode.set(
+                            state_layers=stated[0],
+                            state_slots_written=stated[1],
+                        )
                     if self.cfg.n_routed_experts:
                         decode.set(moe_pairs_held=pairs, moe_experts_hit=hit)
                 self.positions = self.positions + jnp.where(

@@ -98,12 +98,13 @@ def init_experts(n_routed: int, held: int, d_model: int, d_ff: int,
     }
 
 
-def route(p, x: jax.Array, top_k: int):
+def route(p, x: jax.Array, top_k: int, norm_eps: float = 0.0,
+          scale: float = 1.0):
     """Sigmoid scores and selection in float32. x: [N, D]. Returns the
     chosen experts' ids [N, k] over the router's whole width and their
     weights [N, k]: the ``top_k`` largest of score + bias, weighed by the
     score alone, normalised over all the chosen (held by this holder or
-    not)."""
+    not; ``norm_eps`` under the sum), times ``scale``."""
     logits = jnp.matmul(
         x.astype(jnp.float32), p["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
@@ -111,10 +112,15 @@ def route(p, x: jax.Array, top_k: int):
     scores = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(scores + p["router_bias"], top_k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
-    return chosen, weights / jnp.sum(weights, axis=-1, keepdims=True)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    if norm_eps:
+        total = total + norm_eps
+    weights = weights / total
+    return chosen, weights if scale == 1.0 else weights * scale
 
 
-def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None):
+def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
+                  norm_eps: float = 0.0, scale: float = 1.0):
     """This holder's part of an expert layer: ``sum of w_e * SwiGLU_e(x)``
     over the experts a token chose that are held here, experts
     ``held[0] .. held[0] + held[1] - 1`` of the router's width. No token
@@ -123,7 +129,8 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None):
     all holders add up to it.
 
     x: [N, D]; ``live``: bool[N], tokens that count (a decode step's
-    inactive slots choose nothing). Token-expert pairs are sorted by held
+    inactive slots choose nothing); ``norm_eps`` and ``scale`` are the
+    router's (``route``). Token-expert pairs are sorted by held
     expert and go through one grouped matmul (``lax.ragged_dot``: on the
     TPU a kernel that visits the rows of each group with that group's
     weights, so the kernel itself skips an expert no token chose). Under
@@ -139,7 +146,7 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None):
     n, d = x.shape
     first, count = held
     n_routed = p["router"].shape[-1]
-    chosen, weights = route(p, x, top_k)
+    chosen, weights = route(p, x, top_k, norm_eps, scale)
     local = chosen - first
     here = (local >= 0) & (local < count)
     if live is not None:

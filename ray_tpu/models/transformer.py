@@ -46,29 +46,49 @@ class UnsupportedModelFeature(NotImplementedError):
     the field's name: nothing computes something else instead."""
 
 
+class StackLayoutError(ValueError):
+    """``params["blocks"]`` of a stack by position is not laid out as the
+    program reads it: one stack of weights for each run of consecutive
+    layers of one kind (``ModelConfig.layer_runs``), ``count`` layers
+    each. Weights stacked by kind of layer, several runs in one stack,
+    are that of a kind with one run only."""
+
+
 @dataclass(frozen=True)
 class AttnKind:
-    """What one kind of attention layer fixes. ``name`` is also the name
-    of its class of KV page (``PagedKVPool``)."""
-    name: str            # "full" | "window"
+    """What one kind of token mixer fixes. ``name`` is also the name of
+    what its layers keep for a sequence (``PagedKVPool``): a class of KV
+    page for the two kinds of attention, and for ``conv`` (a gated short
+    convolution, no K and V) a few columns of state by slot."""
+    name: str            # "full" | "window" | "conv"
     kv_heads: int
     rope_theta: float
     window: int          # 0 = every earlier key
     sink: bool           # a learned per-head column that takes mass only
 
 
+CONV = AttnKind("conv", 0, 0.0, 0, False)
+
+
 @dataclass(frozen=True)
 class LayerRun:
-    """Consecutive layers of one kind: ``count`` rows from ``start`` of
-    the kind's stacked weights (``key`` in ``params["blocks"]``; ``None``
-    where the stack is uniform and ``blocks`` is the one stack), and
-    where the run's first layer lies in its class of KV page."""
+    """Consecutive layers of one kind: the ``count`` layers of the run's
+    own stack of weights (``key`` in ``params["blocks"]``; ``None`` where
+    the stack is uniform and ``blocks`` is the one stack), and where the
+    run's first layer lies in what its kind keeps for a sequence (its
+    class of KV page, or the convolution layers' state)."""
     attn: AttnKind
     experts: bool
     key: Optional[str]
-    start: int
     count: int
     cache_start: int
+
+    @property
+    def start(self) -> int:
+        """Row of the run's first layer in its stack: 0, since every run
+        has a stack of its own (``benchmarks/tests/test_fault_toy.py`` pins
+        the name; a ``benchmark`` PR can drop it there and here)."""
+        return 0
 
 
 @dataclass(frozen=True)
@@ -100,18 +120,23 @@ class ModelConfig:
     value_scale: float = 1.0    # values are scaled before attention
     # -- the stack by position. Empty patterns: every layer full attention
     # and a dense feed-forward, one uniform stack (the defaults above) ----
-    attn_pattern: Tuple[str, ...] = ()    # "full" | "window", one a layer
+    attn_pattern: Tuple[str, ...] = ()    # "full" | "window" | "conv", one a layer
     ffn_pattern: Tuple[str, ...] = ()     # "dense" | "experts", one a layer
     window: int = 0                       # keys a windowed query sees, itself among them
     window_kv_heads: int = 0              # 0 = n_kv_heads
     window_rope_theta: float = 0.0        # 0 = rope_theta
     window_sink: bool = False
+    qk_norm: bool = False                 # RMS norm over each head of q and k, before the rotary
+    conv_kernel: int = 0                  # taps of a "conv" layer's causal depthwise convolution
+    tie_embeddings: bool = False          # the head is the embedding, transposed
     # -- expert layers (models/moe.py experts_apply): dropless top-k over
     # the published router width, this holder's experts computed ----------
     d_ff_expert: int = 0
     n_routed_experts: int = 0
     experts_per_token: int = 0
     experts_held: Tuple[int, int] = (0, 0)   # (first, count); (0, 0) = all
+    router_norm_eps: float = 0.0          # under the sum the chosen scores are normalised by
+    routed_scaling: float = 1.0           # times the normalised weights
 
     def __post_init__(self):
         derived = {
@@ -126,7 +151,7 @@ class ModelConfig:
             derived["experts_held"] = (0, self.n_routed_experts)
         for name, value in derived.items():
             object.__setattr__(self, name, value)
-        for name, allowed in (("attn_pattern", ("full", "window")),
+        for name, allowed in (("attn_pattern", ("full", "window", "conv")),
                               ("ffn_pattern", ("dense", "experts"))):
             pattern = derived[name]
             if pattern and (
@@ -138,6 +163,8 @@ class ModelConfig:
                 )
         if "window" in derived["attn_pattern"] and self.window < 1:
             raise ValueError("a windowed layer needs `window` >= 1")
+        if "conv" in derived["attn_pattern"] and self.conv_kernel < 2:
+            raise ValueError("a convolution layer needs `conv_kernel` >= 2")
         if "experts" in derived["ffn_pattern"]:
             first, count = derived["experts_held"]
             if not (
@@ -161,6 +188,8 @@ class ModelConfig:
         return not self.attn_pattern and not self.ffn_pattern
 
     def attn_kind(self, name: str) -> AttnKind:
+        if name == "conv":
+            return CONV
         if name == "window":
             return AttnKind(
                 "window", self.window_kv_heads or self.n_kv_heads,
@@ -176,29 +205,62 @@ class ModelConfig:
         return tuple(zip(attn, ffn))
 
     def layer_runs(self) -> Tuple[LayerRun, ...]:
-        """The stack as runs of consecutive layers of one kind, in order."""
-        runs, in_stack, in_class = [], {}, {}
-        for attn, ffn in self.layer_kinds():
-            key = None if self.uniform else f"{attn}.{ffn}"
-            last = runs[-1] if runs else None
-            if last is not None and last.key == key:
-                runs[-1] = dataclasses.replace(last, count=last.count + 1)
+        """The stack as runs of consecutive layers of one kind, in order.
+        Each run has a stack of weights of its own: ``<attention>.<feed-
+        forward>`` names a kind's first run, ``<...>.<n>`` its n-th later
+        one. (A run that was a slice of its kind's stack had the slice
+        copied out every step by the chip's compiler: a ``lax.scan`` reads
+        a whole buffer.)"""
+        runs, last_kind, of_kind, in_class = [], None, {}, {}
+        for kind in self.layer_kinds():
+            attn, ffn = kind
+            if runs and kind == last_kind:
+                runs[-1] = dataclasses.replace(runs[-1], count=runs[-1].count + 1)
             else:
+                n = of_kind.get(kind, 0)
+                of_kind[kind] = n + 1
+                key = f"{attn}.{ffn}" + (f".{n}" if n else "")
                 runs.append(LayerRun(
-                    self.attn_kind(attn), ffn == "experts", key,
-                    in_stack.get(key, 0), 1, in_class.get(attn, 0),
+                    self.attn_kind(attn), ffn == "experts",
+                    None if self.uniform else key, 1, in_class.get(attn, 0),
                 ))
-            in_stack[key] = in_stack.get(key, 0) + 1
+            last_kind = kind
             in_class[attn] = in_class.get(attn, 0) + 1
         return tuple(runs)
 
+    def require_blocks_by_run(self, blocks) -> None:
+        """Raises ``StackLayoutError`` where a stack by position's
+        ``blocks`` are not one stack a run (``layer_runs``): by whoever
+        takes weights (the engine as it is built, ``run_stack`` as it is
+        traced), so that weights stacked another way fail by name and
+        never as a ``KeyError`` or a scan over the wrong layers."""
+        if self.uniform:
+            return
+        runs = {run.key: run.count for run in self.layer_runs()}
+        have = {k: v["ln1"].shape[0] for k, v in blocks.items()}
+        if have != runs:
+            raise StackLayoutError(
+                "`blocks` holds stacks (layers each) "
+                f"{dict(sorted(have.items()))}, the pattern's runs are "
+                f"{dict(sorted(runs.items()))}: the program reads one "
+                "stack of weights for each run of consecutive layers of "
+                "one kind (`ModelConfig.layer_runs`)"
+            )
+
     def kv_classes(self) -> Dict[str, Tuple[int, AttnKind]]:
         """Classes of KV page, by the attention kind that writes them:
-        name -> (layers of that kind, the kind)."""
+        name -> (layers of that kind, the kind). A convolution layer
+        writes none."""
         counts: Dict[str, int] = {}
         for attn, _ in self.layer_kinds():
-            counts[attn] = counts.get(attn, 0) + 1
+            if attn != "conv":
+                counts[attn] = counts.get(attn, 0) + 1
         return {n: (c, self.attn_kind(n)) for n, c in sorted(counts.items())}
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep state by slot and no pages: the convolutions."""
+        return sum(1 for attn, _ in self.layer_kinds() if attn == "conv")
 
     def require_uniform_dense(self, path: str) -> None:
         """For the paths that run the one uniform block with heads of one
@@ -213,7 +275,8 @@ class ModelConfig:
                 )
         derived = self.d_model // self.n_heads
         for name, plain in (("head_dim", derived), ("v_head_dim", derived),
-                            ("rotary_dim", derived), ("value_scale", 1.0)):
+                            ("rotary_dim", derived), ("value_scale", 1.0),
+                            ("qk_norm", False), ("tie_embeddings", False)):
             if getattr(self, name) != plain:
                 raise UnsupportedModelFeature(
                     f"{path} does not implement `{name}`="
@@ -228,10 +291,11 @@ def _dense_init(key, *shape, dtype, scale=None):
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     """Stacked-layer parameter pytree. A uniform stack keeps its layers
-    under ``blocks``; a stack by position keeps one stack for each kind of
-    layer under ``blocks["<attention>.<feed-forward>"]``."""
+    under ``blocks``; a stack by position keeps one stack for each run of
+    consecutive layers of one kind under ``blocks[<the run's key>]``
+    (``layer_runs``)."""
     if not cfg.uniform:
-        return _init_params_by_kind(cfg, key)
+        return _init_params_by_run(cfg, key)
     k = jax.random.split(key, 12)
     d, hd = cfg.d_model, cfg.head_dim
     L = cfg.n_layers
@@ -266,29 +330,31 @@ def init_params(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     }
 
 
-def _init_params_by_kind(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
+def _init_params_by_run(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     d, dt = cfg.d_model, cfg.dtype
     first, held = cfg.experts_held
 
     dense = functools.partial(_dense_init, dtype=dt)
-    counts: Dict[Tuple[str, str], int] = {}
-    for kind in cfg.layer_kinds():
-        counts[kind] = counts.get(kind, 0) + 1
     blocks = {}
-    for i, ((attn, ffn), n) in enumerate(sorted(counts.items())):
-        k = jax.random.split(jax.random.fold_in(key, i), 9)
-        kind = cfg.attn_kind(attn)
-        p = {
-            "ln1": jnp.ones((n, d), dt),
-            "ln2": jnp.ones((n, d), dt),
-            "wq": dense(k[0], n, d, cfg.n_heads * cfg.head_dim),
-            "wk": dense(k[1], n, d, kind.kv_heads * cfg.head_dim),
-            "wv": dense(k[2], n, d, kind.kv_heads * cfg.v_head_dim),
-            "wo": dense(k[3], n, cfg.n_heads * cfg.v_head_dim, d),
-        }
+    for i, run in enumerate(cfg.layer_runs()):
+        k = jax.random.split(jax.random.fold_in(key, i), 10)
+        kind, n = run.attn, run.count
+        p = {"ln1": jnp.ones((n, d), dt), "ln2": jnp.ones((n, d), dt)}
+        if kind.name == "conv":
+            p["w_in"] = dense(k[0], n, d, 3 * d)
+            p["conv"] = dense(k[1], n, cfg.conv_kernel, d)
+            p["w_out"] = dense(k[3], n, d, d)
+        else:
+            p["wq"] = dense(k[0], n, d, cfg.n_heads * cfg.head_dim)
+            p["wk"] = dense(k[1], n, d, kind.kv_heads * cfg.head_dim)
+            p["wv"] = dense(k[2], n, d, kind.kv_heads * cfg.v_head_dim)
+            p["wo"] = dense(k[3], n, cfg.n_heads * cfg.v_head_dim, d)
+            if cfg.qk_norm:
+                p["q_norm"] = jnp.ones((n, cfg.head_dim), dt)
+                p["k_norm"] = jnp.ones((n, cfg.head_dim), dt)
         if kind.sink:
             p["sink"] = jax.random.normal(k[4], (n, cfg.n_heads), jnp.float32)
-        if ffn == "experts":
+        if run.experts:
             p["moe"] = moe_mod.init_experts(
                 cfg.n_routed_experts, held, d, cfg.d_ff_expert, n, k[5], dt
             )
@@ -296,14 +362,16 @@ def _init_params_by_kind(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
             p["w_gate"] = dense(k[6], n, d, cfg.d_ff)
             p["w_up"] = dense(k[7], n, d, cfg.d_ff)
             p["w_down"] = dense(k[8], n, cfg.d_ff, d)
-        blocks[f"{attn}.{ffn}"] = p
-    k = jax.random.split(jax.random.fold_in(key, len(counts)), 2)
-    return {
+        blocks[run.key] = p
+    k = jax.random.split(jax.random.fold_in(key, len(blocks)), 2)
+    params = {
         "embed": dense(k[0], cfg.vocab_size, d, scale=0.02),
         "blocks": blocks,
         "ln_f": jnp.ones((d,), dt),
-        "head": dense(k[1], d, cfg.vocab_size),
     }
+    if not cfg.tie_embeddings:
+        params["head"] = dense(k[1], d, cfg.vocab_size)
+    return params
 
 
 def param_specs(cfg: ModelConfig, pp: int = 1) -> Dict[str, Any]:
@@ -559,33 +627,71 @@ def rotate(x: jax.Array, ang: jax.Array) -> jax.Array:
     return jnp.concatenate([out, x[..., r:]], -1)
 
 
-def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, attend,
+def head_logits(cfg: ModelConfig, params, h: jax.Array) -> jax.Array:
+    """Final norm and the head: float32 logits over the vocabulary. A
+    model with ``tie_embeddings`` has no ``head``: the embedding is read
+    the other way."""
+    h = rms_norm(h, params["ln_f"], cfg.rms_eps)
+    if cfg.tie_embeddings:
+        return jnp.einsum("...d,vd->...v", h, params["embed"]).astype(jnp.float32)
+    return (h @ params["head"]).astype(jnp.float32)
+
+
+def gated_conv(cfg: ModelConfig, p, x, shift):
+    """The operator of a ``conv`` layer: ``[B, C, z] = x W_in``; ``s = B *
+    z``; a causal depthwise convolution of ``s`` over the last
+    ``conv_kernel`` tokens (``p["conv"]``: one row a tap, the oldest
+    first); ``(C * that) W_out``. ``shift(s)`` is the caller's: it gives
+    the ``conv_kernel - 1`` columns of ``s`` before each token (oldest
+    first, each shaped like ``s``; zeros before the sequence), keeps the
+    last of them where the caller keeps a sequence's state, and returns
+    them with the caller's cache."""
+    gate_in, gate_out, z = jnp.split(x @ p["w_in"], 3, axis=-1)
+    s = gate_in * z
+    earlier, cache = shift(s)
+    mixed = sum(
+        tap.astype(jnp.float32) * col.astype(jnp.float32)
+        for tap, col in zip(p["conv"], (*earlier, s))
+    )
+    return (gate_out * mixed.astype(cfg.dtype)) @ p["w_out"], cache
+
+
+def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
                   live=None):
-    """One layer of the kind ``run`` names: norm, Q K V, rotary, attention
-    through the caller's cache, feed-forward. h: [..., D]; ``ang``: the
-    rotary angles [..., rotary_dim / 2] of h's tokens at the kind's rope
-    base. ``attend(q, k, v, sink)`` gets
-    [..., heads, size] arrays (``sink``: float32[H] or None), writes k and v
-    where the caller keeps them and returns (float32 [..., H * v_head_dim],
-    the caller's cache). Returns (h, that cache, int32[2]: token-expert
-    pairs this holder computed and held experts hit; zeros in a dense
-    layer). ``live``: bool over the leading dims, tokens whose choice of
-    expert counts."""
+    """One layer of the kind ``run`` names: norm, the token mixer through
+    the caller's cache, feed-forward. h: [..., D]; ``ang``: the rotary
+    angles [..., rotary_dim / 2] of h's tokens at the kind's rope base.
+    An attention layer: Q K V, rotary, and ``mix(q, k, v, sink)``, which
+    gets [..., heads, size] arrays (``sink``: float32[H] or None), writes k
+    and v where the caller keeps them and returns (float32 [..., H *
+    v_head_dim], the caller's cache). A convolution layer: ``gated_conv``
+    with ``mix`` as its ``shift``. Returns (h, that cache, int32[2]:
+    token-expert pairs this holder computed and held experts hit; zeros in
+    a dense layer). ``live``: bool over the leading dims, tokens whose
+    choice of expert counts."""
     lead, kind = h.shape[:-1], run.attn
     x = rms_norm(h, p["ln1"], cfg.rms_eps)
-    q = (x @ p["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
-    k = (x @ p["wk"]).reshape(*lead, kind.kv_heads, cfg.head_dim)
-    v = (x @ p["wv"]).reshape(*lead, kind.kv_heads, cfg.v_head_dim)
-    if cfg.value_scale != 1.0:
-        v = v * cfg.value_scale
-    attn, cache = attend(rotate(q, ang), rotate(k, ang), v, p.get("sink"))
-    h = h + (attn.astype(cfg.dtype) @ p["wo"])
+    if kind.name == "conv":
+        op, cache = gated_conv(cfg, p, x, mix)
+        h = h + op
+    else:
+        q = (x @ p["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
+        k = (x @ p["wk"]).reshape(*lead, kind.kv_heads, cfg.head_dim)
+        v = (x @ p["wv"]).reshape(*lead, kind.kv_heads, cfg.v_head_dim)
+        if cfg.qk_norm:
+            q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+            k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+        if cfg.value_scale != 1.0:
+            v = v * cfg.value_scale
+        attn, cache = mix(rotate(q, ang), rotate(k, ang), v, p.get("sink"))
+        h = h + (attn.astype(cfg.dtype) @ p["wo"])
     x2 = rms_norm(h, p["ln2"], cfg.rms_eps)
     if run.experts:
         y, pairs, hit = moe_mod.experts_apply(
             p["moe"], x2.reshape(-1, cfg.d_model),
             top_k=cfg.experts_per_token, held=cfg.experts_held,
             live=None if live is None else live.reshape(-1),
+            norm_eps=cfg.router_norm_eps, scale=cfg.routed_scaling,
         )
         return h + y.reshape(h.shape), cache, jnp.stack([pairs, hit])
     y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
@@ -593,33 +699,33 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, attend,
 
 
 def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
-              live=None):
+              live=None, shift=None):
     """Every layer in the pattern's order, each run of one kind a
-    ``lax.scan`` over that kind's stacked weights. ``positions``: int32
-    of h's leading dims. ``attend(kind, layer, q, k, v, sink, cache) -> (attention, cache)``:
-    ``layer`` counts within the kind's class of KV page. Returns (h, cache,
-    the blocks' int32[2] counts summed)."""
+    ``lax.scan`` over the run's stacked weights. ``positions``: int32
+    of h's leading dims. ``attend(kind, layer, q, k, v, sink, cache) ->
+    (attention, cache)`` and, for a convolution layer, ``shift(layer, s,
+    cache) -> (the columns before each token, cache)``: ``layer`` counts
+    within what the kind keeps for a sequence (its class of KV page, the
+    convolutions' state). Returns (h, cache, the blocks' int32[2] counts
+    summed)."""
+    cfg.require_blocks_by_run(blocks)
     counts = jnp.zeros((2,), jnp.int32)
     for run in cfg.layer_runs():
         stack = blocks if run.key is None else blocks[run.key]
-        if (run.start, run.count) != (0, stack["ln1"].shape[0]):
-            stack = jax.tree.map(
-                lambda a: a[run.start : run.start + run.count], stack
-            )
-
-        ang = rope_freqs(
+        conv = run.attn.name == "conv"
+        ang = None if conv else rope_freqs(
             cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
         )[positions]
 
-        def body(carry, p, run=run, ang=ang):
+        def body(carry, p, run=run, ang=ang, conv=conv):
             h, cache, layer, counts = carry
-            h, cache, c = decoder_block(
-                cfg, run, p, h, ang,
-                lambda q, k, v, sink: attend(
-                    run.attn, layer, q, k, v, sink, cache
-                ),
-                live,
-            )
+            if conv:
+                def mix(s):
+                    return shift(layer, s, cache)
+            else:
+                def mix(q, k, v, sink):
+                    return attend(run.attn, layer, q, k, v, sink, cache)
+            h, cache, c = decoder_block(cfg, run, p, h, ang, mix, live)
             return (h, cache, layer + 1, counts + c), None
 
         (h, cache, _, counts), _ = jax.lax.scan(

@@ -92,7 +92,7 @@ PAGE = 16
 
 
 def _model(name):
-    """The three configurations' widths, slots and pages of the full class
+    """The configurations' widths, slots and pages of the full class
     (``benchmarks/configs``), contexts to ``max_seq_len``."""
     from ray_tpu.models import transformer as tfm
 
@@ -108,6 +108,18 @@ def _model(name):
             n_kv_heads=8, d_ff=8192, max_seq_len=1536, rope_theta=1e6,
             dtype=jnp.bfloat16,
         ), 32, 2560
+    if name == "lfm2":  # 3 full layers of 8 KV heads x 64 stored 128 wide,
+        # groups of 4, table 256; 11 convolution layers' state by slot
+        return tfm.ModelConfig(
+            vocab_size=65536, d_model=2048, n_layers=14, n_heads=32,
+            n_kv_heads=8, d_ff=7168, max_seq_len=4096, rope_theta=1e6,
+            dtype=jnp.bfloat16, rms_eps=1e-5,
+            attn_pattern=("conv", "conv") + ("full", "conv", "conv", "conv") * 3,
+            ffn_pattern=("dense",) * 2 + ("experts",) * 12,
+            qk_norm=True, conv_kernel=3, tie_embeddings=True,
+            d_ff_expert=1792, n_routed_experts=32, experts_per_token=4,
+            router_norm_eps=1e-6,
+        ), 64, 8192
     # mimo-v2.5-l7-ep16: a full class of 4 KV heads, groups of 16, keys of
     # 192 stored 256 wide, values 128, table 512; 64 rings of 9 pages
     return tfm.ModelConfig(
@@ -138,32 +150,36 @@ class _Deployment:
         # pool's size: a pool of one slot's pages keeps the engine small.
         # The engine reads the platform to choose its decode attention, and
         # the attached backend here is the CPU
-        with pytest.MonkeyPatch.context() as m:
-            m.setattr(jax, "default_backend", lambda: "tpu")
-            self.eng = ContinuousBatchingEngine(
-                cfg, params={}, max_batch=slots, page_size=PAGE,
-                n_pages=table + 1, max_pages_per_seq=table,
-            )
-        assert self.eng._attn_kernel == "compiled"
-
         def shape(dims, dtype):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
 
         self.shape = shape
+        # shapes in place of weights: the engine checks their layout only
         self.params = jax.tree.map(
             lambda x: shape(x.shape, x.dtype),
             jax.eval_shape(lambda: tfm.init_params(cfg, jax.random.PRNGKey(0))),
         )
+        with pytest.MonkeyPatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            self.eng = ContinuousBatchingEngine(
+                cfg, params=self.params, max_batch=slots, page_size=PAGE,
+                n_pages=table + 1, max_pages_per_seq=table,
+            )
+        assert self.eng._attn_kernel == "compiled"
         self.pool_k, self.pool_v, self.tables = {}, {}, {}
         for cls, (layers, kind) in cfg.kv_classes().items():
             n = slots * self.eng.pool.ring_pages + 1 if kind.window else n_pages
             lead = (layers, kind.kv_heads, n, PAGE)
             self.pool_k[cls] = shape(lead + (self.eng.pool.k_dim,), cfg.dtype)
-            self.pool_v[cls] = shape(lead + (cfg.v_head_dim,), cfg.dtype)
+            self.pool_v[cls] = shape(lead + (self.eng.pool.v_dim,), cfg.dtype)
             self.tables[cls] = shape(
                 (slots, self.eng._table_len(cls)), jnp.int32
             )
         self.pools = [*self.pool_k.values(), *self.pool_v.values()]
+        # what the layers that are no attention keep, by slot
+        self.state = jax.tree.map(
+            lambda x: shape(x.shape, x.dtype), self.eng.pool.state
+        )
         self._decode = None
 
     def ints(self, *dims):
@@ -177,6 +193,7 @@ class _Deployment:
                 self.params, self.pool_k, self.pool_v, self.tables,
                 self.ints(*n), self.ints(*n), self.shape(n, jnp.bool_),
                 self.shape(n, jnp.float32), self.shape(n, jnp.uint32),
+                self.state,
             ).compile()
         return self._decode
 
@@ -266,7 +283,7 @@ def test_pool_writers_copy_no_pool(deployment, program):
         t_pad = 2048  # the longest prompt of the cells
         compiled = d.eng._prefill.lower(
             d.params, d.pool_k, d.pool_v, d.ints(t_pad), t_pad,
-            {"full": d.ints(t_pad // PAGE)},
+            {"full": d.ints(t_pad // PAGE)}, d.state, d.ints(), d.ints(),
         ).compile()
     assert not d.copies_of_a_pool(compiled.as_text())
     mem = compiled.memory_analysis()
@@ -297,12 +314,13 @@ def test_two_page_classes_and_held_experts_copy_no_pool(deployment, program):
         compiled = eng._prefill.lower(
             d.params, d.pool_k, d.pool_v, d.ints(2048), 2048,
             {"full": d.ints(2048 // PAGE), "window": d.ints(9)},
+            d.state, d.ints(), d.ints(),
         ).compile()
     else:
         compiled = eng._prefill_suffix.lower(
             d.params, d.pool_k, d.pool_v, d.ints(512), 512, d.ints(),
             {"full": d.ints(table), "window": d.ints(9)},
-            d.ints(512 // PAGE),
+            d.ints(512 // PAGE), d.state, d.ints(), d.ints(),
         ).compile()
     text = compiled.as_text()
     assert not d.copies_of_a_pool(text)
@@ -311,6 +329,57 @@ def test_two_page_classes_and_held_experts_copy_no_pool(deployment, program):
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools)
     # temporaries: the rings' gathered tables of 64 slots, the chunk's scores
     assert mem.temp_size_in_bytes < 2.5 * 2**30
+
+
+# -- state by slot beside the paged KV at the widths of `lfm2-8b-a1b-l14` --------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
+def test_state_by_slot_and_heads_of_64_copy_no_pool_and_no_weights(
+    deployment, program
+):
+    """``lfm2-8b-a1b-l14.turns``: 64 slots, contexts to 4,096 (a table of
+    256), 8,192 pages of 3 attention layers x 8 KV heads x 64, and 11
+    convolution layers' two columns of 2,048 a slot. Heads of 64 are stored
+    128 wide: as they are, Mosaic refuses the kernel's DMA of a page
+    ("slice shape along dimension 4 must be aligned to tiling (128), but is
+    64"; the chip lays such a row out in 128 lanes either way). Every
+    program aliases both pools and the state and copies none of them; the
+    decode step holds the paged-attention kernel and the grouped expert
+    matmul; and no run's stack of expert weights is copied: each run has a
+    stack of its own, where a static slice of its kind's stack was a copy
+    of ``[3, 32, 2048, 1792]`` a matrix a step."""
+    d = deployment("lfm2")
+    eng, table = d.eng, d.tables["full"].shape[1]
+    assert (eng.pool.k_dim, eng.pool.v_dim, table) == (128, 128, 256)
+    assert eng.max_prefill_tokens == 2896  # the cell's 2,048 in one program
+    assert d.state["conv"].shape == (11, 2, 64, 2048)
+    assert [r.count for r in d.cfg.layer_runs()] == [2, 1, 3, 1, 3, 1, 3]
+    if program == "decode_step":
+        compiled = d.decode_step()
+    elif program == "prefill":
+        compiled = eng._prefill.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(2048), 2048,
+            {"full": d.ints(2048 // PAGE)}, d.state, d.ints(), d.ints(),
+        ).compile()
+    else:  # no prompt of the cell takes it; a longer one would
+        compiled = eng._prefill_suffix.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(720), 720, d.ints(),
+            {"full": d.ints(table)}, d.ints(720 // PAGE), d.state,
+            d.ints(), d.ints(),
+        ).compile()
+    text = compiled.as_text()
+    assert not d.copies_of_a_pool(text)
+    assert not re.findall(r"= bf16\[11,2,64,2048\]\S* copy\(", text)
+    assert KERNEL in text  # lax.ragged_dot: the grouped expert matmul
+    if program == "decode_step":
+        assert "paged_attention_decode" in text
+    for dims in ("32,2048,1792", "32,1792,2048"):  # a run's or a layer's experts
+        assert not re.findall(rf"= bf16\[[13],{dims}\]\S* (copy|fusion)\(", text), dims
+    mem = compiled.memory_analysis()
+    state = 2 * d.state["conv"].size
+    assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
+    assert mem.temp_size_in_bytes < 2.0 * 2**30
 
 
 # -- scheduler kernels: the head's real round ---------------------------------

@@ -301,7 +301,8 @@ def test_a_prefix_hit_is_on_the_prefill_span():
         assert b.generate_ids([prompt], GEN) == want
         (prefill,) = engine_spans("engine.prefill")
         assert prefill["args"] | {"id": 0, "parent": 0} == {
-            "t_pad": 8, "hit_tokens": 16, "chunks": 1, "id": 0, "parent": 0}
+            "t_pad": 8, "true_len": 2, "hit_tokens": 16, "chunks": 1,
+            "id": 0, "parent": 0}
         (insert,) = engine_spans("engine.prefix_insert")
         assert insert["args"]["pages"] == 0  # the hit covered them
     finally:

@@ -50,11 +50,30 @@ PAGE = 4
 TOL = 2e-4
 
 
+def by_run(cfg, params):
+    """The family stacks the layers of a kind together, and its reference
+    reads them so; the engine takes a stack of its own for each run of
+    consecutive layers (``ModelConfig.layer_runs``). The toy has kinds
+    with several runs, which the cell's seven layers have not."""
+    blocks, taken = {}, {}
+    for run in cfg.layer_runs():
+        kind = ".".join(run.key.split(".")[:2])
+        at = taken.get(kind, 0)
+        taken[kind] = at + run.count
+        blocks[run.key] = jax.tree.map(
+            lambda a: a[at : at + run.count], params["blocks"][kind]
+        )
+    return {**params, "blocks": blocks}
+
+
 @pytest.fixture(scope="module")
 def toy():
+    """(the program's configuration, the weights as the reference reads
+    them, the reference, the weights as the engine takes them)."""
     family = spec.load_family(TOY, BENCH)
     reference = spec.load_reference(TOY, BENCH)
-    return family.model_config(TOY), family.make_weights(TOY, 5), reference
+    cfg, weights = family.model_config(TOY), family.make_weights(TOY, 5)
+    return cfg, weights, reference, by_run(cfg, weights)
 
 
 @pytest.fixture(autouse=True)
@@ -66,7 +85,7 @@ def small_prefill_programs(monkeypatch):
 
 def make_engine(toy, **kw):
     kw = {"max_batch": 3, "page_size": PAGE, "n_pages": 64, **kw}
-    eng = ContinuousBatchingEngine(toy[0], toy[1], **kw)
+    eng = ContinuousBatchingEngine(toy[0], toy[3], **kw)
     assert (eng.max_prefill_tokens, eng.prefill_chunk) == (16, 4)
     return eng
 
@@ -295,6 +314,39 @@ def test_a_dense_configuration_gives_the_parents_logits_to_the_bit(
     assert crcs == PARENT[dtype]
 
 
+# CRC32 of the float32 logits of every prefill / prefill_suffix run of this
+# file's toy (13 layers, two page classes, 8 of 32 experts held), and of
+# the served tokens, from the parent commit's engine (4ec9a34: `decoder_block`
+# before it knew a mixer that is no attention, stacks by kind sliced a run)
+PARENT_TOY = {
+    "float32": ([1882634391, 3636613899, 3156382098, 25897010, 4237674743,
+                 2937360573, 1431250081, 2034603823, 3212228887, 2463870794,
+                 124420986, 3326332443], 3355322928),
+    "bfloat16": ([2188577051, 4088118218, 1348395843, 3190153717, 4108571303,
+                  3632099174, 936470520, 2925090720, 2329348211, 2147429832,
+                  1188732779, 2390298474], 3932849118),
+}
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_windowed_expert_toy_gives_the_parents_outputs_to_the_bit(dtype):
+    """A stack by position that has no convolution layer takes none of the
+    branches PR 36 added (state by slot, QK-norm, a tied head, the router's
+    epsilon), and a stack of its own for each run holds the rows the slice
+    of a kind's stack held."""
+    cfg = dict(TOY, torch_dtype=dtype)
+    family = spec.load_family(cfg, BENCH)
+    model, weights = family.model_config(cfg), family.make_weights(cfg, 5)
+    eng = make_engine((model, weights, None, by_run(model, weights)))
+    seen = capture_prefill_logits(eng)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (37, 5, 16, 23)]
+    outs = eng.generate_ids(prompts, GenerationConfig(max_new_tokens=20))
+    crcs = [zlib.crc32(np.asarray(x, np.float32).tobytes()) for x in seen]
+    tokens = zlib.crc32(np.asarray(outs, np.int32).tobytes())
+    assert (crcs, tokens) == PARENT_TOY[dtype]
+
+
 def test_rms_eps_is_the_configurations(toy):
     """The norm's epsilon follows ``ModelConfig.rms_eps``: the default is
     the 1e-6 every dense configuration has run with."""
@@ -431,6 +483,22 @@ def test_full_layers_take_the_kernel_and_rings_their_own_path(toy, kernel):
 def test_a_path_that_lacks_the_feature_raises_a_typed_error(toy, call):
     with pytest.raises(tfm.UnsupportedModelFeature):
         call(toy)
+
+
+def test_weights_stacked_by_kind_are_refused_by_name(toy):
+    """The program reads one stack of weights a run. The family's own
+    layout (one a kind, which is the cell's too while each of its kinds
+    has one run) is refused by name where a kind has several runs, as the
+    engine is built and as ``run_stack`` is traced: no ``KeyError``, no
+    scan over the wrong layers."""
+    cfg, by_kind = toy[0], toy[1]
+    assert len(cfg.layer_runs()) > len(by_kind["blocks"])
+    with pytest.raises(tfm.StackLayoutError, match="one stack of weights"):
+        ContinuousBatchingEngine(
+            cfg, by_kind, max_batch=1, page_size=PAGE, n_pages=16)
+    with pytest.raises(tfm.StackLayoutError):
+        tfm.run_stack(cfg, by_kind["blocks"], None, None, None, None)
+    cfg.require_blocks_by_run(toy[3]["blocks"])
 
 
 def test_params_sig_tells_two_sets_of_weights_apart():
