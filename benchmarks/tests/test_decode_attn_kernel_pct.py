@@ -36,7 +36,8 @@ def test_reader_and_aliases_are_found_by_name():
     with open(os.path.join(spec.REPO_ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     entries = {m["name"]: m for m in bench["per_layer"] if m["name"].startswith(NAME)}
-    assert sorted(entries) == [NAME, NAME + ".moe", NAME + ".sat"]
+    assert sorted(entries) == [
+        NAME, NAME + ".moe", NAME + ".sat", NAME + ".state"]
     cells = {c["name"] for c in bench["workloads"]}
     listed = [c for m in entries.values() for c in m["workloads"]]
     assert sorted(listed) == sorted(cells)  # every cell, once

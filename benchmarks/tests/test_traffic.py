@@ -127,6 +127,18 @@ def replay_slots(schedule, slots, step_s, seconds, prefill_s_per_ktok=0.05):
     return {"tokens": tokens, "queued": len(queue), "in_flight": in_flight}
 
 
+def rank_orders(m, slots, step_s, prefill_s_per_ktok=0.05):
+    """The orders 0..39 of a mix by their tokens inside the window in a
+    replay, fewest first, and the counts: how an ``order_seed`` is chosen."""
+    def tokens(order):
+        s = traffic.schedule(
+            dict(m, arrivals=dict(m["arrivals"], order_seed=order)), 50)
+        return replay_slots(s, slots, step_s, 50, prefill_s_per_ktok)["tokens"]
+
+    counts = {o: tokens(o) for o in range(40)}
+    return sorted(counts, key=lambda o: (counts[o], o)), counts
+
+
 # the parent's measured step interval, then what a v5e allows this model
 # soon: PR 30's decode kernel read 11.3 ms (ledger, PR 30)
 REASONING_STEP_S = (0.0366, 0.030, 0.025, 0.020, 0.015, 0.0113, 0.011)
@@ -165,16 +177,86 @@ def test_reasoning_order_seed_is_the_median_order_at_a_full_batch():
     the forty orders lie within a hundredth of each other: what the order
     moved in the spent schedule (a tenth) is gone."""
     m = mix("reasoning")
-
-    def tokens(order):
-        s = traffic.schedule(
-            dict(m, arrivals=dict(m["arrivals"], order_seed=order)), 50)
-        return replay_slots(s, 32, REASONING_STEP_S[0], 50)["tokens"]
-
-    counts = {o: tokens(o) for o in range(40)}
-    ranked = sorted(counts, key=lambda o: (counts[o], o))
+    ranked, counts = rank_orders(m, 32, REASONING_STEP_S[0])
     assert ranked.index(m["arrivals"]["order_seed"]) in (19, 20, 21, 22)
     assert counts[ranked[-1]] < 1.01 * counts[ranked[0]]
+
+
+# measured (my chip runs, PR 38, the traced runs of the reloaded cell): a
+# decode step's interval is 25.9-26.3 ms on the device and 6.8-7.2 on the
+# host. What a prefill costs the slots, in seconds per 1,000 prompt tokens,
+# is the cost at which the replay through the cell's 64 slots reads the
+# measured 907 tokens/s (908 here; 133 queued and 197 in flight at the close
+# against 144 and 175-186): the slots that the hand-over leaves empty (batch
+# 47 of 64) are in it; through 47 slots the same fit gives 52. Then PR 37's
+# step (ledger, PR 37: 13.6 ms on the device and 4.1 on the host)
+MIXED_STEP_S, MIXED_PREFILL_S_PER_KTOK = 0.033, 0.085
+MIXED_STEP_S_PR37 = 0.0177
+
+
+def test_mixed_is_the_issues_mix():
+    """``mixed`` since PR 38: the lengths it had (twelve padded prompt
+    lengths, 256-7,680 log-uniform; answers 128-512), 64 at once when the
+    ramp-in starts and then 4.5 arrivals a second to the close."""
+    cell = spec.load_cell("mimo-v2.5-l7-ep16.mixed")
+    a = cell.mix["arrivals"]
+    s = traffic.schedule(cell.mix, 50)
+    assert (len(s), sum(r.max_new for r in s)) == (334, 92_515)
+    assert (len(s) - a["initial_burst"]) / (a["ramp_in_s"] + 50) == pytest.approx(4.5)
+    assert sum(1 for r in s if r.due < -a["ramp_in_s"] + 0.064) == 64
+    p, o = [r.prompt_len for r in s], [r.max_new for r in s]
+    assert min(p) >= 256 - 16 and max(p) <= 7680
+    assert len({-(-x // 16) for x in p}) <= 12
+    assert np.mean(p) == pytest.approx(2164, rel=0.01)
+    assert stats.percentile(p, 50) == pytest.approx(1216, rel=0.02)
+    assert min(o) >= 128 and max(o) <= 512
+    assert max(a + b for a, b in zip(p, o)) <= (
+        cell.cfg["deployment"]["max_context_tokens"])
+
+
+def test_mixed_schedule_outlasts_the_engine():
+    """What the mix's ``what`` says. Through the cell's 64 slots, from the
+    measured step interval down to PR 37's 17.7 ms at the measured cost of
+    a prefill, the tokens inside the window never fall and requests are
+    queued at the close at every one; at the slowest the requests in flight
+    stay 20 under the router's ``serve_admission_max_inflight``, through
+    the 46 slots the engine fills too. The schedule it replaced (192
+    requests) has none queued at either speed and reads fewer tokens at the
+    faster one once prefill costs 50 ms per 1,000 tokens, which is what
+    refused PR 37; the new one is spent only there, and still reads half
+    as much again as at today's speed."""
+    from ray_tpu.config import cfg
+
+    m = mix("mixed")
+    s = traffic.schedule(m, 50)
+    steps = (MIXED_STEP_S, 0.028, 0.024, 0.020, MIXED_STEP_S_PR37)
+    runs = [replay_slots(s, 64, x, 50, MIXED_PREFILL_S_PER_KTOK) for x in steps]
+    counts = [r["tokens"] for r in runs]
+    assert counts == sorted(counts) and counts[-1] > 1.2 * counts[0]
+    assert all(r["queued"] > 0.2 * len(s) for r in runs)
+    filled = replay_slots(s, 46, MIXED_STEP_S, 50, MIXED_PREFILL_S_PER_KTOK)
+    for r in (runs[0], filled):
+        assert r["in_flight"] <= cfg.serve_admission_max_inflight - 20
+    spent = replay_slots(s, 64, MIXED_STEP_S_PR37, 50, 0.050)
+    assert spent["queued"] == 0 and spent["tokens"] > 1.5 * counts[0]
+    old = traffic.schedule(
+        dict(m, arrivals=dict(m["arrivals"], rate_per_s=3.2, order_seed=37)), 50)
+    assert len(old) == 192
+    was = [replay_slots(old, 64, MIXED_STEP_S, 50, MIXED_PREFILL_S_PER_KTOK),
+           replay_slots(old, 64, MIXED_STEP_S_PR37, 50, 0.050)]
+    assert was[0]["queued"] == was[1]["queued"] == 0
+    assert was[1]["tokens"] < 0.9 * was[0]["tokens"]
+
+
+def test_mixed_order_seed_is_the_median_order_at_the_measured_interval():
+    """``mixed``'s ``order_seed`` by the same rule, replayed through its
+    64 slots at the measured step interval and cost of a prefill. With
+    prompts of 256-7,680 in one queue the order moves the count by a
+    tenth: which long prompts hold the slots up inside the window."""
+    m = mix("mixed")
+    ranked, counts = rank_orders(m, 64, MIXED_STEP_S, MIXED_PREFILL_S_PER_KTOK)
+    assert ranked.index(m["arrivals"]["order_seed"]) in (19, 20, 21, 22)
+    assert counts[ranked[-1]] > 1.08 * counts[ranked[0]]
 
 
 def test_schedule_unspent_reader_on_a_toy_run():
