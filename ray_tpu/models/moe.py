@@ -79,6 +79,11 @@ def moe_apply(p, x: jax.Array, capacity_factor: float = 1.25) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+# what an expert layer holds for each held expert; `router` and
+# `router_bias` are the layer's own
+EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def init_experts(n_routed: int, held: int, d_model: int, d_ff: int,
                  n_layers: int, key, dtype):
     """Weights of ``n_layers`` expert layers that hold ``held`` of
@@ -120,7 +125,7 @@ def route(p, x: jax.Array, top_k: int, norm_eps: float = 0.0,
 
 
 def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
-                  norm_eps: float = 0.0, scale: float = 1.0):
+                  norm_eps: float = 0.0, scale: float = 1.0, layer=None):
     """This holder's part of an expert layer: ``sum of w_e * SwiGLU_e(x)``
     over the experts a token chose that are held here, experts
     ``held[0] .. held[0] + held[1] - 1`` of the router's width. No token
@@ -130,16 +135,25 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
 
     x: [N, D]; ``live``: bool[N], tokens that count (a decode step's
     inactive slots choose nothing); ``norm_eps`` and ``scale`` are the
-    router's (``route``). Token-expert pairs are sorted by held
+    router's (``route``). ``p``: the layer's ``router`` and
+    ``router_bias``, and ``EXPERT_WEIGHTS`` either the layer's own
+    ``[held, D, F]`` or, with ``layer`` (an int32 scalar, traced under a
+    scan), the ``[L, held, D, F]`` stacks of a run of ``L`` layers of
+    which this is the ``layer``-th. Token-expert pairs are sorted by held
     expert and go through one grouped matmul (``lax.ragged_dot``: on the
     TPU a kernel that visits the rows of each group with that group's
-    weights, so the kernel itself skips an expert no token chose). Under
-    ``lax.scan`` over stacked layers the layer's whole ``[held, D, F]``
-    slice is first copied out of the stack, chosen or not: the traced
-    decode step of the 16-expert cell spends 12.3 ms of 54 on that copy
-    (PERF.md section 5, Open question 13). The rows are a static budget:
-    twice what a uniform router sends here, and all ``N * top_k`` pairs
-    in the branch taken when more than that arrive.
+    weights, so the kernel itself skips an expert no token chose). The
+    grouped matmul reads a run's stack where it lies: the stack is viewed
+    as ``L * held`` groups (a reshape of leading dims, no copy) and the
+    group sizes are zero outside the layer's ``held``, so the layers that
+    are not this one are so many experts no token chose. Handed the
+    layer's slice of the stack instead, as a ``lax.scan`` over the stack
+    hands it, the chip's compiler wrote the slice out as a buffer of its
+    own before the kernel read it, every held expert chosen or not: a
+    third of both sparse cells' decode step (PERF.md section 6, PR 39).
+    The rows are a static budget: twice what a uniform router sends here,
+    and all ``N * top_k`` pairs in the branch taken when more than that
+    arrive.
 
     Returns (out [N, D], pairs held here, held experts hit), the last two
     int32 counts over live tokens."""
@@ -158,13 +172,21 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
     n_pairs = jnp.sum(sizes)
     token = (jnp.arange(n * top_k, dtype=jnp.int32) // top_k)[order]
     weight = jnp.where(here, weights, 0.0).reshape(-1)[order]
+    stacks = [p[name] for name in EXPERT_WEIGHTS]
+    if layer is None:  # the layer's own weights: a stack of one
+        stacks, layer = [w[None] for w in stacks], 0
+    groups = stacks[0].shape[0] * count
+    w_gate, w_up, w_down = (w.reshape(groups, *w.shape[2:]) for w in stacks)
+    in_stack = jax.lax.dynamic_update_slice(
+        jnp.zeros((groups,), jnp.int32), sizes, (layer * count,)
+    )
 
     def run(rows: int):
         tok, w = token[:rows], weight[:rows]
         xs = x[tok]
-        gate = jax.lax.ragged_dot(xs, p["w_gate"], sizes)
-        up = jax.lax.ragged_dot(xs, p["w_up"], sizes)
-        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["w_down"], sizes)
+        gate = jax.lax.ragged_dot(xs, w_gate, in_stack)
+        up = jax.lax.ragged_dot(xs, w_up, in_stack)
+        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, in_stack)
         # rows past the last group belong to no expert: whatever the
         # kernel left there is not read
         y = jnp.where(

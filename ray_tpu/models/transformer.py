@@ -210,7 +210,9 @@ class ModelConfig:
         forward>`` names a kind's first run, ``<...>.<n>`` its n-th later
         one. (A run that was a slice of its kind's stack had the slice
         copied out every step by the chip's compiler: a ``lax.scan`` reads
-        a whole buffer.)"""
+        a whole buffer. What the scan hands its body is a slice too, and
+        an expert run's three large stacks were copied layer by layer for
+        the grouped matmul: ``run_stack`` keeps those out of the scan.)"""
         runs, last_kind, of_kind, in_class = [], None, {}, {}
         for kind in self.layer_kinds():
             attn, ffn = kind
@@ -657,7 +659,7 @@ def gated_conv(cfg: ModelConfig, p, x, shift):
 
 
 def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
-                  live=None):
+                  live=None, layer=None):
     """One layer of the kind ``run`` names: norm, the token mixer through
     the caller's cache, feed-forward. h: [..., D]; ``ang``: the rotary
     angles [..., rotary_dim / 2] of h's tokens at the kind's rope base.
@@ -668,7 +670,9 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
     with ``mix`` as its ``shift``. Returns (h, that cache, int32[2]:
     token-expert pairs this holder computed and held experts hit; zeros in
     a dense layer). ``live``: bool over the leading dims, tokens whose
-    choice of expert counts."""
+    choice of expert counts. ``layer``: where ``p["moe"]`` holds the
+    experts of a whole run of layers, this layer's place among them
+    (``moe.experts_apply``)."""
     lead, kind = h.shape[:-1], run.attn
     x = rms_norm(h, p["ln1"], cfg.rms_eps)
     if kind.name == "conv":
@@ -692,6 +696,7 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
             top_k=cfg.experts_per_token, held=cfg.experts_held,
             live=None if live is None else live.reshape(-1),
             norm_eps=cfg.router_norm_eps, scale=cfg.routed_scaling,
+            layer=layer,
         )
         return h + y.reshape(h.shape), cache, jnp.stack([pairs, hit])
     y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
@@ -701,23 +706,33 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
 def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
               live=None, shift=None):
     """Every layer in the pattern's order, each run of one kind a
-    ``lax.scan`` over the run's stacked weights. ``positions``: int32
-    of h's leading dims. ``attend(kind, layer, q, k, v, sink, cache) ->
-    (attention, cache)`` and, for a convolution layer, ``shift(layer, s,
-    cache) -> (the columns before each token, cache)``: ``layer`` counts
-    within what the kind keeps for a sequence (its class of KV page, the
-    convolutions' state). Returns (h, cache, the blocks' int32[2] counts
-    summed)."""
+    ``lax.scan`` over the run's stacked weights: all of them but an
+    expert run's ``moe.EXPERT_WEIGHTS``, which the scan's body closes over
+    whole and ``experts_apply`` reads in place by the layer's index in the
+    run (as the scan's ``xs`` a layer's ``[held, D, F]`` was copied out of
+    the stack every step, before the grouped matmul read it).
+    ``positions``: int32 of h's leading dims. ``attend(kind, layer, q, k,
+    v, sink, cache) -> (attention, cache)`` and, for a convolution layer,
+    ``shift(layer, s, cache) -> (the columns before each token, cache)``:
+    ``layer`` counts within what the kind keeps for a sequence (its class
+    of KV page, the convolutions' state). Returns (h, cache, the blocks'
+    int32[2] counts summed)."""
     cfg.require_blocks_by_run(blocks)
     counts = jnp.zeros((2,), jnp.int32)
     for run in cfg.layer_runs():
         stack = blocks if run.key is None else blocks[run.key]
+        experts = {}
+        if run.experts:
+            experts = {k: stack["moe"][k] for k in moe_mod.EXPERT_WEIGHTS}
+            stack = {**stack, "moe": {
+                k: v for k, v in stack["moe"].items() if k not in experts
+            }}
         conv = run.attn.name == "conv"
         ang = None if conv else rope_freqs(
             cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
         )[positions]
 
-        def body(carry, p, run=run, ang=ang, conv=conv):
+        def body(carry, p, run=run, ang=ang, conv=conv, experts=experts):
             h, cache, layer, counts = carry
             if conv:
                 def mix(s):
@@ -725,7 +740,12 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
             else:
                 def mix(q, k, v, sink):
                     return attend(run.attn, layer, q, k, v, sink, cache)
-            h, cache, c = decoder_block(cfg, run, p, h, ang, mix, live)
+            in_run = None
+            if run.experts:
+                # `layer` began at the run's first layer in its class
+                p = {**p, "moe": {**p["moe"], **experts}}
+                in_run = layer - run.cache_start
+            h, cache, c = decoder_block(cfg, run, p, h, ang, mix, live, in_run)
             return (h, cache, layer + 1, counts + c), None
 
         (h, cache, _, counts), _ = jax.lax.scan(
