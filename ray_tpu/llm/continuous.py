@@ -21,10 +21,13 @@ rebuilt TPU-first in the JetStream/PagedAttention mold:
   positions out of the pool where it lies; where there is no TPU, and for
   a windowed layer's ring, attention gathers each slot's table into a
   contiguous view (gathers + one big einsum, no dynamic shapes).
-- **State by slot**: a layer that is no attention (a gated short
-  convolution) keeps no K and V but a few columns of its own input for
-  each sequence: fixed size, indexed by slot, beside the pools
-  (``PagedKVPool.state``), with no pages to reserve or to wait for.
+- **State by slot**: a layer that is no attention keeps no K and V but a
+  state of fixed size for each sequence, indexed by slot, beside the pools
+  (``PagedKVPool.state``), with no pages to reserve or to wait for: a few
+  columns of its own input (a gated short convolution), or such columns
+  and a float32 matrix a head (the gated delta rule, a linear attention:
+  one token a step in ``decode_step``, a scan over blocks of tokens in the
+  two prefill programs, ``models/transformer.py`` ``delta_scan``).
 
 Reference files for parity intent: vllm paged attention + continuous
 batching scheduler; JetStream's slot/page design is the public TPU
@@ -144,6 +147,12 @@ LANES = 128  # a TPU vector register's lanes: the tile of an array's last dim
 PREFILL_SCORES_BYTES = 2**30
 
 
+# the slot's axis in each array of ``PagedKVPool.state``, and the array
+# that holds a kind's columns before its short convolution
+SLOT_AXIS = {"conv": 2, "delta_taps": 2, "delta_s": 1}
+TAPS_OF = {"conv": "conv", "delta": "delta_taps"}
+
+
 def stored_width(size: int, kernel: bool = False) -> int:
     """Width a key or a value is stored at: a head wider than one tile of
     ``LANES`` takes whole tiles (192 -> 256, zeros behind the head's own
@@ -184,9 +193,14 @@ class PagedKVPool:
     ``state`` holds what the layers that are no attention keep for a
     sequence, by slot and not by page: ``state["conv"]``
     ``[convolution layers, conv_kernel - 1, max_batch, d_model]``, the last
-    columns of each layer's gated input, the oldest first. It has no free
-    list: a slot's columns are its occupant's from the prefill that wrote
-    them, nothing is reserved and nothing can run short."""
+    columns of each layer's gated input, the oldest first;
+    ``state["delta_taps"]`` ``[delta layers, conv_kernel - 1, max_batch,
+    delta_width]``, the last columns of q, k and v before their short
+    convolution, and ``state["delta_s"]`` ``[delta layers, max_batch,
+    heads, key size, value size]`` in float32, the delta rule's matrix a
+    head (``SLOT_AXIS``: which axis of each is the slot's). It has no free
+    list: a slot's state is its occupant's from the prefill that wrote it,
+    nothing is reserved and nothing can run short."""
 
     def __init__(self, cfg: tfm.ModelConfig, n_pages: int, page: int,
                  max_batch: int = 0, kernel: bool = False):
@@ -207,11 +221,24 @@ class PagedKVPool:
             self.k[name] = jnp.zeros(shape + (self.k_dim,), cfg.dtype)
             self.v[name] = jnp.zeros(shape + (self.v_dim,), cfg.dtype)
         self.state: Dict[str, jax.Array] = {}
-        if cfg.state_layers:
+        kinds, taps = cfg.state_kinds(), cfg.conv_kernel - 1
+        if "conv" in kinds:
             self.state["conv"] = jnp.zeros(
-                (cfg.state_layers, cfg.conv_kernel - 1, max_batch, cfg.d_model),
-                cfg.dtype,
+                (kinds["conv"], taps, max_batch, cfg.d_model), cfg.dtype
             )
+        if "delta" in kinds:
+            self.state["delta_taps"] = jnp.zeros(
+                (kinds["delta"], taps, max_batch, cfg.delta_width), cfg.dtype
+            )
+            self.state["delta_s"] = jnp.zeros(
+                (kinds["delta"], max_batch, cfg.delta_heads,
+                 cfg.delta_key_dim, cfg.delta_value_dim), jnp.float32,
+            )
+        # bytes of state by slot one sequence holds, as declared
+        self.state_bytes_per_slot = sum(
+            a.nbytes // a.shape[SLOT_AXIS[name]]
+            for name, a in self.state.items()
+        )
 
     @property
     def n_pages(self) -> int:
@@ -382,7 +409,8 @@ class ContinuousBatchingEngine:
                 "the shared prefix cache holds pages of the `full` class "
                 "alone; a model with a window class of KV page "
                 "(`attn_pattern` \"window\") or with state by slot "
-                "(`attn_pattern` \"conv\") is served with prefix_cache=False"
+                "(`attn_pattern` \"conv\", \"delta\") is served with "
+                "prefix_cache=False"
             )
         configure_compile_cache()
         self.cfg = cfg
@@ -478,9 +506,10 @@ class ContinuousBatchingEngine:
     def _refuse_windowed(self, what: str) -> None:
         """For the paths that move a sequence as pages of the ``full``
         class: what else a sequence holds would be left behind."""
+        kinds = ", ".join(f'"{k}"' for k in self.cfg.state_kinds())
         for has, field in ((self.windowed, "a window class of KV page"),
                            (self.stateful, "state by slot (`attn_pattern` "
-                                           "\"conv\")")):
+                                           f"{kinds})")):
             if has:
                 raise tfm.UnsupportedModelFeature(
                     f"{what} moves pages of the `full` class alone and is "
@@ -497,7 +526,7 @@ class ContinuousBatchingEngine:
         S_max = P_max * page
         ring = self.pool.ring_pages * page  # tokens a slot's ring holds
         k_dim, v_dim = self.pool.k_dim, self.pool.v_dim
-        taps = cfg.conv_kernel - 1  # columns a convolution layer keeps
+        taps = cfg.conv_kernel - 1  # columns a short convolution keeps
 
         def stored(x, width=k_dim):
             """Keys (or values, ``width=v_dim``) at the width the pool
@@ -509,38 +538,62 @@ class ContinuousBatchingEngine:
             return jnp.pad(x, pad)
 
         # The two prefill programs carry through the stack the rows of ONE
-        # slot, ``[convolution layers, taps, D]``, and put them into the
+        # slot (``[convolution layers, taps, D]``; a delta layer's ``[layers,
+        # taps, width]`` and ``[layers, H, dk, dv]``), and put them into the
         # state once, after the last layer: with the whole state in the
         # layers' scan the chip's compiler gave it another layout inside
         # the program and copied it in and out.
         def slot_rows(state, slot):
-            """``[convolution layers, taps, D]``; None of a model without."""
-            if not self.stateful:
-                return None
-            return jax.lax.dynamic_slice_in_dim(state["conv"], slot, 1, 2)[:, :, 0]
+            """Each array of the state without its slot axis, at ``slot``."""
+            return {
+                name: jnp.squeeze(jax.lax.dynamic_slice_in_dim(
+                    a, slot, 1, SLOT_AXIS[name]), SLOT_AXIS[name])
+                for name, a in state.items()
+            }
 
         def put_slot_rows(state, slot, rows):
-            if rows is None:
-                return state
-            return {"conv": jax.lax.dynamic_update_slice_in_dim(
-                state["conv"], rows[:, :, None], slot, 2
-            )}
+            return {
+                name: jax.lax.dynamic_update_slice_in_dim(
+                    a, jnp.expand_dims(rows[name], SLOT_AXIS[name]), slot,
+                    SLOT_AXIS[name],
+                )
+                for name, a in state.items()
+            }
 
-        def shift_sequence(layer, s, cache, true_len):
+        def shift_sequence(kind, layer, s, cache, true_len):
             """``run_stack``'s ``shift`` for ONE sequence's block of
-            tokens. s: [1, T, D]; ``cache["rows"][layer]``: [taps, D], the
-            columns before the block's first token. Leaves there the
-            ``taps`` columns before position ``true_len`` of the block (of
-            a padded block's real tokens, the last): what the padding
+            tokens. s: [1, T, D]; ``cache["rows"][...][layer]``: [taps,
+            D], the columns before the block's first token. Leaves there
+            the ``taps`` columns before position ``true_len`` of the block
+            (of a padded block's real tokens, the last): what the padding
             holds is never state."""
-            rows, t = cache["rows"], s.shape[1]
+            name, t = TAPS_OF[kind.name], s.shape[1]
+            rows = cache["rows"][name]
             ext = jnp.concatenate([rows[layer].astype(s.dtype), s[0]], 0)
             earlier = tuple(ext[j : j + t][None] for j in range(taps))
             end = jax.lax.dynamic_slice_in_dim(ext, true_len, taps, 0)
             rows = jax.lax.dynamic_update_index_in_dim(
                 rows, end.astype(rows.dtype), layer, 0
             )
-            return earlier, {**cache, "rows": rows}
+            return earlier, {**cache, "rows": {**cache["rows"], name: rows}}
+
+        def scan_sequence(layer, q, k, v, g, beta, cache, true_len):
+            """``run_stack``'s ``recur`` for ONE sequence's block of
+            tokens: the delta rule as a scan over blocks of tokens
+            (``tfm.delta_scan``), from ``cache["rows"]["delta_s"][layer]``
+            [H, dk, dv], the state before the block's first token, to the
+            state after its first ``true_len``, which is left there: at
+            and after ``true_len`` the gate is 1 and beta 0, so the
+            padding changes nothing. q, k, v: [1, T, H, size]; g, beta:
+            [1, T, H]."""
+            rows = cache["rows"]["delta_s"]
+            real = (jnp.arange(g.shape[1]) < true_len)[:, None]
+            o, end = tfm.delta_scan(
+                rows[layer], q[0], k[0], v[0], jnp.where(real, g[0], 0.0),
+                jnp.where(real, beta[0], 0.0),
+            )
+            rows = jax.lax.dynamic_update_index_in_dim(rows, end, layer, 0)
+            return o[None], {**cache, "rows": {**cache["rows"], "delta_s": rows}}
 
         def write_token(pool, layer, page_ids, offsets, active, x):
             """One token a slot at (page, offset), head-major. x: [B, KH,
@@ -690,11 +743,12 @@ class ContinuousBatchingEngine:
                     "v": {**pool_v, name: pv}, "walked": walked,
                 }
 
-            def shift(layer, s, cache):
+            def shift(kind, layer, s, cache):
                 """Every slot's columns before its token, and the slot's
                 state moved on by one: an inactive slot keeps what its row
                 held, which no live slot reads."""
-                conv = cache["state"]["conv"]
+                name = TAPS_OF[kind.name]
+                conv = cache["state"][name]
                 held = conv[layer]  # [taps, B, D]
                 moved = jnp.concatenate(
                     [held[1:], s[None].astype(held.dtype)], 0
@@ -703,8 +757,20 @@ class ContinuousBatchingEngine:
                 wrote = jnp.stack([1, live_slots]).astype(jnp.int32)
                 conv = jax.lax.dynamic_update_index_in_dim(conv, moved, layer, 0)
                 return tuple(held), {
-                    **cache, "state": {"conv": conv},
+                    **cache, "state": {**cache["state"], name: conv},
                     "stated": cache["stated"] + wrote,
+                }
+
+            def recur(layer, q, k, v, g, beta, cache):
+                """Every slot's ``S`` moved on by its token
+                (``tfm.delta_step``); an inactive slot keeps its own."""
+                every = cache["state"]["delta_s"]
+                held = every[layer]  # [B, H, dk, dv]
+                o, moved = tfm.delta_step(held, q, k, v, g, beta)
+                moved = jnp.where(active[:, None, None, None], moved, held)
+                every = jax.lax.dynamic_update_index_in_dim(every, moved, layer, 0)
+                return o, {
+                    **cache, "state": {**cache["state"], "delta_s": every},
                 }
 
             h, cache, moe = tfm.run_stack(
@@ -712,7 +778,7 @@ class ContinuousBatchingEngine:
                 {"k": pool_k, "v": pool_v, "state": state,
                  "walked": jnp.zeros((4,), jnp.int32),
                  "stated": jnp.zeros((2,), jnp.int32)},
-                attend, live=active, shift=shift,
+                attend, live=active, shift=shift, recur=recur,
             )
             counts = [moe, cache["walked"]]
             if self.stateful:
@@ -761,8 +827,11 @@ class ContinuousBatchingEngine:
             pos = jnp.arange(t_pad)
             h = params["embed"][tokens][None].astype(cfg.dtype)  # [1,T,D]
 
-            def shift(layer, s, cache):
-                return shift_sequence(layer, s, cache, true_len)
+            def shift(kind, layer, s, cache):
+                return shift_sequence(kind, layer, s, cache, true_len)
+
+            def recur(layer, q, k, v, g, beta, cache):
+                return scan_sequence(layer, q, k, v, g, beta, cache, true_len)
 
             def attend(kind, layer, q, k, v, sink, cache):
                 pool_k, pool_v = cache["k"], cache["v"]
@@ -818,7 +887,7 @@ class ContinuousBatchingEngine:
             h, cache, moe = tfm.run_stack(
                 cfg, params["blocks"], h, pos[None],
                 {"k": pool_k, "v": pool_v, "rows": rows}, attend,
-                shift=shift,
+                shift=shift, recur=recur,
             )
             cache["state"] = put_slot_rows(state, slot, cache["rows"])
             return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
@@ -849,10 +918,10 @@ class ContinuousBatchingEngine:
             serves every split within a suffix-length bucket). A windowed
             layer reads the window before the suffix out of the slot's
             ring, then writes the suffix's last pages over it. A
-            convolution layer takes row ``slot`` of its state, which the
-            sequence's earlier run left there, as the columns before the
-            suffix, and leaves there the state of the suffix's first
-            ``true_len`` tokens. tokens:
+            convolution or delta layer takes row ``slot`` of its state,
+            which the sequence's earlier run left there, as the state
+            before the suffix, and leaves there the state of the suffix's
+            first ``true_len`` tokens. tokens:
             int32[t_pad] padded suffix; table by class: ``full``
             int32[P_max], ``window`` the ring; suffix_page_ids:
             int32[t_pad // page] of the ``full`` class. Returns (logits
@@ -860,8 +929,11 @@ class ContinuousBatchingEngine:
             pos = hist_len + jnp.arange(t_pad)  # absolute positions
             h = params["embed"][tokens][None].astype(cfg.dtype)
 
-            def shift(layer, s, cache):
-                return shift_sequence(layer, s, cache, true_len)
+            def shift(kind, layer, s, cache):
+                return shift_sequence(kind, layer, s, cache, true_len)
+
+            def recur(layer, q, k, v, g, beta, cache):
+                return scan_sequence(layer, q, k, v, g, beta, cache, true_len)
 
             def attend(kind, layer, q, k, v, sink, cache):
                 pool_k, pool_v = cache["k"], cache["v"]
@@ -928,7 +1000,7 @@ class ContinuousBatchingEngine:
             h, cache, moe = tfm.run_stack(
                 cfg, params["blocks"], h, pos[None],
                 {"k": pool_k, "v": pool_v, "rows": rows}, attend,
-                shift=shift,
+                shift=shift, recur=recur,
             )
             cache["state"] = put_slot_rows(state, slot, cache["rows"])
             return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
@@ -1177,12 +1249,19 @@ class ContinuousBatchingEngine:
         }
         with tracing.span(
             "engine.prefill", "engine", t_pad=t_pad, true_len=t,
-            hit_tokens=0, chunks=1 + chunks,
+            hit_tokens=0, chunks=1 + chunks, head=head,
         ) as sp:
             if self.stateful:
-                # rows of state written: one a convolution layer a run of
-                # a program, the last run's at the prompt's true end
+                # rows of state written: one a layer that keeps state a run
+                # of a program, the last run's at the prompt's true end
                 sp.set(state_written=self.cfg.state_layers * (1 + chunks))
+            delta_layers = self.cfg.state_kinds().get("delta", 0)
+            if delta_layers:
+                # blocks of the delta rule's chunked scan, a layer a run
+                block = tfm.DELTA_BLOCK
+                sp.set(scan_blocks=delta_layers * (
+                    -(-head // block) + chunks * -(-chunk // block)
+                ))
             slot = np.int32(slot)
             logits, moe = self._write_pool(
                 lambda k, v, state: self._prefill(
@@ -1192,6 +1271,9 @@ class ContinuousBatchingEngine:
             )
             pairs = [moe]
             if chunks:
+                # the last chunk's logits are the ones read: let the first
+                # program's [head, vocabulary] go before the chunks run
+                del logits
                 dev_tables = {
                     n: jnp.asarray(row) for n, row in tables.items()
                 }
@@ -1566,6 +1648,11 @@ class ContinuousBatchingEngine:
                     )
                     if self.windowed or self.stateful:
                         decode.set(full_pages=sum(written))
+                    if self.stateful:
+                        # each live slot's state read once and written once
+                        decode.set(state_bytes=(
+                            2 * len(live) * self.pool.state_bytes_per_slot
+                        ))
                     if self.windowed:
                         decode.set(
                             window_pages=sum(

@@ -58,16 +58,23 @@ class StackLayoutError(ValueError):
 class AttnKind:
     """What one kind of token mixer fixes. ``name`` is also the name of
     what its layers keep for a sequence (``PagedKVPool``): a class of KV
-    page for the two kinds of attention, and for ``conv`` (a gated short
-    convolution, no K and V) a few columns of state by slot."""
-    name: str            # "full" | "window" | "conv"
+    page for the two kinds of attention; for ``conv`` (a gated short
+    convolution, no K and V) a few columns of state by slot; for ``delta``
+    (the gated delta rule, a linear attention) such columns and a matrix of
+    state a head."""
+    name: str            # "full" | "window" | "conv" | "delta"
     kv_heads: int
-    rope_theta: float
+    rope_theta: float    # 0 = the layer's q and k are not rotated
     window: int          # 0 = every earlier key
     sink: bool           # a learned per-head column that takes mass only
 
 
 CONV = AttnKind("conv", 0, 0.0, 0, False)
+DELTA = AttnKind("delta", 0, 0.0, 0, False)
+# the kinds that are no attention: state by slot, no K and V, no rotary
+STATE_KINDS = {"conv": CONV, "delta": DELTA}
+# tokens a block of the delta rule's chunked scan holds (``delta_scan``)
+DELTA_BLOCK = 64
 
 
 @dataclass(frozen=True)
@@ -100,7 +107,7 @@ class ModelConfig:
     n_kv_heads: int = 4
     d_ff: int = 1408            # width of a dense feed-forward layer
     max_seq_len: int = 2048
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0  # 0 = no rotary: q and k carry no position
     n_experts: int = 0          # 0 = dense MLP; >0 = Switch-MoE every layer
     expert_capacity_factor: float = 1.25
     dtype: Any = jnp.bfloat16
@@ -120,15 +127,22 @@ class ModelConfig:
     value_scale: float = 1.0    # values are scaled before attention
     # -- the stack by position. Empty patterns: every layer full attention
     # and a dense feed-forward, one uniform stack (the defaults above) ----
-    attn_pattern: Tuple[str, ...] = ()    # "full" | "window" | "conv", one a layer
+    attn_pattern: Tuple[str, ...] = ()    # "full" | "window" | "conv" | "delta", one a layer
     ffn_pattern: Tuple[str, ...] = ()     # "dense" | "experts", one a layer
     window: int = 0                       # keys a windowed query sees, itself among them
     window_kv_heads: int = 0              # 0 = n_kv_heads
     window_rope_theta: float = 0.0        # 0 = rope_theta
     window_sink: bool = False
     qk_norm: bool = False                 # RMS norm over each head of q and k, before the rotary
-    conv_kernel: int = 0                  # taps of a "conv" layer's causal depthwise convolution
+    qk_norm_whole: bool = False           # ... over the whole projection instead, all heads as one row
+    post_norm: bool = False               # x + norm(op(x)): ln1 and ln2 after operator and feed-forward, none before
+    conv_kernel: int = 0                  # taps of a "conv" or "delta" layer's causal depthwise convolution
     tie_embeddings: bool = False          # the head is the embedding, transposed
+    # -- "delta" layers (``gated_delta``): heads, and a head's key and value size
+    delta_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_neg_eigval: bool = False        # beta in (0, 2): the transition's eigenvalue 1 - beta in (-1, 1)
     # -- expert layers (models/moe.py experts_apply): dropless top-k over
     # the published router width, this holder's experts computed ----------
     d_ff_expert: int = 0
@@ -151,7 +165,7 @@ class ModelConfig:
             derived["experts_held"] = (0, self.n_routed_experts)
         for name, value in derived.items():
             object.__setattr__(self, name, value)
-        for name, allowed in (("attn_pattern", ("full", "window", "conv")),
+        for name, allowed in (("attn_pattern", ("full", "window", *STATE_KINDS)),
                               ("ffn_pattern", ("dense", "experts"))):
             pattern = derived[name]
             if pattern and (
@@ -163,8 +177,20 @@ class ModelConfig:
                 )
         if "window" in derived["attn_pattern"] and self.window < 1:
             raise ValueError("a windowed layer needs `window` >= 1")
-        if "conv" in derived["attn_pattern"] and self.conv_kernel < 2:
-            raise ValueError("a convolution layer needs `conv_kernel` >= 2")
+        if set(STATE_KINDS) & set(derived["attn_pattern"]) and self.conv_kernel < 2:
+            raise ValueError(
+                "a convolution or delta layer needs `conv_kernel` >= 2"
+            )
+        if "delta" in derived["attn_pattern"] and not (
+            self.delta_heads > 0 and self.delta_key_dim > 0
+            and self.delta_value_dim > 0
+        ):
+            raise ValueError(
+                "a delta layer needs delta_heads, delta_key_dim and "
+                "delta_value_dim"
+            )
+        if self.qk_norm_whole and not self.qk_norm:
+            raise ValueError("`qk_norm_whole` says over what `qk_norm` runs")
         if "experts" in derived["ffn_pattern"]:
             first, count = derived["experts_held"]
             if not (
@@ -188,8 +214,8 @@ class ModelConfig:
         return not self.attn_pattern and not self.ffn_pattern
 
     def attn_kind(self, name: str) -> AttnKind:
-        if name == "conv":
-            return CONV
+        if name in STATE_KINDS:
+            return STATE_KINDS[name]
         if name == "window":
             return AttnKind(
                 "window", self.window_kv_heads or self.n_kv_heads,
@@ -251,18 +277,33 @@ class ModelConfig:
 
     def kv_classes(self) -> Dict[str, Tuple[int, AttnKind]]:
         """Classes of KV page, by the attention kind that writes them:
-        name -> (layers of that kind, the kind). A convolution layer
-        writes none."""
+        name -> (layers of that kind, the kind). A convolution or delta
+        layer writes none."""
         counts: Dict[str, int] = {}
         for attn, _ in self.layer_kinds():
-            if attn != "conv":
+            if attn not in STATE_KINDS:
                 counts[attn] = counts.get(attn, 0) + 1
         return {n: (c, self.attn_kind(n)) for n, c in sorted(counts.items())}
 
+    def state_kinds(self) -> Dict[str, int]:
+        """Kinds of layer that keep state by slot and no pages, by name ->
+        layers of that kind: ``conv``, ``delta``."""
+        counts: Dict[str, int] = {}
+        for attn, _ in self.layer_kinds():
+            if attn in STATE_KINDS:
+                counts[attn] = counts.get(attn, 0) + 1
+        return dict(sorted(counts.items()))
+
     @property
     def state_layers(self) -> int:
-        """Layers that keep state by slot and no pages: the convolutions."""
-        return sum(1 for attn, _ in self.layer_kinds() if attn == "conv")
+        """Layers that keep state by slot and no pages."""
+        return sum(self.state_kinds().values())
+
+    @property
+    def delta_width(self) -> int:
+        """Channels of a delta layer's short convolution: q, k and v of
+        every head side by side."""
+        return self.delta_heads * (2 * self.delta_key_dim + self.delta_value_dim)
 
     def require_uniform_dense(self, path: str) -> None:
         """For the paths that run the one uniform block with heads of one
@@ -278,12 +319,18 @@ class ModelConfig:
         derived = self.d_model // self.n_heads
         for name, plain in (("head_dim", derived), ("v_head_dim", derived),
                             ("rotary_dim", derived), ("value_scale", 1.0),
-                            ("qk_norm", False), ("tie_embeddings", False)):
+                            ("qk_norm", False), ("tie_embeddings", False),
+                            ("post_norm", False)):
             if getattr(self, name) != plain:
                 raise UnsupportedModelFeature(
                     f"{path} does not implement `{name}`="
                     f"{getattr(self, name)}"
                 )
+        if not self.rope_theta:
+            raise UnsupportedModelFeature(
+                f"{path} always rotates q and k; `rope_theta`=0 (no rotary) "
+                "is not implemented there"
+            )
 
 
 def _dense_init(key, *shape, dtype, scale=None):
@@ -346,14 +393,33 @@ def _init_params_by_run(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
             p["w_in"] = dense(k[0], n, d, 3 * d)
             p["conv"] = dense(k[1], n, cfg.conv_kernel, d)
             p["w_out"] = dense(k[3], n, d, d)
+        elif kind.name == "delta":
+            heads, wide = cfg.delta_heads, cfg.delta_heads * cfg.delta_value_dim
+            kd = jax.random.split(k[9], 3)
+            p["w_qkv"] = dense(k[0], n, d, cfg.delta_width)
+            p["conv"] = dense(k[1], n, cfg.conv_kernel, cfg.delta_width)
+            p["w_a"] = dense(k[2], n, d, heads)
+            p["w_b"] = dense(k[3], n, d, heads)
+            # Mamba-2's and the published layer's: A in (0, 16), dt in
+            # [0.001, 0.1], log-uniform
+            p["a_log"] = jnp.log(jax.random.uniform(
+                k[4], (n, heads), jnp.float32, 1e-3, 16.0))
+            dt_init = jnp.exp(jax.random.uniform(
+                kd[0], (n, heads), jnp.float32, jnp.log(1e-3), jnp.log(0.1)))
+            p["dt_bias"] = dt_init + jnp.log(-jnp.expm1(-dt_init))
+            p["w_g"] = dense(kd[1], n, d, wide)
+            p["o_norm"] = jnp.ones((n, cfg.delta_value_dim), dt)
+            p["wo"] = dense(kd[2], n, wide, d)
         else:
             p["wq"] = dense(k[0], n, d, cfg.n_heads * cfg.head_dim)
             p["wk"] = dense(k[1], n, d, kind.kv_heads * cfg.head_dim)
             p["wv"] = dense(k[2], n, d, kind.kv_heads * cfg.v_head_dim)
             p["wo"] = dense(k[3], n, cfg.n_heads * cfg.v_head_dim, d)
             if cfg.qk_norm:
-                p["q_norm"] = jnp.ones((n, cfg.head_dim), dt)
-                p["k_norm"] = jnp.ones((n, cfg.head_dim), dt)
+                q_row = cfg.n_heads if cfg.qk_norm_whole else 1
+                k_row = kind.kv_heads if cfg.qk_norm_whole else 1
+                p["q_norm"] = jnp.ones((n, q_row * cfg.head_dim), dt)
+                p["k_norm"] = jnp.ones((n, k_row * cfg.head_dim), dt)
         if kind.sink:
             p["sink"] = jax.random.normal(k[4], (n, cfg.n_heads), jnp.float32)
         if run.experts:
@@ -639,6 +705,16 @@ def head_logits(cfg: ModelConfig, params, h: jax.Array) -> jax.Array:
     return (h @ params["head"]).astype(jnp.float32)
 
 
+def _tap_sum(taps, earlier, s):
+    """A causal depthwise convolution as shifted products, in float32:
+    ``taps`` one row a tap, the oldest first; ``earlier`` the columns of
+    ``s`` before each token, the oldest first."""
+    return sum(
+        tap.astype(jnp.float32) * col.astype(jnp.float32)
+        for tap, col in zip(taps, (*earlier, s))
+    )
+
+
 def gated_conv(cfg: ModelConfig, p, x, shift):
     """The operator of a ``conv`` layer: ``[B, C, z] = x W_in``; ``s = B *
     z``; a causal depthwise convolution of ``s`` over the last
@@ -651,45 +727,215 @@ def gated_conv(cfg: ModelConfig, p, x, shift):
     gate_in, gate_out, z = jnp.split(x @ p["w_in"], 3, axis=-1)
     s = gate_in * z
     earlier, cache = shift(s)
-    mixed = sum(
-        tap.astype(jnp.float32) * col.astype(jnp.float32)
-        for tap, col in zip(p["conv"], (*earlier, s))
-    )
+    mixed = _tap_sum(p["conv"], earlier, s)
     return (gate_out * mixed.astype(cfg.dtype)) @ p["w_out"], cache
+
+
+_EXACT = jax.lax.Precision.HIGHEST  # float32 products that stay float32 on a TPU
+
+
+def _unit_lower_inverse(a: jax.Array) -> jax.Array:
+    """``(I + a)^-1`` of a strictly lower-triangular ``a`` ``[..., C, C]``,
+    C a power of two: forward substitution by blocks, as matrix products.
+    The inverse of a diagonal block of size 2b is ``[[X, 0], [-Y L X,
+    Y]]`` of its two halves' inverses X, Y and its lower-left block L; with
+    M the block-diagonal matrix of all the halves' inverses and ``a_low``
+    all the blocks L in their places that is ``M - M a_low M``, so log2 C
+    rounds of two products of whole C x C matrices give the inverse (a
+    Neumann series would sum powers of ``a`` that cancel, a substitution
+    row by row is C sequential steps, and blocks of 2 x 2 or 4 x 4 as
+    arrays of their own take a tile of the chip each)."""
+    c = a.shape[-1]
+    if c & (c - 1):
+        raise ValueError(f"a block of the scan holds a power of two, not {c}")
+    at = jnp.arange(c)
+
+    def low(b):
+        """``a``'s lower-left blocks of the diagonal blocks of size 2b:
+        rows of a block's second half, columns of its first."""
+        rows, cols = at[:, None], at[None, :]
+        return jnp.where(
+            (rows // (2 * b) == cols // (2 * b)) & (rows // b > cols // b),
+            a, 0.0,
+        )
+
+    inv = jnp.eye(c, dtype=a.dtype) - low(1)  # blocks of 2: X = Y = 1
+    b = 2
+    while b < c:
+        inv = inv - jnp.einsum(
+            "...ij,...jk,...kl->...il", inv, low(b), inv, precision=_EXACT
+        )
+        b *= 2
+    return inv
+
+
+def delta_step(state, q, k, v, g, beta):
+    """The gated delta rule moved on by one token, every head of every
+    sequence alike, in float32: ``S' = exp(g) S``; ``u = beta (v - S'^T
+    k)``; ``S = S' + k u^T``; ``o = S^T q``. state: [..., dk, dv]; q, k:
+    [..., dk]; v: [..., dv]; g, beta: [...]. Returns (o [..., dv], S).
+    Products and sums over one axis, no matrix unit: nothing is rounded."""
+    state = state * jnp.exp(g)[..., None, None]
+    u = beta[..., None] * (v - jnp.sum(k[..., None] * state, -2))
+    state = state + k[..., None] * u[..., None, :]
+    return jnp.sum(q[..., None] * state, -2), state
+
+
+def delta_scan(state, q, k, v, g, beta, block: int = 0):
+    """The same recurrence over T tokens of one sequence as a scan over
+    blocks of ``block`` tokens (0 = ``DELTA_BLOCK``; a power of two; the
+    delta rule's chunked form, Yang et al. 2024, with the gate's decay): inside a block products of block-sized
+    matrices, from block to block the carried ``S``. state: [H, dk, dv]
+    float32, the state before the first token; q, k: [T, H, dk]; v: [T, H,
+    dv]; g, beta: [T, H]; all float32. Returns (o [T, H, dv], the state
+    after the last token). A token with g = 0 and beta = 0 changes
+    nothing, which is how the caller ends the sequence before T and how a
+    ragged last block is filled here.
+
+    With ``c_t`` the sum of g over the block's tokens up to t and ``D[t,
+    i] = exp(c_t - c_i)`` for i <= t: ``u`` solves ``(I + A) U = beta (V -
+    exp(c) K S_0)``, ``A[t, i] = beta_t D[t, i] k_t.k_i`` below the
+    diagonal; ``O = exp(c) Q S_0 + ((Q K^T) * D) U``; ``S_end = exp(c_C)
+    S_0 + (K * D[C, :])^T U``. What does not hold ``S_0`` is computed for
+    all blocks at once."""
+    block = block or DELTA_BLOCK
+    t, heads = g.shape
+    n = -(-t // block)
+    pad = n * block - t
+
+    def blocks(x):  # [T, H, ...] -> [n, H, block, ...]
+        x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+        return jnp.moveaxis(x.reshape(n, block, *x.shape[1:]), 1, 2)
+
+    q, k, v, g, beta = map(blocks, (q, k, v, g, beta))
+    c = jnp.cumsum(g, -1)  # [n, H, C]
+    at = jnp.arange(block)
+    upto = at[:, None] >= at[None, :]
+    # exp of a masked difference: above the diagonal it would overflow
+    decay = jnp.exp(jnp.where(upto, c[..., :, None] - c[..., None, :], -jnp.inf))
+    kk = jnp.einsum("nhtd,nhid->nhti", k, k, precision=_EXACT)
+    a = jnp.where(at[:, None] > at[None, :], beta[..., None] * decay * kk, 0.0)
+    solve = _unit_lower_inverse(a)  # [n, H, C, C]
+    u_free = jnp.einsum(
+        "nhti,nhid->nhtd", solve, beta[..., None] * v, precision=_EXACT
+    )
+    since = jnp.exp(c)  # the decay from the block's start to each token
+    w = jnp.einsum(
+        "nhti,nhid->nhtd", solve, (beta * since)[..., None] * k,
+        precision=_EXACT,
+    )
+    qk = decay * jnp.einsum("nhtd,nhid->nhti", q, k, precision=_EXACT)
+    q_in = since[..., None] * q
+    end = since[..., -1]  # [n, H]
+    k_out = jnp.exp(c[..., -1:] - c)[..., None] * k
+
+    def one(state, xs):
+        u_free, w, qk, q_in, k_out, end = xs
+        u = u_free - jnp.einsum("htk,hkv->htv", w, state, precision=_EXACT)
+        o = jnp.einsum("htk,hkv->htv", q_in, state, precision=_EXACT) + (
+            jnp.einsum("hti,hiv->htv", qk, u, precision=_EXACT)
+        )
+        state = end[:, None, None] * state + jnp.einsum(
+            "htk,htv->hkv", k_out, u, precision=_EXACT
+        )
+        return state, o
+
+    state, o = jax.lax.scan(one, state, (u_free, w, qk, q_in, k_out, end))
+    return jnp.moveaxis(o, 1, 2).reshape(n * block, heads, -1)[:t], state
+
+
+def gated_delta(cfg: ModelConfig, p, x, shift, recur):
+    """The operator of a ``delta`` layer, the gated delta rule (Yang,
+    Kautz, Hatamizadeh 2024): ``[q, k, v] = x W_qkv``, each channel through
+    a causal depthwise convolution over the last ``conv_kernel`` tokens
+    (``p["conv"]``, the oldest tap first) and SiLU; per head q and k to unit
+    length, q times ``dk^-1/2``; ``beta = sigmoid(x W_b)`` (times 2 with
+    ``delta_neg_eigval``); ``g = -exp(A_log) softplus(x W_a + dt_bias)``;
+    the recurrence of ``delta_step`` over the caller's state; the output
+    RMS-normed over a head's dims (one scale for all heads), gated by
+    ``silu(x W_g)``, through ``W_o``. ``shift(s)`` is ``gated_conv``'s:
+    the columns of ``s`` before each token, and the caller's cache;
+    ``recur(q, k, v, g, beta, cache)`` runs the recurrence in float32 from
+    where the caller keeps a sequence's ``S`` and returns (o [..., H, dv],
+    the cache)."""
+    lead = x.shape[:-1]
+    heads, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    s = x @ p["w_qkv"]
+    earlier, cache = shift(s)
+    mixed = jax.nn.silu(_tap_sum(p["conv"], earlier, s))
+    q, k, v = jnp.split(mixed, [heads * dk, 2 * heads * dk], axis=-1)
+    q = q.reshape(*lead, heads, dk)
+    k = k.reshape(*lead, heads, dk)
+
+    def unit(y):
+        return y / (jnp.sqrt(jnp.sum(y * y, -1, keepdims=True)) + 1e-6)
+
+    beta = jax.nn.sigmoid((x @ p["w_b"]).astype(jnp.float32))
+    if cfg.delta_neg_eigval:
+        beta = 2.0 * beta
+    g = -jnp.exp(p["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+        (x @ p["w_a"]).astype(jnp.float32) + p["dt_bias"].astype(jnp.float32)
+    )
+    o, cache = recur(
+        unit(q) * dk**-0.5, unit(k), v.reshape(*lead, heads, dv), g, beta,
+        cache,
+    )
+    o = rms_norm(o, p["o_norm"].astype(jnp.float32), cfg.rms_eps)
+    gate = jax.nn.silu(x @ p["w_g"])
+    return (o.reshape(*lead, -1).astype(cfg.dtype) * gate) @ p["wo"], cache
 
 
 def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
                   live=None, layer=None):
     """One layer of the kind ``run`` names: norm, the token mixer through
-    the caller's cache, feed-forward. h: [..., D]; ``ang``: the rotary
-    angles [..., rotary_dim / 2] of h's tokens at the kind's rope base.
+    the caller's cache, feed-forward; with ``post_norm`` the two norms
+    come after the operator and after the feed-forward, inside the
+    residual (``x + norm(op(x))``), and nothing is normed before. h: [...,
+    D]; ``ang``: the rotary angles [..., rotary_dim / 2] of h's tokens at
+    the kind's rope base, None where the kind does not rotate.
     An attention layer: Q K V, rotary, and ``mix(q, k, v, sink)``, which
     gets [..., heads, size] arrays (``sink``: float32[H] or None), writes k
     and v where the caller keeps them and returns (float32 [..., H *
     v_head_dim], the caller's cache). A convolution layer: ``gated_conv``
-    with ``mix`` as its ``shift``. Returns (h, that cache, int32[2]:
-    token-expert pairs this holder computed and held experts hit; zeros in
-    a dense layer). ``live``: bool over the leading dims, tokens whose
-    choice of expert counts. ``layer``: where ``p["moe"]`` holds the
-    experts of a whole run of layers, this layer's place among them
-    (``moe.experts_apply``)."""
+    with ``mix`` as its ``shift``; a delta layer: ``gated_delta`` with
+    ``mix`` as its (``shift``, ``recur``). Returns (h, that cache,
+    int32[2]: token-expert pairs this holder computed and held experts
+    hit; zeros in a dense layer). ``live``: bool over the leading dims,
+    tokens whose choice of expert counts. ``layer``: where ``p["moe"]``
+    holds the experts of a whole run of layers, this layer's place among
+    them (``moe.experts_apply``)."""
     lead, kind = h.shape[:-1], run.attn
-    x = rms_norm(h, p["ln1"], cfg.rms_eps)
+    x = h if cfg.post_norm else rms_norm(h, p["ln1"], cfg.rms_eps)
     if kind.name == "conv":
         op, cache = gated_conv(cfg, p, x, mix)
-        h = h + op
+    elif kind.name == "delta":
+        op, cache = gated_delta(cfg, p, x, *mix)
     else:
-        q = (x @ p["wq"]).reshape(*lead, cfg.n_heads, cfg.head_dim)
-        k = (x @ p["wk"]).reshape(*lead, kind.kv_heads, cfg.head_dim)
-        v = (x @ p["wv"]).reshape(*lead, kind.kv_heads, cfg.v_head_dim)
-        if cfg.qk_norm:
-            q = rms_norm(q, p["q_norm"], cfg.rms_eps)
-            k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+        def project(w, heads, size, norm=None):
+            """x W as heads; q and k normed over the whole row before the
+            cut into heads, or over each head after it."""
+            y = x @ p[w]
+            norm = norm if cfg.qk_norm else None
+            if norm and cfg.qk_norm_whole:
+                y = rms_norm(y, p[norm], cfg.rms_eps)
+            y = y.reshape(*lead, heads, size)
+            if norm and not cfg.qk_norm_whole:
+                y = rms_norm(y, p[norm], cfg.rms_eps)
+            return y
+
+        q = project("wq", cfg.n_heads, cfg.head_dim, "q_norm")
+        k = project("wk", kind.kv_heads, cfg.head_dim, "k_norm")
+        v = project("wv", kind.kv_heads, cfg.v_head_dim)
         if cfg.value_scale != 1.0:
             v = v * cfg.value_scale
-        attn, cache = mix(rotate(q, ang), rotate(k, ang), v, p.get("sink"))
-        h = h + (attn.astype(cfg.dtype) @ p["wo"])
-    x2 = rms_norm(h, p["ln2"], cfg.rms_eps)
+        if ang is not None:
+            q, k = rotate(q, ang), rotate(k, ang)
+        attn, cache = mix(q, k, v, p.get("sink"))
+        op = attn.astype(cfg.dtype) @ p["wo"]
+    if cfg.post_norm:
+        op = rms_norm(op, p["ln1"], cfg.rms_eps)
+    h = h + op
+    x2 = h if cfg.post_norm else rms_norm(h, p["ln2"], cfg.rms_eps)
     if run.experts:
         y, pairs, hit = moe_mod.experts_apply(
             p["moe"], x2.reshape(-1, cfg.d_model),
@@ -698,13 +944,17 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
             norm_eps=cfg.router_norm_eps, scale=cfg.routed_scaling,
             layer=layer,
         )
-        return h + y.reshape(h.shape), cache, jnp.stack([pairs, hit])
-    y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
-    return h + y, cache, jnp.zeros((2,), jnp.int32)
+        y = y.reshape(h.shape)
+    else:
+        y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
+    if cfg.post_norm:
+        y = rms_norm(y, p["ln2"], cfg.rms_eps)
+    counts = jnp.stack([pairs, hit]) if run.experts else jnp.zeros((2,), jnp.int32)
+    return h + y, cache, counts
 
 
 def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
-              live=None, shift=None):
+              live=None, shift=None, recur=None):
     """Every layer in the pattern's order, each run of one kind a
     ``lax.scan`` over the run's stacked weights: all of them but an
     expert run's ``moe.EXPERT_WEIGHTS``, which the scan's body closes over
@@ -712,11 +962,13 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
     run (as the scan's ``xs`` a layer's ``[held, D, F]`` was copied out of
     the stack every step, before the grouped matmul read it).
     ``positions``: int32 of h's leading dims. ``attend(kind, layer, q, k,
-    v, sink, cache) -> (attention, cache)`` and, for a convolution layer,
-    ``shift(layer, s, cache) -> (the columns before each token, cache)``:
-    ``layer`` counts within what the kind keeps for a sequence (its class
-    of KV page, the convolutions' state). Returns (h, cache, the blocks'
-    int32[2] counts summed)."""
+    v, sink, cache) -> (attention, cache)``; for a convolution or a delta
+    layer ``shift(kind, layer, s, cache) -> (the columns before each
+    token, cache)``, and for a delta layer also ``recur(layer, q, k, v, g,
+    beta, cache) -> (the recurrence's output, cache)``: ``layer`` counts
+    within what the kind keeps for a sequence (its class of KV page, its
+    state by slot). Returns (h, cache, the blocks' int32[2] counts
+    summed)."""
     cfg.require_blocks_by_run(blocks)
     counts = jnp.zeros((2,), jnp.int32)
     for run in cfg.layer_runs():
@@ -727,16 +979,22 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
             stack = {**stack, "moe": {
                 k: v for k, v in stack["moe"].items() if k not in experts
             }}
-        conv = run.attn.name == "conv"
-        ang = None if conv else rope_freqs(
-            cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
-        )[positions]
+        stateful = run.attn.name in STATE_KINDS
+        ang = None
+        if not stateful and run.attn.rope_theta:
+            ang = rope_freqs(
+                cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
+            )[positions]
 
-        def body(carry, p, run=run, ang=ang, conv=conv, experts=experts):
+        def body(carry, p, run=run, ang=ang, stateful=stateful,
+                 experts=experts):
             h, cache, layer, counts = carry
-            if conv:
+            if stateful:
                 def mix(s):
-                    return shift(layer, s, cache)
+                    return shift(run.attn, layer, s, cache)
+
+                if run.attn.name == "delta":
+                    mix = (mix, functools.partial(recur, layer))
             else:
                 def mix(q, k, v, sink):
                     return attend(run.attn, layer, q, k, v, sink, cache)

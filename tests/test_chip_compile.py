@@ -120,6 +120,17 @@ def _model(name):
             d_ff_expert=1792, n_routed_experts=32, experts_per_token=4,
             router_norm_eps=1e-6,
         ), 64, 8192
+    if name == "olmo":  # 4 full layers of 30 KV heads x 128 in groups of
+        # one, table 1,056; 12 delta layers' float32 matrix a head by slot
+        return tfm.ModelConfig(
+            vocab_size=100352, d_model=3840, n_layers=16, n_heads=30,
+            n_kv_heads=30, d_ff=11008, max_seq_len=16896, rope_theta=0.0,
+            dtype=jnp.bfloat16,
+            attn_pattern=("delta", "delta", "delta", "full") * 4,
+            ffn_pattern=("dense",) * 16, qk_norm=True, qk_norm_whole=True,
+            post_norm=True, conv_kernel=4, delta_heads=30, delta_key_dim=96,
+            delta_value_dim=192, delta_neg_eigval=True,
+        ), 16, 4096
     # mimo-v2.5-l7-ep16: a full class of 4 KV heads, groups of 16, keys of
     # 192 stored 256 wide, values 128, table 512; 64 rings of 9 pages
     return tfm.ModelConfig(
@@ -428,6 +439,73 @@ def test_state_by_slot_and_heads_of_64_copy_no_pool_and_no_weights(
     # 0.23, 0.06 and 0.68 GiB at the parent, the copied matrices among them
     bound = {"decode_step": 0.05, "prefill": 0.05, "prefill_suffix": 0.5}
     assert mem.temp_size_in_bytes < bound[program] * 2**30
+
+
+# -- a float32 matrix of state a head at the widths of `olmo-hybrid-7b-l16` ------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
+def test_a_matrix_of_state_a_head_and_groups_of_one_copy_no_state(
+    deployment, program
+):
+    """``olmo-hybrid-7b-l16.longdoc``: 16 slots, contexts to 16,896 (a
+    table of 1,056 pages, twice the longest before it), 4,096 pages of 4
+    full layers x 30 KV heads x 128 with as many query heads (the kernel's
+    groups of one: a chunk is 17 pages of 240 KiB), and 12 delta layers'
+    state by slot: ``S`` ``[12, 16, 30, 96, 192]`` in float32 and the
+    columns ``[12, 3, 16, 11520]``. The chip lays a row of 192 out in 256
+    lanes, so ``S`` takes 4/3 of its 0.40 GiB; it keeps that one layout
+    through every program. Every program aliases both pools and both
+    arrays of state and copies none of them: ``decode_step`` updates the
+    whole state in place inside the layers' scans, the prefill programs
+    carry one slot's rows and put them in once. The first prefill program
+    takes 2,976 tokens and a chunk 736 (30 heads), neither a multiple of
+    the scan's block of 64: the last block is ragged. Temporaries: the
+    chunk's float32 scores over the whole table, 1.39 GiB of 1.73."""
+    d = deployment("olmo")
+    eng, table = d.eng, d.tables["full"].shape[1]
+    assert (eng.pool.k_dim, eng.pool.v_dim, table) == (128, 128, 1056)
+    assert (eng.max_prefill_tokens, eng.prefill_chunk) == (2976, 736)
+    assert d.state["delta_s"].shape == (12, 16, 30, 96, 192)
+    assert d.state["delta_s"].dtype == jnp.float32
+    assert d.state["delta_taps"].shape == (12, 3, 16, 11520)
+    assert [r.count for r in d.cfg.layer_runs()] == [3, 1] * 4
+    if program == "decode_step":
+        compiled = d.decode_step()
+    elif program == "prefill":
+        compiled = eng._prefill.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(2976), 2976,
+            {"full": d.ints(2976 // PAGE)}, d.state, d.ints(), d.ints(),
+        ).compile()
+    else:
+        compiled = eng._prefill_suffix.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(736), 736, d.ints(),
+            {"full": d.ints(table)}, d.ints(736 // PAGE), d.state,
+            d.ints(), d.ints(),
+        ).compile()
+    text = compiled.as_text()
+    assert not d.copies_of_a_pool(text)
+    assert not re.findall(r"= f32\[12,16,30,96,192\]\S* copy\(", text)
+    assert not re.findall(r"= bf16\[12,3,16,11520\]\S* copy\(", text)
+    assert set(re.findall(r"f32\[12,16,30,96,192\](\{[^}]*\})", text)) == {
+        "{4,3,2,1,0:T(8,128)}"}
+    if program == "decode_step":
+        kernels = [
+            line for line in text.splitlines()
+            if f'custom_call_target="{KERNEL}"' in line
+            and "paged_attention_decode" in line
+        ]
+        assert kernels and all("bf16[4,30,4096,16,128]" in k for k in kernels)
+    mem = compiled.memory_analysis()
+    # as the chip stores it: rows of 192 in 256 lanes
+    state = 12 * 16 * 30 * 96 * 256 * 4 + 2 * d.state["delta_taps"].size
+    assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
+    bound = {"decode_step": 0.05, "prefill": 0.75, "prefill_suffix": 1.9}
+    assert mem.temp_size_in_bytes < bound[program] * 2**30
+    # nothing but the logits leaves a program beside what it aliases
+    rows = {"decode_step": 0, "prefill": 2976, "prefill_suffix": 736}[program]
+    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert own < rows * 100352 * 4 + 2**20
 
 
 @pytest.mark.parametrize("name", ["mistral", "mixed", "lfm2"])
