@@ -145,6 +145,12 @@ LANES = 128  # a TPU vector register's lanes: the tile of an array's last dim
 # what one prefill program's [H, T, T] float32 scores may take: the longest
 # prompt a single program runs (2,048 tokens at 64 heads, 2,896 at 32)
 PREFILL_SCORES_BYTES = 2**30
+# what one trip's float32 scores may take where a chunk of a prompt walks its
+# slot's pages (``_table_attention``). Timed alone on a v5e at 30 and at 64
+# heads: 32 to 64 MiB read alike, 12 MiB a third to twice slower (more
+# trips), 128 MiB two to three times (the compiler no longer keeps a trip's
+# scores in the chip's fast memory)
+ATTN_BLOCK_BYTES = 32 * 2**20
 
 
 # the slot's axis in each array of ``PagedKVPool.state``, and the array
@@ -373,6 +379,71 @@ def _window_attention(q, k, v, hist_k, hist_v, q_from, window, sink):
     return out.reshape(nb * w, -1)[:t]
 
 
+def _attention_block_pages(t: int, heads: int, page: int, table: int) -> int:
+    """Pages of a slot's table one trip of ``_table_attention`` takes: as
+    many as keep the trip's ``[t, heads, keys]`` float32 scores within
+    ``ATTN_BLOCK_BYTES``, at least one and at most the table."""
+    return max(1, min(table, ATTN_BLOCK_BYTES // (4 * t * heads * page)))
+
+
+def _table_attention(q, pool_k, pool_v, layer, table, pos, head_dim):
+    """Causal attention of a block of ``t`` queries over the keys a slot
+    holds in its pages, walked in blocks of whole pages as far as the keys
+    go: trip ``i`` gathers ``block_pages`` entries of ``table`` out of the
+    pool's ``layer``, scores them in float32, masks by ``key position <=
+    pos`` and folds them into a running maximum, a running sum and a float32
+    accumulator (the softmax over all keys, summed block by block and
+    divided once at the end). The trip count ``ceil((pos[-1] + 1) /
+    block)`` is traced, so one program serves every history, and what the
+    table holds past the last query's position is never read. Key 0 is
+    seen by every query, so a block wholly masked for a row leaves its
+    running values as they were. q: [t, KH, G, size] at the pool's stored
+    width, of which ``head_dim`` are the head's own; pool_k, pool_v:
+    [layers, KH, pages, page, size]; table: int32[P]; pos: int32[t],
+    increasing. Returns float32 [t, KH, G, v size]."""
+    t, kh, g, _ = q.shape
+    page = pool_k.shape[3]
+    block_pages = _attention_block_pages(t, kh * g, page, table.shape[0])
+    block = block_pages * page
+    trips = -(-table.shape[0] // block_pages)
+    # a slice past the table's end would be moved back inside it: unfilled
+    # entries name the scratch page, whose positions no query reaches
+    table = jnp.pad(table, (0, trips * block_pages - table.shape[0]))
+    qf = jnp.transpose(q, (1, 2, 0, 3)).astype(jnp.float32)  # [KH, G, t, size]
+
+    def fold(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, i * block_pages, block_pages)
+        # one gather out of the pool as it lies, by (layer, head, page): a
+        # slice of the layer first does not depend on the trip, and the
+        # compiler would take it out of the loop, a copy of a layer's pages
+        at = (layer * kh + jnp.arange(kh)[:, None]) * pool_k.shape[2] + ids
+        ks = pool_k.reshape(-1, *pool_k.shape[3:])[at].reshape(kh, block, -1)
+        vs = pool_v.reshape(-1, *pool_v.shape[3:])[at].reshape(kh, block, -1)
+        scores = jnp.einsum(
+            "kgtd,ksd->kgts", qf, ks.astype(jnp.float32)
+        ) / jnp.sqrt(head_dim)
+        seen = (i * block + jnp.arange(block))[None, :] <= pos[:, None]
+        scores = jnp.where(seen[None, None], scores, -1e30)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1))
+        p = jnp.exp(scores - m_new[..., None])
+        keep = jnp.exp(m - m_new)
+        acc = acc * keep[..., None] + jnp.einsum(
+            "kgts,ksd->kgtd", p, vs.astype(jnp.float32)
+        )
+        return m_new, l * keep + jnp.sum(p, axis=-1), acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, -(-(pos[-1] + 1) // block), fold,
+        (
+            jnp.full((kh, g, t), -1e30, jnp.float32),
+            jnp.zeros((kh, g, t), jnp.float32),
+            jnp.zeros((kh, g, t, pool_v.shape[-1]), jnp.float32),
+        ),
+    )
+    return jnp.transpose(acc / l[..., None], (2, 0, 1, 3))
+
+
 class ContinuousBatchingEngine:
     """Slot-based continuous batching over the flagship transformer.
 
@@ -434,8 +505,9 @@ class ContinuousBatchingEngine:
         # the longest prompt one prefill program takes: its [H, T, T]
         # float32 scores stay within PREFILL_SCORES_BYTES. A longer
         # prompt's rest goes through the history-plus-suffix program in
-        # chunks of a quarter of that length, whose scores are
-        # [chunk, H, context] and grow with T only. Whole pages both.
+        # chunks of a quarter of that length, which walks the keys before
+        # and in the chunk block by block: its scores are [H, chunk, block]
+        # a trip and its time grows with T only. Whole pages both.
         whole = int((PREFILL_SCORES_BYTES / (4 * cfg.n_heads)) ** 0.5)
         self.max_prefill_tokens = max(1, whole // page_size) * page_size
         self.prefill_chunk = (
@@ -912,10 +984,11 @@ class ContinuousBatchingEngine:
             tokens' KV is in its pages already (restored from the shared
             prefix cache, or written by the prompt's earlier chunks):
             write the suffix KV into its pages, then attend over history +
-            suffix by gathering the slot's whole page table (fixed
-            shapes — the decode formulation applied to a prompt block;
-            ``hist_len``, a multiple of ``page``, is traced, so one program
-            serves every split within a suffix-length bucket). A windowed
+            suffix by walking the slot's page table in blocks of whole
+            pages as far as the keys go (``_table_attention``: fixed
+            shapes a trip, a traced count of trips; ``hist_len``, a
+            multiple of ``page``, is traced, so one program serves every
+            split within a suffix-length bucket). A windowed
             layer reads the window before the suffix out of the slot's
             ring, then writes the suffix's last pages over it. A
             convolution or delta layer takes row ``slot`` of its state,
@@ -971,25 +1044,14 @@ class ContinuousBatchingEngine:
                     pv = write_pages(
                         pv, layer, suffix_page_ids, stored(v[0], v_dim)
                     )
-                    # history + suffix keys via the slot's full table; key
-                    # positions past hist_len + q_pos (incl. the scratch
-                    # page behind unfilled table slots) are masked
-                    ks = pk[layer][:, table[name]].reshape(kh, S_max, k_dim)
-                    vs = pv[layer][:, table[name]].reshape(kh, S_max, v_dim)
-                    groups = cfg.n_heads // kh
-                    qh = stored(q[0].reshape(t_pad, kh, groups, cfg.head_dim))
-                    scores = jnp.einsum(
-                        "tkgd,ksd->tkgs",
-                        qh.astype(jnp.float32),
-                        ks.astype(jnp.float32),
-                    ) / jnp.sqrt(cfg.head_dim)
-                    causal = jnp.arange(S_max)[None, :] <= pos[:, None]
-                    scores = jnp.where(
-                        causal[:, None, None, :], scores, -1e30
-                    )
-                    probs = jax.nn.softmax(scores, axis=-1)
-                    attn = jnp.einsum(
-                        "tkgs,ksd->tkgd", probs, vs.astype(jnp.float32)
+                    # history + suffix keys through the slot's table, the
+                    # suffix's own among them, as far as they go: what lies
+                    # past hist_len + t_pad is not read, and key positions
+                    # past hist_len + q_pos are masked
+                    qh = q[0].reshape(t_pad, kh, cfg.n_heads // kh, -1)
+                    attn = _table_attention(
+                        stored(qh), pk, pv, layer, table[name], pos,
+                        cfg.head_dim,
                     )[..., : cfg.v_head_dim].reshape(t_pad, -1)[None]
                 return attn, {
                     **cache, "k": {**pool_k, name: pk},
@@ -1247,9 +1309,20 @@ class ContinuousBatchingEngine:
             )
             for name, ids in pages.items()
         }
+        # keys a layer of the ``full`` class scores for the prompt's chunks,
+        # whole blocks as far as each chunk's keys go, beside the keys the
+        # slot's table holds, as many times
+        block = self.page * _attention_block_pages(
+            chunk, self.cfg.n_heads, self.page, self.max_pages_per_seq
+        )
         with tracing.span(
             "engine.prefill", "engine", t_pad=t_pad, true_len=t,
             hit_tokens=0, chunks=1 + chunks, head=head,
+            attn_keys_walked=sum(
+                -(-(at + chunk) // block) * block
+                for at in range(head, t_pad, chunk)
+            ),
+            attn_keys_table=chunks * self.max_pages_per_seq * self.page,
         ) as sp:
             if self.stateful:
                 # rows of state written: one a layer that keeps state a run
@@ -1278,6 +1351,10 @@ class ContinuousBatchingEngine:
                     n: jnp.asarray(row) for n, row in tables.items()
                 }
             for at in range(head, t_pad, chunk):
+                # a run's [chunk, vocabulary] logits are allocated as it is
+                # dispatched: the host stays one run ahead of the device,
+                # not the prompt's nineteen
+                jax.block_until_ready(pairs[-2:-1])
                 logits, moe = self._prefill_chunk(
                     tokens[at : at + chunk], at, dev_tables, pages, slot,
                     min(t - at, chunk),

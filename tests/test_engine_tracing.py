@@ -230,6 +230,34 @@ def test_decode_spans_count_every_token_and_page(toy):
     assert st["queue_wait_s"] == pytest.approx(waits * 1e-3, abs=1e-6)
 
 
+def test_a_chunked_prompts_prefill_span_counts_the_keys_its_chunks_walk(
+    toy, monkeypatch
+):
+    """One prefill program takes 16 tokens at the toy's 4 heads, the rest
+    of a prompt goes in chunks of 8 that walk the slot's table of 12 pages
+    in blocks of 2 pages: the span carries the keys a layer scored, whole
+    blocks as far as each chunk's keys go, beside the keys of the whole
+    table as many times; an unchunked prompt's carries zeros."""
+    from ray_tpu.llm import continuous
+
+    monkeypatch.setattr(continuous, "PREFILL_SCORES_BYTES", 4 * 4 * 16 * 16)
+    monkeypatch.setattr(continuous, "ATTN_BLOCK_BYTES", 4 * 8 * 4 * 16)
+    eng = make_engine(toy, max_batch=2)
+    assert (eng.max_prefill_tokens, eng.prefill_chunk) == (16, 8)
+    assert eng.max_pages_per_seq == 12
+    long, short = list(range(1, 46)), [7, 8, 9]
+    eng.generate_ids([long, short], GEN)
+    chunked, whole = (s["args"] for s in engine_spans("engine.prefill"))
+    # 45 tokens: 48 padded, 16 in the prefill program, chunks at 16, 24,
+    # 32 and 40, whose keys end at 24, 32, 40 and 48: 2, 2, 3, 3 blocks of 16
+    assert (chunked["t_pad"], chunked["head"], chunked["chunks"]) == (48, 16, 5)
+    assert chunked["attn_keys_walked"] == (2 + 2 + 3 + 3) * 16
+    assert chunked["attn_keys_table"] == 4 * 12 * PAGE
+    assert chunked["attn_keys_walked"] <= chunked["attn_keys_table"]
+    assert (whole["chunks"], whole["head"]) == (1, 8)
+    assert (whole["attn_keys_walked"], whole["attn_keys_table"]) == (0, 0)
+
+
 def test_a_pool_too_small_for_two_requests_stalls_the_admit(toy):
     # each request reserves ceil((3 + 16) / 8) = 3 pages; 5 are usable
     eng = make_engine(toy, n_pages=6)
