@@ -3,7 +3,9 @@ python surface (/root/reference/python/ray/_private/worker.py:1406,
 remote_function.py:314, actor.py:1024)."""
 from __future__ import annotations
 
+import faulthandler
 import functools
+import io
 import os
 import uuid
 from typing import Any, Dict, List, Optional, Sequence, Union
@@ -58,9 +60,16 @@ def init(
         if ignore_reinit_error:
             return get_runtime()
         raise RuntimeError("ray_tpu.init() called twice; pass ignore_reinit_error=True")
-    if address is None:
-        from ray_tpu.config import cfg
+    from ray_tpu.config import cfg
 
+    if cfg.crash_bundles and not faulthandler.is_enabled():
+        # a fatal signal (SIGSEGV in native code, say) then names the
+        # frame of every thread on standard error before the process dies
+        try:
+            faulthandler.enable(all_threads=True)
+        except (RuntimeError, io.UnsupportedOperation):
+            pass  # standard error is closed or has no descriptor
+    if address is None:
         address = cfg.head_address or None
     if address is not None:
         from ray_tpu.cluster.client import RemoteRuntime

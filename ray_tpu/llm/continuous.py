@@ -69,7 +69,11 @@ class _Request:
     """A request from ``submit()``/``adopt_pages()`` to its end. ``span``
     is its ``engine.request`` span, open all that time; the times are
     ``perf_counter`` stamps and the lock counters are summed over every
-    acquisition of the engine lock in ``stream_rid``."""
+    acquisition of the engine lock in ``stream_rid``. ``first_step`` is
+    the count of the step that gave it its slot: that step made its second
+    token and step ``first_step + k - 1`` its token ``k``; the first came
+    out of its prefill at ``t_first``. The pickup lags are how long its
+    tokens, once on the host, waited for ``stream_rid``'s consumer."""
 
     req_id: int
     prompt: List[int]
@@ -81,6 +85,9 @@ class _Request:
     lock_wait_s: float = 0.0
     lock_wait_max_s: float = 0.0
     lock_acquires: int = 0
+    first_step: int = 0
+    pickup_lag_s: float = 0.0
+    pickup_lag_max_s: float = 0.0
 
 
 class _PageClass:
@@ -151,6 +158,10 @@ PREFILL_SCORES_BYTES = 2**30
 # trips), 128 MiB two to three times (the compiler no longer keeps a trip's
 # scores in the chip's fast memory)
 ATTN_BLOCK_BYTES = 32 * 2**20
+# steps whose end the engine remembers (``_step_done``): a consumer further
+# behind than that finds its token's stamp overwritten and skips its lag.
+# Eight minutes of steps at 66 a second
+STEP_STAMPS = 1 << 15
 
 
 # the slot's axis in each array of ``PagedKVPool.state``, and the array
@@ -537,6 +548,11 @@ class ContinuousBatchingEngine:
         self.lock_wait_s = 0.0
         self.lock_acquires = 0
         self.queue_wait_s = 0.0
+        # when each step's tokens were on the host (a ``perf_counter`` stamp
+        # where ``engine.readback`` ends), by step count in a ring: what
+        # ``stream_rid`` measures a token's wait for its consumer from
+        self._steps = 0
+        self._step_done = [0.0] * STEP_STAMPS
         # disaggregated serving (PR 18): which weights this engine runs,
         # bumped by swap_params; manifests stamp both so a decode engine
         # never grafts KV computed under different weights
@@ -1074,8 +1090,15 @@ class ContinuousBatchingEngine:
     # ------------------------------------------------------------------
     # scheduler
     # ------------------------------------------------------------------
-    @_locked
     def submit(self, prompt: List[int], gen: GenerationConfig) -> int:
+        t_wait = time.perf_counter()
+        with self._lock:
+            waited = time.perf_counter() - t_wait
+            req = self._submit_locked(prompt, gen)
+            req.span.set(submit_lock_wait_ms=waited * 1e3)
+            return req.req_id
+
+    def _submit_locked(self, prompt: List[int], gen: GenerationConfig):
         if self._swapping and self._swap_started is not None:
             from ray_tpu.config import cfg
 
@@ -1101,7 +1124,7 @@ class ContinuousBatchingEngine:
             )
         req = self._begin_request(list(prompt), gen)
         self.queue.append(req)
-        return req.req_id
+        return req
 
     def _begin_request(self, prompt: List[int], gen: GenerationConfig):
         req = _Request(self._next_req, prompt, gen)
@@ -1184,6 +1207,8 @@ class ContinuousBatchingEngine:
             self.queue.popleft()
             admitted += 1
             req.t_admit = time.perf_counter()
+            req.first_step = self._steps
+            req.span.set(slot=si)
             self.queue_wait_s += req.t_admit - req.t_submit
             prompt = req.prompt
             t = len(prompt)
@@ -1589,6 +1614,8 @@ class ContinuousBatchingEngine:
         req = self._begin_request(prompt, gen)
         # grafted mid-batch: no queue, no prefill here
         req.t_admit = req.t_first = req.t_submit
+        req.first_step = self._steps
+        req.span.set(slot=si)
         rid = req.req_id
         dev = np.asarray(pages["full"][:ship_pages], np.int32)
         self._scatter(dev, k, v)
@@ -1702,7 +1729,7 @@ class ContinuousBatchingEngine:
     def step(self) -> List[int]:
         """Admit + one decode step for all active slots. Returns req_ids
         finished in this step."""
-        with tracing.span("engine.step", "engine"):
+        with tracing.span("engine.step", "engine") as stepping:
             self._admit()
             before = set(self.results)
             live = [s for s in self.slots if s.active]
@@ -1716,6 +1743,7 @@ class ContinuousBatchingEngine:
                     # pages that hold a live token, by class
                     decode.set(
                         live=len(live),
+                        slots=self.B,
                         ctx=sum(s.pos + 1 for s in live),
                         pages_reserved=sum(
                             len(s.pages.get("full", ())) for s in live
@@ -1749,6 +1777,10 @@ class ContinuousBatchingEngine:
                 # where the host waits for the step's tokens
                 with tracing.span("engine.readback", "engine"):
                     nxt_h = np.asarray(nxt)
+                if stepping:
+                    self._step_done[self._steps % STEP_STAMPS] = (
+                        time.perf_counter()
+                    )
                 if decode:
                     # the step's sums are known once its tokens are: the
                     # ring's record shares the span's args
@@ -1776,6 +1808,7 @@ class ContinuousBatchingEngine:
                     slot.pos += 1
                     slot.out.append(int(nxt_h[si]))
                     self._maybe_finish(si)
+            self._steps += 1
             return [r for r in self.results if r not in before]
 
     def pending(self) -> int:
@@ -1811,6 +1844,9 @@ class ContinuousBatchingEngine:
         local prefill ever runs)."""
         yielded = 0
         req = self._live.get(rid)
+        # how long its tokens wait for this consumer once they are on the
+        # host: measured while the request has a span
+        traced = req is not None and bool(req.span)
         try:
             while True:
                 t_wait = time.perf_counter()
@@ -1830,16 +1866,42 @@ class ContinuousBatchingEngine:
                     if slot is not None and slot.eos in out:
                         out = out[: out.index(slot.eos)]
                 while yielded < len(out):
+                    if traced:
+                        self._note_pickup(req, yielded)
                     yield out[yielded]
                     yielded += 1
             final = self.results.pop(rid)
             while yielded < len(final):
+                if traced:
+                    self._note_pickup(req, yielded)
                 yield final[yielded]
                 yielded += 1
         finally:
+            if traced:
+                # the ring's record shares the span's args: this lands
+                # there though the engine ended the span before
+                req.span.set(
+                    pickup_lag_ms=req.pickup_lag_s * 1e3,
+                    pickup_lag_max_ms=req.pickup_lag_max_s * 1e3,
+                )
             # consumer abandoned mid-stream: reclaim the slot's pages and
             # stop burning decode steps on a dead client
             self._cancel(rid)
+
+    def _note_pickup(self, req: _Request, k: int) -> None:
+        """Token ``k`` of ``req`` is handed to its consumer now: how long
+        it has been on the host (``_Request.first_step``). Takes no lock: a
+        stamp the ring has since overwritten (``STEP_STAMPS`` steps on) is
+        skipped."""
+        made = req.t_first
+        if k:
+            step = req.first_step + k - 1
+            if self._steps - step >= STEP_STAMPS:
+                return
+            made = self._step_done[step % STEP_STAMPS]
+        waited = max(0.0, time.perf_counter() - made)
+        req.pickup_lag_s += waited
+        req.pickup_lag_max_s = max(req.pickup_lag_max_s, waited)
 
     def _note_lock_wait(self, req: Optional[_Request], waited: float) -> None:
         """One acquisition of the engine lock by a request's stream (the

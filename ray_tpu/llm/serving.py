@@ -333,11 +333,11 @@ def build_llm_deployment(
                 )
                 if handoff and not skip:
                     rid = self._adopt_handoff(handoff)
-                tokens = (
-                    self.engine.stream_rid(rid)
-                    if rid is not None
-                    else self.engine.stream_ids(prompt, gen)
-                )
+                if rid is None:
+                    rid = self.engine.submit(prompt, gen)
+                # the engine's request id, for a reader of the ring
+                sp.set(rid=rid)
+                tokens = self.engine.stream_rid(rid)
                 n = 0
                 try:
                     for tok in tokens:

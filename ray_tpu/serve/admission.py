@@ -30,6 +30,7 @@ import time
 from collections import deque
 from typing import Dict, Optional
 
+from ray_tpu.util import tracing
 from ray_tpu.util.metrics import Counter, Gauge
 
 SERVE_SHED = Counter(
@@ -186,37 +187,51 @@ class AdmissionController:
         timeout_s = (
             self.wait_timeout_s if timeout_s is None else float(timeout_s)
         )
-        with self._cv:
-            # fast path: nobody parked ahead of us and both gates open
-            # (granted-but-unclaimed waiters already own depth slots —
-            # ignoring them here would breach max_inflight under the
-            # exact contention this gate exists for)
-            if (
-                self._waiting == 0
-                and self._inflight + self._granted_pending
-                < self.max_inflight
-                and self._bucket.try_take()
-            ):
-                return self._grant_locked(tenant)
-            if self._waiting >= self.wait_cap:
-                return self._shed_locked("queue_full")
-            waiter = self._park_locked(tenant, cost)
-            deadline = time.monotonic() + timeout_s
+        # one span a request, shed ones too; its length is the time in
+        # the waiting room
+        with tracing.span("serve.admit", "serve", tenant=tenant) as sp, self._cv:
+            # ``waiting``: parked ahead of this request as it came
+            sp.set(waiting=self._waiting, outcome="fast")
             try:
-                while True:
-                    self._pump_locked()
-                    if waiter.granted:
-                        return self._grant_locked(tenant, pumped=True)
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0:
-                        return self._shed_locked("timeout", waiter)
-                    # wake early enough to re-check the refilling bucket
-                    self._cv.wait(timeout=min(remaining, 0.05))
-            except BaseException:
-                self._abandon_locked(waiter)
+                return self._admit_locked(tenant, timeout_s, cost, sp)
+            except Overloaded as exc:
+                sp.set(outcome=f"shed:{exc.reason}")
                 raise
+            finally:
+                # in flight after the grant, or at the shed
+                sp.set(inflight=self._inflight)
 
     # -- internals (caller holds self._cv) -----------------------------
+    def _admit_locked(self, tenant: str, timeout_s: float, cost: int, sp):
+        # fast path: nobody parked ahead of us and both gates open
+        # (granted-but-unclaimed waiters already own depth slots —
+        # ignoring them here would breach max_inflight under the
+        # exact contention this gate exists for)
+        if (
+            self._waiting == 0
+            and self._inflight + self._granted_pending < self.max_inflight
+            and self._bucket.try_take()
+        ):
+            return self._grant_locked(tenant)
+        if self._waiting >= self.wait_cap:
+            return self._shed_locked("queue_full")
+        waiter = self._park_locked(tenant, cost)
+        sp.set(outcome="waited")
+        deadline = time.monotonic() + timeout_s
+        try:
+            while True:
+                self._pump_locked()
+                if waiter.granted:
+                    return self._grant_locked(tenant, pumped=True)
+                remaining = deadline - time.monotonic()
+                if remaining <= 0:
+                    return self._shed_locked("timeout", waiter)
+                # wake early enough to re-check the refilling bucket
+                self._cv.wait(timeout=min(remaining, 0.05))
+        except BaseException:
+            self._abandon_locked(waiter)
+            raise
+
     def _grant_locked(self, tenant: str, pumped: bool = False) -> Ticket:
         if not pumped:
             # WFQ accounting for fast-path grants too, so virtual time

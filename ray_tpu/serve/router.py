@@ -498,6 +498,7 @@ class RoutedStream:
         payload,
         tenant: str,
         ticket,
+        trace: dict,
         resume_base: int = 0,
     ):
         self._router = router
@@ -512,7 +513,6 @@ class RoutedStream:
         self.delivered = 0
         self.failovers = 0
         self._t0 = time.monotonic()
-        self._t0_wall = time.time()
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
         self._finished = False
@@ -528,12 +528,28 @@ class RoutedStream:
             {**self._labels, "model": str(self.model)} if self.model else None
         )
         SERVE_STREAMS.inc(labels=self._labels)
-        # one trace for the stream's whole life: every dispatch (the
-        # first and each failover) is submitted under it, so the
-        # replica's and the engine's spans share its id
-        self._trace = tracing.child_context("serve_stream")
+        # what the wait for a delta cost this stream: reads that ran into
+        # their window, and the probes of the replica that followed
+        self._read_timeouts = 0
+        self._probes = 0
+        self._probe_s = 0.0
+        # one trace for the stream's whole life: the admission before it
+        # (the router hands the trace over), every dispatch (the first and
+        # each failover) and this span lie under it, so the replica's and
+        # the engine's spans share its id
+        self._trace = trace
+        with tracing.installed(self._trace):
+            self._span = tracing.span(
+                "serve.stream", "serve",
+                pid=f"serve:{self._labels['deployment']}",
+            ).begin()
         try:
+            t_dispatch = time.perf_counter()
             self._dispatch(self.resume_base)
+            # the first dispatch: channel or sink made, the call submitted
+            self._span.set(
+                dispatch_ms=(time.perf_counter() - t_dispatch) * 1e3
+            )
         except BaseException:
             self._finish("500")
             raise
@@ -543,7 +559,10 @@ class RoutedStream:
             dispatched = self._router._dispatch_stream(
                 self._payload, resume_from
             )
-        self._reader, self._ref, self._replica, self._cleanup = dispatched
+        (
+            self._reader, self._ref, self._replica, self._cleanup, transport
+        ) = dispatched
+        self._span.set(transport=transport)
 
     # -- consumption ----------------------------------------------------
     def read(self, timeout: Optional[float] = None):
@@ -561,6 +580,7 @@ class RoutedStream:
                     self._finish("200")
                     raise ChannelClosed("stream ended") from None
                 if isinstance(exc, TimeoutError):
+                    self._read_timeouts += 1
                     outcome = self._probe()
                     if outcome is None:  # replica still running
                         if (
@@ -602,6 +622,7 @@ class RoutedStream:
     def _probe(self):
         """None = still running; "done" = method returned; an exception
         = the replica call failed (death, raise)."""
+        t0 = time.perf_counter()
         try:
             ray_tpu.get(self._ref, timeout=0.05)
             return "done"
@@ -609,6 +630,9 @@ class RoutedStream:
             return None
         except BaseException as exc:  # noqa: BLE001
             return exc
+        finally:
+            self._probes += 1
+            self._probe_s += time.perf_counter() - t0
 
     def _drain_tail(self):
         """The replica method returned: drain what it wrote between our
@@ -707,14 +731,9 @@ class RoutedStream:
             SERVE_TPOT_MS.observe(tpot, labels=self._labels)
             if self._mlabels:
                 SERVE_TPOT_MS.observe(tpot, labels=self._mlabels)
-        # request-lifecycle span (ISSUE 15): one slice per stream in the
-        # Chrome-trace export, beside the task slices it caused
-        tracing.SPANS.record(
-            "serve_stream",
-            "serve",
-            self._t0_wall,
-            time.monotonic() - self._t0,
-            pid=f"serve:{self._labels['deployment']}",
+        # request-lifecycle span: one slice per stream in the Chrome-trace
+        # export, beside the task slices it caused
+        self._span.end(
             code=code,
             delivered=self.delivered,
             failovers=self.failovers,
@@ -723,7 +742,9 @@ class RoutedStream:
                 if self._t_first is not None
                 else None
             ),
-            **tracing.event_args(self._trace),
+            read_timeouts=self._read_timeouts,
+            probes=self._probes,
+            probe_ms=self._probe_s * 1e3,
         )
         self._router._note_finished(code)
         self._ticket.done()
@@ -742,13 +763,12 @@ class RoutedStream:
 # the router
 # ---------------------------------------------------------------------------
 class _UnaryRequest:
-    def __init__(self, router, ref, ticket, t0, model=None, trace=None):
+    def __init__(self, router, ref, ticket, t0, span, model=None):
         self._router = router
         self.ref = ref
         self._ticket = ticket
         self._t0 = t0
-        self._trace = trace
-        self._t0_wall = time.time()
+        self._span = span  # its ``serve.unary``, begun before the dispatch
         self._done = False
         self._labels = {"deployment": router._rs.dep.name}
         self._mlabels = (
@@ -784,15 +804,7 @@ class _UnaryRequest:
                 (time.monotonic() - self._t0) * 1000.0,
                 labels=self._labels,
             )
-            tracing.SPANS.record(
-                "serve_unary",
-                "serve",
-                self._t0_wall,
-                time.monotonic() - self._t0,
-                pid=f"serve:{self._labels['deployment']}",
-                code=code,
-                **tracing.event_args(self._trace),
-            )
+            self._span.end(code=code)
             self._router._note_finished(code)
             self._ticket.done()
 
@@ -839,38 +851,46 @@ class ServeRouter:
         model = (
             payload.get("model") if isinstance(payload, dict) else None
         )
-        ticket = self.admission.admit(
-            tenant, cost=_request_cost(payload)
-        )
-        t0 = time.monotonic()
-        hit = None
-        try:
-            with tracing.installed(
-                tracing.child_context("serve_unary")
-            ) as trace:
+        # one trace for the request: its admission, its ``serve.unary``
+        # and the replica's and the engine's spans share the id
+        trace = tracing.child_context("serve.unary")
+        with tracing.installed(trace):
+            ticket = self.admission.admit(
+                tenant, cost=_request_cost(payload)
+            )
+            span = tracing.span(
+                "serve.unary", "serve",
+                pid=f"serve:{self._labels['deployment']}",
+            ).begin()
+            t0 = time.monotonic()
+            hit = None
+            try:
                 ref, replica = self._rs.submit_traced(
                     method, (payload,), {}, model=model
                 )
-            hit = self._lease_hit(replica)
-        except BaseException as exc:
-            ticket.done()
-            # per-model empty set is retryable (503), not a server error
-            code = "503" if isinstance(exc, NoReplicasForModel) else "500"
-            SERVE_REQUESTS.inc(labels={"code": code, **self._labels})
-            if model:
-                SERVE_REQUESTS.inc(
-                    labels={
-                        "code": code,
-                        **self._labels,
-                        "model": str(model),
-                    }
+                hit = self._lease_hit(replica)
+            except BaseException as exc:
+                ticket.done()
+                # per-model empty set is retryable (503), not a server error
+                code = (
+                    "503" if isinstance(exc, NoReplicasForModel) else "500"
                 )
-            self._note_finished(code)
-            raise
+                span.end(code=code)
+                SERVE_REQUESTS.inc(labels={"code": code, **self._labels})
+                if model:
+                    SERVE_REQUESTS.inc(
+                        labels={
+                            "code": code,
+                            **self._labels,
+                            "model": str(model),
+                        }
+                    )
+                self._note_finished(code)
+                raise
         (SERVE_LEASE_HITS if hit else SERVE_LEASE_MISSES).inc(
             labels=self._labels
         )
-        return _UnaryRequest(self, ref, ticket, t0, model=model, trace=trace)
+        return _UnaryRequest(self, ref, ticket, t0, span, model=model)
 
     def call(
         self,
@@ -885,12 +905,15 @@ class ServeRouter:
     def stream(
         self, payload, tenant: str = "default", resume_base: int = 0
     ) -> RoutedStream:
-        ticket = self.admission.admit(
-            tenant, cost=_request_cost(payload)
-        )
+        trace = tracing.child_context("serve.stream")
+        with tracing.installed(trace):
+            ticket = self.admission.admit(
+                tenant, cost=_request_cost(payload)
+            )
         try:
             return RoutedStream(
-                self, payload, tenant, ticket, resume_base=resume_base
+                self, payload, tenant, ticket, trace,
+                resume_base=resume_base,
             )
         except Overloaded:
             raise
@@ -900,7 +923,8 @@ class ServeRouter:
 
     def _dispatch_stream(self, payload, resume_from: int):
         """Pick transport + replica, dispatch ``stream_to``. Returns
-        ``(reader, ref, replica, cleanup(cancelled=...))``."""
+        ``(reader, ref, replica, cleanup(cancelled=...), transport)``,
+        the transport's name ``shm``, ``push`` or ``relay``."""
         from ray_tpu.config import cfg
 
         model = (
@@ -943,7 +967,7 @@ class ServeRouter:
             def cleanup(cancelled: bool = False, _sid=sid):
                 sink.discard(_sid)
 
-            return stream, ref, replica, cleanup
+            return stream, ref, replica, cleanup, "push"
         # legacy polling relay fallback (cross-host, push plane disabled)
         from .proxy import start_stream
 
@@ -967,7 +991,7 @@ class ServeRouter:
             if ch is not None:
                 ch.destroy()
 
-        return reader, ref, None, cleanup
+        return reader, ref, None, cleanup, "relay"
 
     def _maybe_prefill(self, payload, resume_from: int, model):
         """Disaggregated split: when this deployment has a companion
@@ -1042,7 +1066,7 @@ class ServeRouter:
             # next write raises ChannelClosed and generation stops
             ch.destroy()
 
-        return ch.reader, ref, replica, cleanup
+        return ch.reader, ref, replica, cleanup, "shm"
 
     def _own_sink(self) -> StreamSink:
         """This router's push endpoint (lazy — unary-only deployments

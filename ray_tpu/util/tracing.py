@@ -217,14 +217,20 @@ _NO_SPAN = _NoSpan()
 
 
 class SpanBuffer:
-    """Bounded ring of completed spans in Chrome-trace 'X' form."""
+    """Bounded ring of completed spans in Chrome-trace 'X' form.
+    ``dropped`` counts the spans the ring has pushed out since it was made
+    or cleared: a reader of a window that finds it above 0 reads part of
+    that window."""
 
     def __init__(self, max_spans: int = 50_000):
         self._spans: deque = deque(maxlen=max_spans)
         self._lock = threading.Lock()
+        self.dropped = 0
 
     def append(self, span: dict) -> None:
         with self._lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.dropped += 1
             self._spans.append(span)
 
     def span(self, name: str, cat: str = "runtime", pid: str = "", **args):
@@ -281,6 +287,7 @@ class SpanBuffer:
     def clear(self) -> None:
         with self._lock:
             self._spans.clear()
+            self.dropped = 0
 
 
 #: the process's span ring (one per process, like the metrics registry)

@@ -1,6 +1,7 @@
-"""Spans and counters inside the serving engine and the replica's stream
-(``util/tracing.span``): the tree they make, the counts they carry, the
-profiler's copy of them, and that recording them changes nothing."""
+"""Spans and counters from the router's admission down to the serving
+engine and back out to the stream's consumer (``util/tracing.span``): the
+tree they make, the counts and waits they carry, the profiler's copy of
+them, and that recording them changes nothing."""
 import glob
 import os
 import tempfile
@@ -106,6 +107,18 @@ def test_span_records_ids_parent_trace_and_maps_the_perf_clock():
         assert lo <= s["ts"] and s["ts"] + s["dur"] <= hi
     assert abs(by["outer"]["ts"] * 1e-6 - time.time()) < 5.0
     assert outer and inner  # a recording span is truthy
+
+
+def test_a_full_ring_counts_the_spans_it_pushes_out():
+    ring = tracing.SpanBuffer(max_spans=4)
+    assert ring.dropped == 0
+    for i in range(6):
+        with ring.span(f"s{i}", "t"):
+            pass
+    assert ring.dropped == 2
+    assert [s["name"] for s in ring.slices()] == ["s2", "s3", "s4", "s5"]
+    ring.clear()
+    assert ring.dropped == 0 and ring.slices() == []
 
 
 def test_span_off_records_nothing_and_is_falsy(monkeypatch):
@@ -279,10 +292,20 @@ def test_a_pool_too_small_for_two_requests_stalls_the_admit(toy):
 def test_stream_threads_lock_wait_lands_on_the_request_and_in_stats(toy):
     eng = make_engine(toy, max_batch=2)
     outs = []
-    # long answers: with six tokens the other threads' steps could finish a
-    # request before its own thread first took the lock (one run in six on
-    # a loaded host), and its span then closes with no acquisition
     gen = GenerationConfig(max_new_tokens=48, temperature=0.0)
+    # the lock is unfair: the other threads' steps could bring a request to
+    # its end before its own thread has had a turn, and its span then
+    # closes with no acquisition (one run in twenty here, however long the
+    # answers). So no step runs while a live request's thread has yet to
+    # take the lock: a turn that finds one only lets go again
+    step = eng.step
+
+    def step_once_every_stream_has_had_a_turn():
+        if all(r.lock_acquires for r in eng._live.values()):
+            return step()
+        return []
+
+    eng.step = step_once_every_stream_has_had_a_turn
 
     def client(p):
         outs.append(list(eng.stream_ids(p, gen)))
@@ -337,6 +360,129 @@ def test_a_prefix_hit_is_on_the_prefill_span():
         store.close(unlink=True)
 
 
+# -- the waits on a request's way in and a token's way out ----------------------
+def test_submits_wait_for_the_lock_is_on_the_request(toy):
+    eng = make_engine(toy)
+    reached, rids = threading.Event(), []
+
+    def submitter():
+        reached.set()
+        rids.append(eng.submit(PROMPTS[0], GEN))
+
+    held = 0.1
+    with eng._lock:
+        t = threading.Thread(target=submitter)
+        t.start()
+        assert reached.wait(30)
+        time.sleep(held)
+    t.join(30)
+    assert not t.is_alive() and rids == [0]
+    free = eng.submit(PROMPTS[1], GEN)
+    while not {0, free} <= set(eng.results):
+        eng.step()
+    waits = {s["args"]["rid"]: s["args"]["submit_lock_wait_ms"]
+             for s in engine_spans("engine.request")}
+    assert waits[0] >= held * 1e3 * 0.5
+    assert 0 <= waits[free] < held * 1e3 * 0.5
+
+
+def test_a_freed_slots_next_request_names_it_and_the_legs_lie_in_order(toy):
+    eng = make_engine(toy, max_batch=1)
+
+    def replica_call(prompt):
+        with tracing.span("replica.stream", "engine"):
+            return list(eng.stream_ids(prompt, GEN))
+
+    assert len(replica_call(PROMPTS[0])) == GEN.max_new_tokens
+    assert len(replica_call(PROMPTS[1])) == GEN.max_new_tokens
+    first, second = sorted(
+        engine_spans("engine.request"), key=lambda s: s["args"]["rid"])
+    call_1, call_2 = sorted(engine_spans("replica.stream"), key=lambda s: s["ts"])
+    assert first["args"]["slot"] == second["args"]["slot"] == 0
+    assert second["args"]["parent"] == call_2["args"]["id"]
+    # the slot falls free; its thread sees the last token and closes; the
+    # thread takes up the next call; that call submits; it is admitted
+    legs = [
+        first["ts"] + first["dur"],
+        call_1["ts"] + call_1["dur"],
+        call_2["ts"],
+        second["ts"],
+        second["ts"] + second["args"]["queue_wait_ms"] * 1e3,
+    ]
+    assert legs == sorted(legs), legs
+    assert all(d["args"]["slots"] == 1 for d in engine_spans("engine.decode"))
+
+
+def test_a_tokens_wait_for_its_consumer_is_on_the_request(toy):
+    eng = make_engine(toy)
+    nap = 0.05
+    t0 = time.perf_counter()
+    stream = eng.stream_ids(PROMPTS[0], GEN)
+    got = [next(stream)]
+    time.sleep(nap)  # the second token is on the host and waits for this
+    got += list(stream)
+    life_ms = (time.perf_counter() - t0) * 1e3
+    assert len(got) == GEN.max_new_tokens
+    (req,) = engine_spans("engine.request")
+    a = req["args"]
+    assert 0 <= a["pickup_lag_max_ms"] <= a["pickup_lag_ms"] <= life_ms
+    assert a["pickup_lag_max_ms"] >= nap * 1e3
+    # a request whose answer nobody streams has no consumer to wait for
+    tracing.SPANS.clear()
+    eng.generate_ids([PROMPTS[1]], GEN)
+    (req,) = engine_spans("engine.request")
+    assert "pickup_lag_ms" not in req["args"]
+
+
+# -- the admission layer --------------------------------------------------------
+def admits():
+    return [s for s in tracing.SPANS.slices(cat="serve")
+            if s["name"] == "serve.admit"]
+
+
+def test_the_admission_span_counts_in_flight_up_to_the_bound():
+    from ray_tpu.serve.admission import AdmissionController, Overloaded
+
+    ctl = AdmissionController(max_inflight=3, wait_timeout_s=0.05)
+    tickets = [ctl.admit("a") for _ in range(3)]
+    with pytest.raises(Overloaded):
+        ctl.admit("b")
+    threading.Timer(0.05, tickets[0].done).start()
+    ctl.admit("c", timeout_s=30).done()
+    args = [s["args"] for s in admits()]
+    assert [a["outcome"] for a in args] == [
+        "fast", "fast", "fast", "shed:timeout", "waited"]
+    assert [a["inflight"] for a in args] == [1, 2, 3, 3, 3]
+    assert [a["tenant"] for a in args] == ["a", "a", "a", "b", "c"]
+    assert all(a["waiting"] == 0 and "id" in a for a in args)
+    shed, waited = admits()[3:]
+    assert shed["dur"] >= 0.05e6 and waited["dur"] >= 0.04e6
+    assert admits()[0]["dur"] < 0.04e6
+
+
+def test_a_shed_request_leaves_its_admission_and_no_stream():
+    from types import SimpleNamespace
+
+    from ray_tpu.serve.admission import AdmissionController, Overloaded
+    from ray_tpu.serve.router import ServeRouter
+
+    router = ServeRouter(
+        SimpleNamespace(dep=SimpleNamespace(name="full")),
+        admission=AdmissionController(max_inflight=1, wait_timeout_s=0.02),
+    )
+    held = router.admission.admit()
+    for call in (router.stream, router.submit):
+        with pytest.raises(Overloaded):
+            call({"prompt": "no room"})
+    held.done()
+    spans = tracing.SPANS.slices(cat="serve")
+    assert [s["name"] for s in spans] == ["serve.admit"] * 3
+    assert [s["args"]["outcome"] for s in spans] == [
+        "fast", "shed:timeout", "shed:timeout"]
+    # each shed request under a trace of its own, as an admitted one is
+    assert len({s["args"]["trace_id"] for s in spans[1:]}) == 2
+
+
 # -- router to engine: one trace ------------------------------------------------
 @pytest.mark.parametrize("kind", ["sync", "async"])
 def test_an_actor_method_runs_in_its_submitters_trace(kind):
@@ -383,14 +529,16 @@ def test_a_requests_spans_share_one_trace_id_from_router_to_engine(how):
         ))
         router = serve.get_router("traced")
         payload = {"prompt": "trace me", "max_new_tokens": 5}
+        t_a = time.perf_counter()
         if how == "stream":
             assert len(list(router.stream(payload))) == 5
-            names = {"serve_stream", "replica.stream", "engine.request",
-                     "engine.step", "engine.decode", "engine.prefill"}
+            names = {"serve.admit", "serve.stream", "replica.stream",
+                     "engine.request", "engine.step", "engine.decode",
+                     "engine.prefill"}
         else:
             assert router.call(payload, timeout=120)["generated_text"]
-            names = {"serve_unary", "engine.request", "engine.step",
-                     "engine.decode", "engine.prefill"}
+            names = {"serve.admit", "serve.unary", "engine.request",
+                     "engine.step", "engine.decode", "engine.prefill"}
         deadline = time.time() + 10
         while time.time() < deadline:  # the router's span lands at _finish
             spans = [s for s in tracing.SPANS.slices() if s["name"] in names]
@@ -398,12 +546,37 @@ def test_a_requests_spans_share_one_trace_id_from_router_to_engine(how):
                 break
             time.sleep(0.05)
         assert names <= {s["name"] for s in spans}
+        t_b = time.perf_counter()
         ids = {s["args"].get("trace_id") for s in spans}
         assert len(ids) == 1 and None not in ids, ids
+        by = {s["name"]: s for s in spans}
+        # the router's span is a span: an id, and the one clock of the rest
+        routed = by["serve.stream" if how == "stream" else "serve.unary"]
+        assert "id" in routed["args"] and routed["args"]["code"] == "200"
+        assert routed["pid"] == "serve:traced"
+        lo = (tracing.PERF_EPOCH_S + t_a) * 1e6
+        hi = (tracing.PERF_EPOCH_S + t_b) * 1e6
+        assert lo <= by["serve.admit"]["ts"] <= routed["ts"]
+        assert routed["ts"] + routed["dur"] <= hi
+        assert by["serve.admit"]["args"]["outcome"] == "fast"
+        assert routed["ts"] <= by["engine.request"]["ts"]
         if how == "stream":
             (stream,) = [s for s in spans if s["name"] == "replica.stream"]
             assert stream["args"]["tokens"] == 5 and stream["args"]["skip"] == 0
             assert stream["pid"] == "serve:traced"
+            request = by["engine.request"]["args"]
+            assert request["parent"] == stream["args"]["id"]
+            assert request["rid"] == stream["args"]["rid"]
+            assert request["slot"] in (0, 1)
+            a = routed["args"]
+            assert a["delivered"] == 5 and a["failovers"] == 0
+            assert a["transport"] in ("shm", "push", "relay")
+            assert 0 < a["dispatch_ms"] <= routed["dur"] * 1e-3
+            # the replica may take the call up while the dispatch still
+            # does its books, not before the dispatch began
+            assert routed["ts"] <= stream["ts"]
+            assert a["ttft_ms"] > 0 and a["probe_ms"] >= 0
+            assert a["probes"] >= a["read_timeouts"] >= 0
             stats = ray_tpu.get(
                 serve.get_deployment_handle("traced").serve_stats.remote(),
                 timeout=30,
@@ -464,7 +637,10 @@ def test_tokens_are_the_same_with_spans_off_and_nothing_is_recorded(
     off = eng.generate_ids(PROMPTS, GEN)
     streamed = list(eng.stream_ids(PROMPTS[0], GEN))
     assert off == on and streamed == on[0]
-    assert tracing.SPANS.slices() == []
+    from ray_tpu.serve.admission import AdmissionController
+
+    AdmissionController().admit("quiet").done()
+    assert tracing.SPANS.slices() == [] and tracing.SPANS.dropped == 0
     # the running totals are the operator's and do not hang on the switch
     assert eng.stats()["lock_acquires"] >= 1
 

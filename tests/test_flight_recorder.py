@@ -306,6 +306,32 @@ def test_crash_bundle_rotation(tmp_path, monkeypatch):
     assert bundles[-1].endswith("r3")
 
 
+def test_init_lets_a_fatal_signal_name_its_frame(request, monkeypatch):
+    """Where crash bundles are on, ``init`` turns ``faulthandler`` on: a
+    SIGSEGV in native code then dumps every thread's frames on standard
+    error. With them off the process is left as it was."""
+    import faulthandler
+    import sys
+
+    def restore():
+        # pytest's own handler writes to a descriptor it keeps in its stash
+        from _pytest.faulthandler import fault_handler_stderr_fd_key
+
+        fd = request.config.stash.get(fault_handler_stderr_fd_key, None)
+        faulthandler.enable(fd if fd is not None else sys.__stderr__)
+
+    if faulthandler.is_enabled():
+        request.addfinalizer(restore)
+    for bundles, enabled in (("0", False), ("1", True)):
+        faulthandler.disable()
+        monkeypatch.setenv("RAY_TPU_CRASH_BUNDLES", bundles)
+        ray_tpu.init(num_nodes=1, resources_per_node={"CPU": 1})
+        try:
+            assert faulthandler.is_enabled() is enabled
+        finally:
+            ray_tpu.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # live two-node run: federation end-to-end, HTTP scrape validity,
 # scheduler decision attribution (tier-1 CI satellite)
