@@ -498,13 +498,13 @@ class ContinuousBatchingEngine:
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
-        # how decode_step attends over the ``full`` class of page: on a TPU
-        # the Pallas kernel (ops/paged_attention.py), elsewhere the XLA
-        # gather. Read from the platform, set by no caller; the CPU tests
-        # put "interpret" here to run the kernel interpreted
-        self._attn_kernel = (
-            "compiled" if jax.default_backend() == "tpu" else None
-        )
+        # how decode_step attends over the ``full`` class of page and runs
+        # its expert products: on a TPU the Pallas kernels (paged attention,
+        # Megablox gmm), elsewhere the XLA gather and lax.ragged_dot (prefill
+        # keeps ragged_dot everywhere: set-up, PERF.md section 6). Read
+        # from the platform, set by no caller; CPU tests put "interpret" here
+        kernel = "compiled" if jax.default_backend() == "tpu" else None
+        self._attn_kernel = self._moe_kernel = kernel
         self.pool = PagedKVPool(
             cfg, n_pages, page_size, max_batch, kernel=bool(self._attn_kernel)
         )
@@ -763,8 +763,8 @@ class ContinuousBatchingEngine:
             and how many there were, the pages the kernel walked and the
             table entries of those layers, which the gather would have
             read; with state by slot two more: the layers that keep it and
-            the rows of it written, one a layer a live slot), the pool and
-            the state."""
+            the rows of it written, one a layer a live slot; with experts
+            the expert layers run in ``gmm``), the pool and the state."""
             b = self.B
             h = params["embed"][tokens].astype(cfg.dtype)  # [B, D]
             # positions a slot's query sees, itself among them; none if idle
@@ -867,10 +867,16 @@ class ContinuousBatchingEngine:
                  "walked": jnp.zeros((4,), jnp.int32),
                  "stated": jnp.zeros((2,), jnp.int32)},
                 attend, live=active, shift=shift, recur=recur,
+                experts_kernel=self._moe_kernel,
             )
             counts = [moe, cache["walked"]]
             if self.stateful:
                 counts.append(cache["stated"])
+            if cfg.n_routed_experts:
+                kernel_layers = bool(self._moe_kernel) * sum(
+                    r.count for r in cfg.layer_runs() if r.experts
+                )
+                counts.append(jnp.full((1,), kernel_layers, jnp.int32))
             logits = tfm.head_logits(cfg, params, h)
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # per-slot key = fold(request seed, absolute position of the
@@ -1784,9 +1790,10 @@ class ContinuousBatchingEngine:
                 if decode:
                     # the step's sums are known once its tokens are: the
                     # ring's record shares the span's args
-                    pairs, hit, in_kernel, full, walked, entries, *stated = (
+                    pairs, hit, in_kernel, full, walked, entries, *rest = (
                         np.asarray(counts).tolist()
                     )
+                    stated = rest[:2] if self.stateful else ()
                     decode.set(
                         attn_kernel_layers=in_kernel, attn_full_layers=full,
                         attn_pages_walked=walked, attn_table_entries=entries,
@@ -1797,7 +1804,10 @@ class ContinuousBatchingEngine:
                             state_slots_written=stated[1],
                         )
                     if self.cfg.n_routed_experts:
-                        decode.set(moe_pairs_held=pairs, moe_experts_hit=hit)
+                        decode.set(
+                            moe_pairs_held=pairs, moe_experts_hit=hit,
+                            moe_kernel_layers=rest[-1],
+                        )
                 self.positions = self.positions + jnp.where(
                     self.active_mask, 1, 0
                 )

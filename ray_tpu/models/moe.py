@@ -124,8 +124,26 @@ def route(p, x: jax.Array, top_k: int, norm_eps: float = 0.0,
     return chosen, weights if scale == 1.0 else weights * scale
 
 
+def gmm_tiles(rows: int, k: int, n: int) -> tuple[int, int, int]:
+    """Megablox ``gmm``'s (m, k, n) tiles for a product of ``rows`` x
+    ``k`` by ``[groups, k, n]``, from those shapes alone: each the largest
+    piece that divides its dimension, rows in multiples of 8 up to 128,
+    ``k`` in multiples of 128 up to 2,048 and ``n`` up to 1,024 (wider
+    than 512 lanes a decode layer's products read their weights 2 to 18 %
+    faster on a v5e; PERF.md section 6). A dimension no such piece
+    divides is one whole tile (a block as long as its array is always
+    legal)."""
+
+    def piece(size, cap, align):
+        fits = range(align, min(size, cap) + 1, align)
+        return max((t for t in fits if size % t == 0), default=size)
+
+    return piece(rows, 128, 8), piece(k, 2048, 128), piece(n, 1024, 128)
+
+
 def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
-                  norm_eps: float = 0.0, scale: float = 1.0, layer=None):
+                  norm_eps: float = 0.0, scale: float = 1.0, layer=None,
+                  kernel=None):
     """This holder's part of an expert layer: ``sum of w_e * SwiGLU_e(x)``
     over the experts a token chose that are held here, experts
     ``held[0] .. held[0] + held[1] - 1`` of the router's width. No token
@@ -155,6 +173,14 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
     and all ``N * top_k`` pairs in the branch taken when more than that
     arrive.
 
+    ``kernel``: None for ``lax.ragged_dot``; ``"compiled"`` or
+    ``"interpret"`` for Megablox ``gmm`` (a Pallas kernel, compiled for
+    the TPU or interpreted) over the same rows, groups and stack, tiled by
+    ``gmm_tiles``. At a decode step's few rows ``ragged_dot`` read the
+    experts at half of HBM's rate and ``gmm`` near it (PERF.md section
+    6). Both take the operands' precision and accumulate
+    in float32.
+
     Returns (out [N, D], pairs held here, held experts hit), the last two
     int32 counts over live tokens."""
     n, d = x.shape
@@ -181,12 +207,23 @@ def experts_apply(p, x: jax.Array, *, top_k: int, held, live=None,
         jnp.zeros((groups,), jnp.int32), sizes, (layer * count,)
     )
 
+    def grouped(lhs, rhs):
+        if kernel is None:
+            return jax.lax.ragged_dot(lhs, rhs, in_stack)
+        from jax.experimental.pallas.ops.tpu import megablox
+
+        return megablox.gmm(
+            lhs, rhs, in_stack, preferred_element_type=lhs.dtype,
+            tiling=gmm_tiles(*lhs.shape, rhs.shape[-1]),
+            interpret=kernel == "interpret",
+        )
+
     def run(rows: int):
         tok, w = token[:rows], weight[:rows]
         xs = x[tok]
-        gate = jax.lax.ragged_dot(xs, w_gate, in_stack)
-        up = jax.lax.ragged_dot(xs, w_up, in_stack)
-        y = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_down, in_stack)
+        gate = grouped(xs, w_gate)
+        up = grouped(xs, w_up)
+        y = grouped(jax.nn.silu(gate) * up, w_down)
         # rows past the last group belong to no expert: whatever the
         # kernel left there is not read
         y = jnp.where(

@@ -886,7 +886,7 @@ def gated_delta(cfg: ModelConfig, p, x, shift, recur):
 
 
 def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
-                  live=None, layer=None):
+                  live=None, layer=None, experts_kernel=None):
     """One layer of the kind ``run`` names: norm, the token mixer through
     the caller's cache, feed-forward; with ``post_norm`` the two norms
     come after the operator and after the feed-forward, inside the
@@ -903,7 +903,7 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
     hit; zeros in a dense layer). ``live``: bool over the leading dims,
     tokens whose choice of expert counts. ``layer``: where ``p["moe"]``
     holds the experts of a whole run of layers, this layer's place among
-    them (``moe.experts_apply``)."""
+    them; ``experts_kernel``: ``moe.experts_apply``'s ``kernel``."""
     lead, kind = h.shape[:-1], run.attn
     x = h if cfg.post_norm else rms_norm(h, p["ln1"], cfg.rms_eps)
     if kind.name == "conv":
@@ -942,7 +942,7 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
             top_k=cfg.experts_per_token, held=cfg.experts_held,
             live=None if live is None else live.reshape(-1),
             norm_eps=cfg.router_norm_eps, scale=cfg.routed_scaling,
-            layer=layer,
+            layer=layer, kernel=experts_kernel,
         )
         y = y.reshape(h.shape)
     else:
@@ -954,7 +954,7 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
 
 
 def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
-              live=None, shift=None, recur=None):
+              live=None, shift=None, recur=None, experts_kernel=None):
     """Every layer in the pattern's order, each run of one kind a
     ``lax.scan`` over the run's stacked weights: all of them but an
     expert run's ``moe.EXPERT_WEIGHTS``, which the scan's body closes over
@@ -967,8 +967,8 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
     token, cache)``, and for a delta layer also ``recur(layer, q, k, v, g,
     beta, cache) -> (the recurrence's output, cache)``: ``layer`` counts
     within what the kind keeps for a sequence (its class of KV page, its
-    state by slot). Returns (h, cache, the blocks' int32[2] counts
-    summed)."""
+    state by slot); ``experts_kernel``: ``moe.experts_apply``'s ``kernel``.
+    Returns (h, cache, the blocks' int32[2] counts summed)."""
     cfg.require_blocks_by_run(blocks)
     counts = jnp.zeros((2,), jnp.int32)
     for run in cfg.layer_runs():
@@ -1003,7 +1003,9 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
                 # `layer` began at the run's first layer in its class
                 p = {**p, "moe": {**p["moe"], **experts}}
                 in_run = layer - run.cache_start
-            h, cache, c = decoder_block(cfg, run, p, h, ang, mix, live, in_run)
+            h, cache, c = decoder_block(
+                cfg, run, p, h, ang, mix, live, in_run, experts_kernel
+            )
             return (h, cache, layer + 1, counts + c), None
 
         (h, cache, _, counts), _ = jax.lax.scan(

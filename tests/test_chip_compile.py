@@ -220,16 +220,27 @@ class _Deployment:
         ]
 
     def copies_of_experts(self, text):
-        """Fusions and copies of the optimised module that yield the
-        experts of a layer ``[held, D, F]`` or of a run ``[L, held, D, F]``:
-        as a scan's ``xs`` a layer's were written out every step
-        (``dynamic-slice_bitcast_fusion``, a third of both sparse cells'
-        device time; PERF.md section 6, PR 39)."""
+        """Operations of the optimised module that yield the experts of a
+        layer ``[held, D, F]`` or of a run ``[L, held, D, F]``, the stacks
+        themselves and views of them aside (a parameter, a bitcast, a
+        tuple's element): as a scan's ``xs`` a layer's were written out
+        every step (``dynamic-slice_bitcast_fusion``, a third of both sparse
+        cells' device time; PERF.md section 6)."""
         cfg = self.cfg
         held, d, f = cfg.experts_held[1], cfg.d_model, cfg.d_ff_expert
         return re.findall(
             rf"(\S+) = bf16\[(?:\d+,)?{held},(?:{d},{f}|{f},{d})\]\S* "
-            r"(?:copy|fusion)\(", text,
+            r"(?!bitcast\(|get-tuple-element\(|parameter\()([\w-]+)\(",
+            text,
+        )
+
+    @staticmethod
+    def grouped_matmuls(text):
+        """(Megablox ``gmm`` kernels, ``lax.ragged_dot``'s operations) in
+        the optimised module."""
+        return (
+            len(re.findall(r"^\s*%gmm[.\d]* = ", text, re.M)),
+            len(re.findall(r"%ragged-dot", text)),
         )
 
     def scans_of_decode_step(self):
@@ -352,9 +363,11 @@ def test_two_page_classes_and_held_experts_copy_no_pool(deployment, program):
     arrays and holds no copy of one: with keys stored 192 wide the chip's
     compiler gave both K pools another layout inside the program and copied
     each twice a run. The grouped expert matmul is a kernel that reads a
-    run's stack of expert weights where it lies: no fusion or copy yields a
+    run's stack of expert weights where it lies: no operation yields a
     layer's ``[16, 4096, 2048]`` (three of 256 MiB a layer of the five-layer
-    run, every step, call and chunk, at the parent)."""
+    run, every step, call and chunk, when a scan sliced the stack). In
+    ``decode_step`` it is Megablox ``gmm`` and no ``lax.ragged_dot`` is
+    left; the prefill programs keep ``ragged_dot``."""
     d = deployment("mixed")
     eng, table = d.eng, d.tables["full"].shape[1]
     assert (eng.max_prefill_tokens, eng.prefill_chunk) == (2048, 512)
@@ -376,7 +389,13 @@ def test_two_page_classes_and_held_experts_copy_no_pool(deployment, program):
     text = compiled.as_text()
     assert not d.copies_of_a_pool(text)
     assert not d.copies_of_experts(text)
-    assert KERNEL in text  # lax.ragged_dot: the grouped expert matmul
+    gmm, ragged = d.grouped_matmuls(text)
+    if program == "decode_step":
+        # gate, up and down of both expert runs (five windowed layers, one
+        # full), in both branches of the row budget (64 and 512 rows)
+        assert (gmm, ragged) == (3 * 2 * 2, 0)
+    else:  # prefill keeps lax.ragged_dot
+        assert gmm == 0 and ragged
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools)
     # temporaries: the rings' gathered tables of 64 slots; the first
@@ -404,8 +423,10 @@ def test_state_by_slot_and_heads_of_64_copy_no_pool_and_no_weights(
     ("slice shape along dimension 4 must be aligned to tiling (128), but is
     64"; the chip lays such a row out in 128 lanes either way). Every
     program aliases both pools and the state and copies none of them; the
-    decode step holds the paged-attention kernel and the grouped expert
-    matmul; and no run's and no layer's expert weights are copied: each run
+    decode step holds the paged-attention kernel and Megablox ``gmm`` for
+    every expert product, and no ``lax.ragged_dot``, which the prefill
+    programs keep; and no run's and no layer's expert weights are
+    copied: each run
     has a stack of its own, where a static slice of its kind's stack was a
     copy of ``[3, 32, 2048, 1792]`` a matrix a step, and the grouped matmul
     reads the stack in place, where the scan's slice of a layer was nine
@@ -434,9 +455,14 @@ def test_state_by_slot_and_heads_of_64_copy_no_pool_and_no_weights(
     assert not d.copies_of_a_pool(text)
     assert not re.findall(r"= bf16\[11,2,64,2048\]\S* copy\(", text)
     assert not d.copies_of_experts(text)
-    assert KERNEL in text  # lax.ragged_dot: the grouped expert matmul
+    gmm, ragged = d.grouped_matmuls(text)
     if program == "decode_step":
         assert "paged_attention_decode" in text
+        # gate, up and down of the six expert runs (1 + 3 layers, three
+        # times); every pair fits the one row budget
+        assert (gmm, ragged) == (3 * 6, 0)
+    else:  # prefill keeps lax.ragged_dot
+        assert gmm == 0 and ragged
     mem = compiled.memory_analysis()
     state = 2 * d.state["conv"].size
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
@@ -513,6 +539,16 @@ def test_a_matrix_of_state_a_head_and_groups_of_one_copy_no_state(
     rows = {"decode_step": 0, "prefill": 2976, "prefill_suffix": 736}[program]
     own = mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert own < rows * 100352 * 4 + 2**20
+
+
+@pytest.mark.parametrize("name", ["mistral", "internlm2", "olmo"])
+def test_a_dense_decode_step_holds_no_grouped_matmul(deployment, name):
+    """The four dense cells' ``decode_step`` (chat and rag share mistral's)
+    has no expert layer: neither Megablox ``gmm`` nor ``lax.ragged_dot``
+    is in it, whichever the engine would choose."""
+    d = deployment(name)
+    assert d.eng._moe_kernel == "compiled"
+    assert d.grouped_matmuls(d.decode_step().as_text()) == (0, 0)
 
 
 @pytest.mark.parametrize("name", ["mistral", "mixed", "lfm2"])

@@ -188,7 +188,8 @@ def test_decode_kernel_matches_gather_path(small, temperature):
 
 def test_no_caller_chooses_the_attention_path(small, monkeypatch):
     """The platform chooses: the kernel on a TPU, the XLA formulation
-    elsewhere; the constructor has no argument for it."""
+    elsewhere, for the decode attention and for the expert layers' grouped
+    matmuls alike; the constructor has no argument for either."""
     import inspect
 
     cfg, params = small
@@ -196,9 +197,11 @@ def test_no_caller_chooses_the_attention_path(small, monkeypatch):
     assert not [n for n in names if "pallas" in n or "kernel" in n or "attention" in n]
     with pytest.raises(TypeError):
         ContinuousBatchingEngine(cfg, params, use_pallas_attention=True)
-    assert ContinuousBatchingEngine(cfg, params)._attn_kernel is None
+    eng = ContinuousBatchingEngine(cfg, params)
+    assert (eng._attn_kernel, eng._moe_kernel) == (None, None)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert ContinuousBatchingEngine(cfg, params)._attn_kernel == "compiled"
+    eng = ContinuousBatchingEngine(cfg, params)
+    assert (eng._attn_kernel, eng._moe_kernel) == ("compiled", "compiled")
 
 
 def test_concurrent_callers_share_one_engine(small):

@@ -83,8 +83,8 @@ def make_engine(toy, on_tpu=False, **kw):
         if on_tpu:  # the pool's rows as the chip stores them
             m.setattr(jax, "default_backend", lambda: "tpu")
         eng = ContinuousBatchingEngine(toy[0], toy[1], **kw)
-    if on_tpu:
-        eng._attn_kernel = "interpret"
+    if on_tpu:  # both of decode's kernels, interpreted
+        eng._attn_kernel = eng._moe_kernel = "interpret"
     assert (eng.max_prefill_tokens, eng.prefill_chunk) == (16, 4)
     return eng
 
@@ -154,8 +154,9 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     chunk in the slot's row). Logits, not tokens: the prefill's at every
     prompt position; a decoded token by the reference's logit of it against
     the reference's best at that position. With ``on_tpu`` the pool is
-    built as on the chip, rows of 8 stored in whole tiles of 128, and the
-    full layers go through the Pallas kernel, interpreted."""
+    built as on the chip, rows of 8 stored in whole tiles of 128, the full
+    layers go through the Pallas kernel and the expert layers' decode
+    products through Megablox ``gmm``, both interpreted."""
     eng = make_engine(toy, on_tpu)
     assert (eng.pool.k_dim, eng.pool.v_dim) == ((128, 128) if on_tpu else (8, 8))
     assert eng.pool.state["conv"].shape == (11, 2, 3, 64)
@@ -366,6 +367,26 @@ def test_spans_carry_the_state_counts(toy):
         assert d["full_pages"] == d["pages_written"]
         assert d["attn_full_layers"] == 3 and "window_pages" not in d
         assert d["moe_pairs_held"] == d["live"] * 4 * 12
+
+
+@pytest.mark.parametrize("kernel", [None, "interpret"], ids=["cpu", "interpret"])
+def test_the_decode_span_counts_the_expert_layers_in_the_kernel(toy, kernel):
+    """``engine.decode`` ``moe_kernel_layers``: of the 12 expert layers,
+    those whose grouped matmuls ran in Megablox ``gmm``: all of them where
+    the engine runs the kernel (compiled on a TPU, interpreted here), none
+    on the CPU's ``lax.ragged_dot``. The tokens are the same either way."""
+    tracing.SPANS.clear()
+    eng = make_engine(toy)
+    assert eng._moe_kernel is None  # no TPU here
+    eng._moe_kernel = kernel
+    prompts = [list(range(1, 12)), [7, 8, 9]]
+    outs = eng.generate_ids(prompts, GenerationConfig(max_new_tokens=8))
+    decodes = [s["args"] for s in tracing.SPANS.slices(cat="engine")
+               if s["name"] == "engine.decode"]
+    assert decodes
+    assert {d["moe_kernel_layers"] for d in decodes} == {12 if kernel else 0}
+    for prompt, out in zip(prompts, outs):
+        assert gaps(toy, prompt, out).max() <= TOL
 
 
 class _Cache:

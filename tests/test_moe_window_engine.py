@@ -281,6 +281,51 @@ def test_a_layer_read_in_its_runs_stack_is_the_layer_alone(
             assert np.abs(np.asarray(got[0]) - np.asarray(other)).max() > 0.1
 
 
+@pytest.mark.parametrize("every", [False, True], ids=["usual", "every"])
+@pytest.mark.parametrize("held", [(8, 8), (0, 32)], ids=str)
+@pytest.mark.parametrize(
+    "n_layers, layer", [(1, 0), (3, 0), (3, 1), (3, 2)],
+    ids=["1of1", "1of3", "2of3", "3of3"],
+)
+def test_the_grouped_matmul_kernel_gives_what_ragged_dot_gives(
+    n_layers, layer, held, every
+):
+    """Megablox ``gmm`` (interpreted; ``kernel``), the decode step's
+    grouped matmul on a TPU, over a run's stack with the layer's index
+    traced: the output ``lax.ragged_dot`` gives over the same rows, groups
+    and stack within float32's order of summation, and both counts to the
+    unit, in both branches of the row budget, with tokens that do not count
+    and with a holder of a part of the router's width."""
+    run, y, live = _expert_run(held, n_layers, every)
+    kw = dict(top_k=4, held=held, live=live)
+    p = {**jax.tree.map(lambda a: a[layer], run),
+         **{k: run[k] for k in moe.EXPERT_WEIGHTS}}
+    got, want = (
+        jax.jit(lambda p, y, i, kernel=kernel: moe.experts_apply(
+            p, y, layer=i, kernel=kernel, **kw))(p, y, jnp.int32(layer))
+        for kernel in ("interpret", None)
+    )
+    np.testing.assert_allclose(
+        np.asarray(got[0]), np.asarray(want[0]), atol=1e-5, rtol=0)
+    assert np.abs(np.asarray(want[0])).max() > 0.1
+    assert (int(got[1]), int(got[2])) == (int(want[1]), int(want[2]))
+    assert (int(got[1]) == 44 * 4) == (every or held == (0, 32))
+
+
+@pytest.mark.parametrize(
+    "shape, tiles",
+    [((256, 2048, 1792), (128, 2048, 896)), ((256, 1792, 2048), (128, 1792, 1024)),
+     ((64, 4096, 2048), (64, 2048, 1024)), ((512, 2048, 4096), (128, 2048, 1024)),
+     ((200, 64, 32), (40, 64, 32)), ((15, 96, 200), (15, 96, 200))],
+    ids=["turns-in", "turns-out", "mixed-64", "mixed-512", "toy", "ragged"],
+)
+def test_the_kernels_tiles_come_from_the_products_shape(shape, tiles):
+    """Rows in tiles that divide them and are no longer than 128, the
+    contraction in pieces of up to 2,048, the columns of up to 1,024; what
+    no such piece divides is one tile."""
+    assert moe.gmm_tiles(*shape) == tiles
+
+
 # -- (c) the control fails the same comparison -------------------------------------
 
 
