@@ -27,7 +27,10 @@ rebuilt TPU-first in the JetStream/PagedAttention mold:
   columns of its own input (a gated short convolution), or such columns
   and a float32 matrix a head (the gated delta rule, a linear attention:
   one token a step in ``decode_step``, a scan over blocks of tokens in the
-  two prefill programs, ``models/transformer.py`` ``delta_scan``).
+  two prefill programs, ``models/transformer.py`` ``delta_scan``). A
+  parallel layer (Falcon-H1: a Mamba-2 mixer beside the attention) keeps
+  both: its attention's pages of the ``full`` class and the mixer's
+  columns and matrix a head by slot, written by the same programs.
 
 Reference files for parity intent: vllm paged attention + continuous
 batching scheduler; JetStream's slot/page design is the public TPU
@@ -166,8 +169,10 @@ STEP_STAMPS = 1 << 15
 
 # the slot's axis in each array of ``PagedKVPool.state``, and the array
 # that holds a kind's columns before its short convolution
-SLOT_AXIS = {"conv": 2, "delta_taps": 2, "delta_s": 1}
-TAPS_OF = {"conv": "conv", "delta": "delta_taps"}
+SLOT_AXIS = {"conv": 2, "delta_taps": 2, "delta_s": 1, "ssm_taps": 2, "ssm_s": 1}
+TAPS_OF = {"conv": "conv", "delta": "delta_taps", "ssm": "ssm_taps"}
+# the array that holds a recurrent kind's float32 matrix a head
+MATRIX_OF = {"delta": "delta_s", "ssm": "ssm_s"}
 
 
 def stored_width(size: int, kernel: bool = False) -> int:
@@ -215,7 +220,11 @@ class PagedKVPool:
     delta_width]``, the last columns of q, k and v before their short
     convolution, and ``state["delta_s"]`` ``[delta layers, max_batch,
     heads, key size, value size]`` in float32, the delta rule's matrix a
-    head (``SLOT_AXIS``: which axis of each is the slot's). It has no free
+    head; ``state["ssm_taps"]`` ``[parallel layers, conv_kernel - 1,
+    max_batch, ssm_width]`` and ``state["ssm_s"]`` ``[parallel layers,
+    max_batch, heads, state size, head size]`` in float32, the same of a
+    parallel layer's Mamba-2 mixer (``SLOT_AXIS``: which axis of each is
+    the slot's). It has no free
     list: a slot's state is its occupant's from the prefill that wrote it,
     nothing is reserved and nothing can run short."""
 
@@ -251,11 +260,20 @@ class PagedKVPool:
                 (kinds["delta"], max_batch, cfg.delta_heads,
                  cfg.delta_key_dim, cfg.delta_value_dim), jnp.float32,
             )
-        # bytes of state by slot one sequence holds, as declared
-        self.state_bytes_per_slot = sum(
-            a.nbytes // a.shape[SLOT_AXIS[name]]
+        if "ssm" in kinds:
+            self.state["ssm_taps"] = jnp.zeros(
+                (kinds["ssm"], taps, max_batch, cfg.ssm_width), cfg.dtype
+            )
+            self.state["ssm_s"] = jnp.zeros(
+                (kinds["ssm"], max_batch, cfg.ssm_heads, cfg.ssm_state,
+                 cfg.ssm_head_dim), jnp.float32,
+            )
+        # bytes of state by slot one sequence holds, as declared, by array
+        self.slot_bytes = {
+            name: a.nbytes // a.shape[SLOT_AXIS[name]]
             for name, a in self.state.items()
-        )
+        }
+        self.state_bytes_per_slot = sum(self.slot_bytes.values())
 
     @property
     def n_pages(self) -> int:
@@ -325,6 +343,10 @@ def _gather_pages(pool_k, pool_v, pages):
     small programs of its own (the index's bounds and wrap-around beside
     the gather), over half of the programs a replica's warm-up lowered."""
     return pool_k["full"][:, :, pages], pool_v["full"][:, :, pages]
+
+
+def _named(patterns) -> str:
+    return ", ".join(f'"{p}"' for p in patterns)
 
 
 def _locked(method):
@@ -491,8 +513,8 @@ class ContinuousBatchingEngine:
                 "the shared prefix cache holds pages of the `full` class "
                 "alone; a model with a window class of KV page "
                 "(`attn_pattern` \"window\") or with state by slot "
-                "(`attn_pattern` \"conv\", \"delta\") is served with "
-                "prefix_cache=False"
+                f"(`attn_pattern` {_named(cfg.state_patterns())}) is served "
+                "with prefix_cache=False"
             )
         configure_compile_cache()
         self.cfg = cfg
@@ -594,7 +616,7 @@ class ContinuousBatchingEngine:
     def _refuse_windowed(self, what: str) -> None:
         """For the paths that move a sequence as pages of the ``full``
         class: what else a sequence holds would be left behind."""
-        kinds = ", ".join(f'"{k}"' for k in self.cfg.state_kinds())
+        kinds = _named(self.cfg.state_patterns())
         for has, field in ((self.windowed, "a window class of KV page"),
                            (self.stateful, "state by slot (`attn_pattern` "
                                            f"{kinds})")):
@@ -648,14 +670,14 @@ class ContinuousBatchingEngine:
                 for name, a in state.items()
             }
 
-        def shift_sequence(kind, layer, s, cache, true_len):
+        def shift_sequence(state, layer, s, cache, true_len):
             """``run_stack``'s ``shift`` for ONE sequence's block of
             tokens. s: [1, T, D]; ``cache["rows"][...][layer]``: [taps,
             D], the columns before the block's first token. Leaves there
             the ``taps`` columns before position ``true_len`` of the block
             (of a padded block's real tokens, the last): what the padding
             holds is never state."""
-            name, t = TAPS_OF[kind.name], s.shape[1]
+            name, t = TAPS_OF[state], s.shape[1]
             rows = cache["rows"][name]
             ext = jnp.concatenate([rows[layer].astype(s.dtype), s[0]], 0)
             earlier = tuple(ext[j : j + t][None] for j in range(taps))
@@ -665,23 +687,28 @@ class ContinuousBatchingEngine:
             )
             return earlier, {**cache, "rows": {**cache["rows"], name: rows}}
 
-        def scan_sequence(layer, q, k, v, g, beta, cache, true_len):
+        def scan_sequence(state, layer, q, k, v, g, beta, cache, true_len):
             """``run_stack``'s ``recur`` for ONE sequence's block of
-            tokens: the delta rule as a scan over blocks of tokens
-            (``tfm.delta_scan``), from ``cache["rows"]["delta_s"][layer]``
+            tokens: the delta rule, or a parallel layer's diagonal
+            recurrence (``beta`` None), as a scan over blocks of tokens
+            (``tfm.delta_scan``), from ``cache["rows"][<its matrix>][layer]``
             [H, dk, dv], the state before the block's first token, to the
             state after its first ``true_len``, which is left there: at
-            and after ``true_len`` the gate is 1 and beta 0, so the
+            and after ``true_len`` the gate is 1 and beta (or v) 0, so the
             padding changes nothing. q, k, v: [1, T, H, size]; g, beta:
             [1, T, H]."""
-            rows = cache["rows"]["delta_s"]
+            name = MATRIX_OF[state]
+            rows = cache["rows"][name]
             real = (jnp.arange(g.shape[1]) < true_len)[:, None]
+            if beta is None:  # the diagonal recurrence: v = dt x, 0 past the end
+                v = jnp.where(real[..., None], v[0], 0.0)
+            else:
+                v, beta = v[0], jnp.where(real, beta[0], 0.0)
             o, end = tfm.delta_scan(
-                rows[layer], q[0], k[0], v[0], jnp.where(real, g[0], 0.0),
-                jnp.where(real, beta[0], 0.0),
+                rows[layer], q[0], k[0], v, jnp.where(real, g[0], 0.0), beta
             )
             rows = jax.lax.dynamic_update_index_in_dim(rows, end, layer, 0)
-            return o[None], {**cache, "rows": {**cache["rows"], "delta_s": rows}}
+            return o[None], {**cache, "rows": {**cache["rows"], name: rows}}
 
         def write_token(pool, layer, page_ids, offsets, active, x):
             """One token a slot at (page, offset), head-major. x: [B, KH,
@@ -766,7 +793,7 @@ class ContinuousBatchingEngine:
             the rows of it written, one a layer a live slot; with experts
             the expert layers run in ``gmm``), the pool and the state."""
             b = self.B
-            h = params["embed"][tokens].astype(cfg.dtype)  # [B, D]
+            h = tfm.embed(cfg, params, tokens)  # [B, D]
             # positions a slot's query sees, itself among them; none if idle
             lengths = jnp.where(active, positions + 1, 0)
             live_pages = jnp.sum(-(-lengths // page))
@@ -831,11 +858,11 @@ class ContinuousBatchingEngine:
                     "v": {**pool_v, name: pv}, "walked": walked,
                 }
 
-            def shift(kind, layer, s, cache):
+            def shift(state, layer, s, cache):
                 """Every slot's columns before its token, and the slot's
                 state moved on by one: an inactive slot keeps what its row
                 held, which no live slot reads."""
-                name = TAPS_OF[kind.name]
+                name = TAPS_OF[state]
                 conv = cache["state"][name]
                 held = conv[layer]  # [taps, B, D]
                 moved = jnp.concatenate(
@@ -849,16 +876,17 @@ class ContinuousBatchingEngine:
                     "stated": cache["stated"] + wrote,
                 }
 
-            def recur(layer, q, k, v, g, beta, cache):
+            def recur(state, layer, q, k, v, g, beta, cache):
                 """Every slot's ``S`` moved on by its token
                 (``tfm.delta_step``); an inactive slot keeps its own."""
-                every = cache["state"]["delta_s"]
+                name = MATRIX_OF[state]
+                every = cache["state"][name]
                 held = every[layer]  # [B, H, dk, dv]
                 o, moved = tfm.delta_step(held, q, k, v, g, beta)
                 moved = jnp.where(active[:, None, None, None], moved, held)
                 every = jax.lax.dynamic_update_index_in_dim(every, moved, layer, 0)
                 return o, {
-                    **cache, "state": {**cache["state"], "delta_s": every},
+                    **cache, "state": {**cache["state"], name: every},
                 }
 
             h, cache, moe = tfm.run_stack(
@@ -904,6 +932,13 @@ class ContinuousBatchingEngine:
                 pool, layer, table[at], x[x.shape[0] - pages * page :]
             )
 
+        def last_logits(params, h, true_len):
+            """The head over the one row the host reads, the last real
+            token's: float32[vocabulary]. Over every row of a prompt it
+            would be [t_pad, vocabulary] (2 GiB of float32 at 2,048 tokens
+            and 261,120 ids) for one row read."""
+            return tfm.head_logits(cfg, params, h[0, true_len - 1])
+
         @functools.partial(
             jax.jit, static_argnames=("t_pad",), donate_argnames=_POOL
         )
@@ -914,18 +949,21 @@ class ContinuousBatchingEngine:
             """Prefill ONE sequence of (padded) length t_pad from its
             first token; write its KV into the given pages and, of a model
             with state by slot, the state of its first ``true_len`` tokens
-            (the real ones; the host picks their last row of the logits)
-            into row ``slot``; return (logits at every position, int32[2]
-            expert counts). tokens: int32[t_pad]; page_ids by class:
-            ``full`` int32[t_pad // page], ``window`` the slot's ring."""
+            (the real ones) into row ``slot``; return (the logits of the
+            last real token, the one row the host reads, float32[vocab];
+            int32[2] expert counts). tokens: int32[t_pad]; page_ids by
+            class: ``full`` int32[t_pad // page], ``window`` the slot's
+            ring."""
             pos = jnp.arange(t_pad)
-            h = params["embed"][tokens][None].astype(cfg.dtype)  # [1,T,D]
+            h = tfm.embed(cfg, params, tokens)[None]  # [1,T,D]
 
-            def shift(kind, layer, s, cache):
-                return shift_sequence(kind, layer, s, cache, true_len)
+            def shift(state, layer, s, cache):
+                return shift_sequence(state, layer, s, cache, true_len)
 
-            def recur(layer, q, k, v, g, beta, cache):
-                return scan_sequence(layer, q, k, v, g, beta, cache, true_len)
+            def recur(state, layer, q, k, v, g, beta, cache):
+                return scan_sequence(
+                    state, layer, q, k, v, g, beta, cache, true_len
+                )
 
             def attend(kind, layer, q, k, v, sink, cache):
                 pool_k, pool_v = cache["k"], cache["v"]
@@ -984,7 +1022,7 @@ class ContinuousBatchingEngine:
                 shift=shift, recur=recur,
             )
             cache["state"] = put_slot_rows(state, slot, cache["rows"])
-            return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
+            return results((last_logits(params, h, true_len), moe), cache)
 
         @functools.partial(
             jax.jit, static_argnames=("t_pad",), donate_argnames=_POOL
@@ -1020,15 +1058,17 @@ class ContinuousBatchingEngine:
             int32[t_pad] padded suffix; table by class: ``full``
             int32[P_max], ``window`` the ring; suffix_page_ids:
             int32[t_pad // page] of the ``full`` class. Returns (logits
-            over suffix positions, int32[2] expert counts)."""
+            of the suffix's last real token, int32[2] expert counts)."""
             pos = hist_len + jnp.arange(t_pad)  # absolute positions
-            h = params["embed"][tokens][None].astype(cfg.dtype)
+            h = tfm.embed(cfg, params, tokens)[None]
 
-            def shift(kind, layer, s, cache):
-                return shift_sequence(kind, layer, s, cache, true_len)
+            def shift(state, layer, s, cache):
+                return shift_sequence(state, layer, s, cache, true_len)
 
-            def recur(layer, q, k, v, g, beta, cache):
-                return scan_sequence(layer, q, k, v, g, beta, cache, true_len)
+            def recur(state, layer, q, k, v, g, beta, cache):
+                return scan_sequence(
+                    state, layer, q, k, v, g, beta, cache, true_len
+                )
 
             def attend(kind, layer, q, k, v, sink, cache):
                 pool_k, pool_v = cache["k"], cache["v"]
@@ -1087,7 +1127,7 @@ class ContinuousBatchingEngine:
                 shift=shift, recur=recur,
             )
             cache["state"] = put_slot_rows(state, slot, cache["rows"])
-            return results((tfm.head_logits(cfg, params, h[0]), moe), cache)
+            return results((last_logits(params, h, true_len), moe), cache)
 
         self._decode_step = decode_step
         self._prefill = prefill
@@ -1359,11 +1399,13 @@ class ContinuousBatchingEngine:
                 # rows of state written: one a layer that keeps state a run
                 # of a program, the last run's at the prompt's true end
                 sp.set(state_written=self.cfg.state_layers * (1 + chunks))
-            delta_layers = self.cfg.state_kinds().get("delta", 0)
-            if delta_layers:
-                # blocks of the delta rule's chunked scan, a layer a run
+            kinds = self.cfg.state_kinds()
+            scan_layers = kinds.get("delta", 0) + kinds.get("ssm", 0)
+            if scan_layers:
+                # blocks of the chunked scan (the delta rule's, a parallel
+                # layer's Mamba-2 mixer's), a layer a run
                 block = tfm.DELTA_BLOCK
-                sp.set(scan_blocks=delta_layers * (
+                sp.set(scan_blocks=scan_layers * (
                     -(-head // block) + chunks * -(-chunk // block)
                 ))
             slot = np.int32(slot)
@@ -1375,17 +1417,10 @@ class ContinuousBatchingEngine:
             )
             pairs = [moe]
             if chunks:
-                # the last chunk's logits are the ones read: let the first
-                # program's [head, vocabulary] go before the chunks run
-                del logits
                 dev_tables = {
                     n: jnp.asarray(row) for n, row in tables.items()
                 }
             for at in range(head, t_pad, chunk):
-                # a run's [chunk, vocabulary] logits are allocated as it is
-                # dispatched: the host stays one run ahead of the device,
-                # not the prompt's nineteen
-                jax.block_until_ready(pairs[-2:-1])
                 logits, moe = self._prefill_chunk(
                     tokens[at : at + chunk], at, dev_tables, pages, slot,
                     min(t - at, chunk),
@@ -1394,7 +1429,7 @@ class ContinuousBatchingEngine:
             # read once the first token is (``_settle_prefill_counts``)
             self._prefill_counts = (sp, pairs)
         self.full_prefill_count += 1
-        return logits[(t - 1) - (t_pad - logits.shape[0])]
+        return logits
 
     def _settle_prefill_counts(self) -> None:
         """``moe_pairs_held`` of the newest ``engine.prefill`` span, read
@@ -1457,7 +1492,7 @@ class ContinuousBatchingEngine:
                 {n: jnp.asarray(row) for n, row in tables.items()},
                 {"full": pages}, np.int32(0), ts,
             )
-        return logits[ts - 1]
+        return logits
 
     def _prefix_insert(self, prompt, pages, covered: int) -> None:
         """Publish the prompt's FULL pages (already in the pool) to the
@@ -1760,10 +1795,16 @@ class ContinuousBatchingEngine:
                     if self.windowed or self.stateful:
                         decode.set(full_pages=sum(written))
                     if self.stateful:
-                        # each live slot's state read once and written once
+                        # each live slot's state read once and written once,
+                        # all of it and a parallel layer's Mamba-2 state's
                         decode.set(state_bytes=(
                             2 * len(live) * self.pool.state_bytes_per_slot
                         ))
+                        ssm = self.pool.slot_bytes.get("ssm_s")
+                        if ssm is not None:
+                            decode.set(ssm_state_bytes=2 * len(live) * (
+                                ssm + self.pool.slot_bytes["ssm_taps"]
+                            ))
                     if self.windowed:
                         decode.set(
                             window_pages=sum(

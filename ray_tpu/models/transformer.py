@@ -61,19 +61,25 @@ class AttnKind:
     page for the two kinds of attention; for ``conv`` (a gated short
     convolution, no K and V) a few columns of state by slot; for ``delta``
     (the gated delta rule, a linear attention) such columns and a matrix of
-    state a head."""
+    state a head. ``state`` names the state by slot a layer of the kind
+    keeps, "" for none: ``conv`` and ``delta`` keep it instead of pages;
+    ``ssm``, a Mamba-2 mixer run beside the attention over the same input
+    (Falcon-H1's parallel block, ``attn_pattern`` "parallel"), beside the
+    pages of the attention's class."""
     name: str            # "full" | "window" | "conv" | "delta"
     kv_heads: int
     rope_theta: float    # 0 = the layer's q and k are not rotated
     window: int          # 0 = every earlier key
     sink: bool           # a learned per-head column that takes mass only
+    state: str = ""      # "" | "conv" | "delta" | "ssm"
 
 
-CONV = AttnKind("conv", 0, 0.0, 0, False)
-DELTA = AttnKind("delta", 0, 0.0, 0, False)
+CONV = AttnKind("conv", 0, 0.0, 0, False, "conv")
+DELTA = AttnKind("delta", 0, 0.0, 0, False, "delta")
 # the kinds that are no attention: state by slot, no K and V, no rotary
 STATE_KINDS = {"conv": CONV, "delta": DELTA}
-# tokens a block of the delta rule's chunked scan holds (``delta_scan``)
+# tokens a block of the chunked scan holds (``delta_scan``: the delta rule's
+# and the Mamba-2 mixer's)
 DELTA_BLOCK = 64
 
 
@@ -83,12 +89,16 @@ class LayerRun:
     own stack of weights (``key`` in ``params["blocks"]``; ``None`` where
     the stack is uniform and ``blocks`` is the one stack), and where the
     run's first layer lies in what its kind keeps for a sequence (its
-    class of KV page, or the convolution layers' state)."""
+    class of KV page, or the convolution layers' state) and, of a kind
+    that keeps state by slot, in that state (``state_start``: where a
+    parallel layer's row of the Mamba-2 state lies; for ``conv`` and
+    ``delta`` it is ``cache_start``)."""
     attn: AttnKind
     experts: bool
     key: Optional[str]
     count: int
     cache_start: int
+    state_start: int = 0
 
     @property
     def start(self) -> int:
@@ -127,7 +137,7 @@ class ModelConfig:
     value_scale: float = 1.0    # values are scaled before attention
     # -- the stack by position. Empty patterns: every layer full attention
     # and a dense feed-forward, one uniform stack (the defaults above) ----
-    attn_pattern: Tuple[str, ...] = ()    # "full" | "window" | "conv" | "delta", one a layer
+    attn_pattern: Tuple[str, ...] = ()    # "full" | "window" | "conv" | "delta" | "parallel", one a layer
     ffn_pattern: Tuple[str, ...] = ()     # "dense" | "experts", one a layer
     window: int = 0                       # keys a windowed query sees, itself among them
     window_kv_heads: int = 0              # 0 = n_kv_heads
@@ -143,6 +153,22 @@ class ModelConfig:
     delta_key_dim: int = 0
     delta_value_dim: int = 0
     delta_neg_eigval: bool = False        # beta in (0, 2): the transition's eigenvalue 1 - beta in (-1, 1)
+    # -- "parallel" layers (``mamba2`` beside the attention): the Mamba-2
+    # mixer's heads, a head's size, the state's size, groups of B and C
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 0
+    # -- muP multipliers (Falcon-H1); 1 = none, and nothing is multiplied
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0           # on k before the rotary
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = ()   # z, x, B, C, dt of the mixer's input projection
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)   # gate before the SiLU; after W_down
     # -- expert layers (models/moe.py experts_apply): dropless top-k over
     # the published router width, this holder's experts computed ----------
     d_ff_expert: int = 0
@@ -158,6 +184,8 @@ class ModelConfig:
             "attn_pattern": tuple(self.attn_pattern),
             "ffn_pattern": tuple(self.ffn_pattern),
             "experts_held": tuple(self.experts_held),
+            "ssm_multipliers": tuple(self.ssm_multipliers),
+            "mlp_multipliers": tuple(self.mlp_multipliers),
         }
         derived["v_head_dim"] = self.v_head_dim or derived["head_dim"]
         derived["rotary_dim"] = self.rotary_dim or derived["head_dim"]
@@ -165,7 +193,8 @@ class ModelConfig:
             derived["experts_held"] = (0, self.n_routed_experts)
         for name, value in derived.items():
             object.__setattr__(self, name, value)
-        for name, allowed in (("attn_pattern", ("full", "window", *STATE_KINDS)),
+        for name, allowed in (("attn_pattern", ("full", "window", "parallel",
+                                                *STATE_KINDS)),
                               ("ffn_pattern", ("dense", "experts"))):
             pattern = derived[name]
             if pattern and (
@@ -177,10 +206,22 @@ class ModelConfig:
                 )
         if "window" in derived["attn_pattern"] and self.window < 1:
             raise ValueError("a windowed layer needs `window` >= 1")
-        if set(STATE_KINDS) & set(derived["attn_pattern"]) and self.conv_kernel < 2:
+        if {*STATE_KINDS, "parallel"} & set(derived["attn_pattern"]) and (
+            self.conv_kernel < 2
+        ):
             raise ValueError(
-                "a convolution or delta layer needs `conv_kernel` >= 2"
+                "a convolution, delta or parallel layer needs `conv_kernel` >= 2"
             )
+        if "parallel" in derived["attn_pattern"] and not (
+            self.ssm_heads > 0 and self.ssm_head_dim > 0 and self.ssm_state > 0
+            and self.ssm_groups > 0 and self.ssm_heads % self.ssm_groups == 0
+        ):
+            raise ValueError(
+                "a parallel layer needs ssm_heads, ssm_head_dim, ssm_state "
+                "and ssm_groups, the groups dividing the heads"
+            )
+        if derived["ssm_multipliers"] and len(derived["ssm_multipliers"]) != 5:
+            raise ValueError("ssm_multipliers gives z, x, B, C and dt one each")
         if "delta" in derived["attn_pattern"] and not (
             self.delta_heads > 0 and self.delta_key_dim > 0
             and self.delta_value_dim > 0
@@ -222,7 +263,10 @@ class ModelConfig:
                 self.window_rope_theta or self.rope_theta, self.window,
                 self.window_sink,
             )
-        return AttnKind("full", self.n_kv_heads, self.rope_theta, 0, False)
+        return AttnKind(
+            "full", self.n_kv_heads, self.rope_theta, 0, False,
+            "ssm" if name == "parallel" else "",
+        )
 
     def layer_kinds(self) -> Tuple[Tuple[str, str], ...]:
         """(attention kind, feed-forward kind) of each layer."""
@@ -239,9 +283,10 @@ class ModelConfig:
         a whole buffer. What the scan hands its body is a slice too, and
         an expert run's three large stacks were copied layer by layer for
         the grouped matmul: ``run_stack`` keeps those out of the scan.)"""
-        runs, last_kind, of_kind, in_class = [], None, {}, {}
+        runs, last_kind, of_kind, in_class, in_state = [], None, {}, {}, {}
         for kind in self.layer_kinds():
             attn, ffn = kind
+            mixer = self.attn_kind(attn)
             if runs and kind == last_kind:
                 runs[-1] = dataclasses.replace(runs[-1], count=runs[-1].count + 1)
             else:
@@ -249,11 +294,13 @@ class ModelConfig:
                 of_kind[kind] = n + 1
                 key = f"{attn}.{ffn}" + (f".{n}" if n else "")
                 runs.append(LayerRun(
-                    self.attn_kind(attn), ffn == "experts",
-                    None if self.uniform else key, 1, in_class.get(attn, 0),
+                    mixer, ffn == "experts", None if self.uniform else key, 1,
+                    in_class.get(mixer.name, 0), in_state.get(mixer.state, 0),
                 ))
             last_kind = kind
-            in_class[attn] = in_class.get(attn, 0) + 1
+            in_class[mixer.name] = in_class.get(mixer.name, 0) + 1
+            if mixer.state:
+                in_state[mixer.state] = in_state.get(mixer.state, 0) + 1
         return tuple(runs)
 
     def require_blocks_by_run(self, blocks) -> None:
@@ -278,32 +325,53 @@ class ModelConfig:
     def kv_classes(self) -> Dict[str, Tuple[int, AttnKind]]:
         """Classes of KV page, by the attention kind that writes them:
         name -> (layers of that kind, the kind). A convolution or delta
-        layer writes none."""
+        layer writes none; a parallel layer writes the ``full`` class."""
         counts: Dict[str, int] = {}
         for attn, _ in self.layer_kinds():
             if attn not in STATE_KINDS:
-                counts[attn] = counts.get(attn, 0) + 1
-        return {n: (c, self.attn_kind(n)) for n, c in sorted(counts.items())}
+                name = self.attn_kind(attn).name
+                counts[name] = counts.get(name, 0) + 1
+        return {
+            n: (c, self.attn_kind(n)) for n, c in sorted(counts.items())
+        }
 
     def state_kinds(self) -> Dict[str, int]:
-        """Kinds of layer that keep state by slot and no pages, by name ->
-        layers of that kind: ``conv``, ``delta``."""
+        """The state by slot the layers keep, by name -> layers that keep
+        it: ``conv``, ``delta`` (instead of pages), ``ssm`` (a parallel
+        layer's, beside its pages)."""
         counts: Dict[str, int] = {}
         for attn, _ in self.layer_kinds():
-            if attn in STATE_KINDS:
-                counts[attn] = counts.get(attn, 0) + 1
+            state = self.attn_kind(attn).state
+            if state:
+                counts[state] = counts.get(state, 0) + 1
         return dict(sorted(counts.items()))
 
     @property
     def state_layers(self) -> int:
-        """Layers that keep state by slot and no pages."""
+        """Layers that keep state by slot."""
         return sum(self.state_kinds().values())
+
+    def state_patterns(self) -> Tuple[str, ...]:
+        """The ``attn_pattern`` entries whose layers keep state by slot."""
+        return tuple(sorted({
+            a for a, _ in self.layer_kinds() if self.attn_kind(a).state
+        }))
 
     @property
     def delta_width(self) -> int:
         """Channels of a delta layer's short convolution: q, k and v of
         every head side by side."""
         return self.delta_heads * (2 * self.delta_key_dim + self.delta_value_dim)
+
+    @property
+    def ssm_inner(self) -> int:
+        """The Mamba-2 mixer's x and z: heads x a head's size."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_width(self) -> int:
+        """Channels of the Mamba-2 mixer's short convolution: x, B, C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     def require_uniform_dense(self, path: str) -> None:
         """For the paths that run the one uniform block with heads of one
@@ -320,7 +388,10 @@ class ModelConfig:
         for name, plain in (("head_dim", derived), ("v_head_dim", derived),
                             ("rotary_dim", derived), ("value_scale", 1.0),
                             ("qk_norm", False), ("tie_embeddings", False),
-                            ("post_norm", False)):
+                            ("post_norm", False),
+                            ("ssm_multipliers", ()),
+                            ("mlp_multipliers", (1.0, 1.0)),
+                            *((m, 1.0) for m in MULTIPLIERS)):
             if getattr(self, name) != plain:
                 raise UnsupportedModelFeature(
                     f"{path} does not implement `{name}`="
@@ -331,6 +402,15 @@ class ModelConfig:
                 f"{path} always rotates q and k; `rope_theta`=0 (no rotary) "
                 "is not implemented there"
             )
+
+
+# the muP multipliers that are one number each (``ModelConfig``): what the
+# train step refuses where one is not 1
+MULTIPLIERS = (
+    "embedding_multiplier", "lm_head_multiplier", "attention_in_multiplier",
+    "attention_out_multiplier", "key_multiplier", "ssm_in_multiplier",
+    "ssm_out_multiplier",
+)
 
 
 def _dense_init(key, *shape, dtype, scale=None):
@@ -415,6 +495,8 @@ def _init_params_by_run(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
             p["wk"] = dense(k[1], n, d, kind.kv_heads * cfg.head_dim)
             p["wv"] = dense(k[2], n, d, kind.kv_heads * cfg.v_head_dim)
             p["wo"] = dense(k[3], n, cfg.n_heads * cfg.v_head_dim, d)
+            if kind.state == "ssm":
+                p.update(_init_mamba2(cfg, n, k[9]))
             if cfg.qk_norm:
                 q_row = cfg.n_heads if cfg.qk_norm_whole else 1
                 k_row = kind.kv_heads if cfg.qk_norm_whole else 1
@@ -440,6 +522,31 @@ def _init_params_by_run(cfg: ModelConfig, key: jax.Array) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         params["head"] = dense(k[1], d, cfg.vocab_size)
     return params
+
+
+def _init_mamba2(cfg: ModelConfig, n: int, key: jax.Array) -> Dict[str, Any]:
+    """A parallel layer's Mamba-2 mixer (``mamba2``), ``n`` layers:
+    ``ssm_in`` (d -> z, x, B, C and dt side by side), the short convolution's
+    taps (the oldest first) and bias over x, B, C, ``A_log``, ``dt_bias`` and
+    ``D`` a head (float32), the gated norm's scale, ``ssm_out``."""
+    d, dt, heads = cfg.d_model, cfg.dtype, cfg.ssm_heads
+    k = jax.random.split(key, 6)
+    dense = functools.partial(_dense_init, dtype=dt)
+    # Mamba-2's: A in (1, 16), dt in [0.001, 0.1], log-uniform
+    step = jnp.exp(jax.random.uniform(
+        k[3], (n, heads), jnp.float32, jnp.log(1e-3), jnp.log(0.1)))
+    return {
+        "ssm_in": dense(k[0], n, d, 2 * cfg.ssm_inner
+                        + 2 * cfg.ssm_groups * cfg.ssm_state + heads),
+        "ssm_conv": dense(k[1], n, cfg.conv_kernel, cfg.ssm_width),
+        "ssm_conv_bias": jnp.zeros((n, cfg.ssm_width), dt),
+        "ssm_a_log": jnp.log(jax.random.uniform(
+            k[2], (n, heads), jnp.float32, 1.0, 16.0)),
+        "ssm_dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "ssm_d": jnp.ones((n, heads), jnp.float32),
+        "ssm_norm": jnp.ones((n, cfg.ssm_inner), dt),
+        "ssm_out": dense(k[4], n, cfg.ssm_inner, d),
+    }
 
 
 def param_specs(cfg: ModelConfig, pp: int = 1) -> Dict[str, Any]:
@@ -695,14 +802,22 @@ def rotate(x: jax.Array, ang: jax.Array) -> jax.Array:
     return jnp.concatenate([out, x[..., r:]], -1)
 
 
+def embed(cfg: ModelConfig, params, tokens: jax.Array) -> jax.Array:
+    """The embedding's rows of ``tokens``, times ``embedding_multiplier``."""
+    h = params["embed"][tokens].astype(cfg.dtype)
+    return h * cfg.embedding_multiplier
+
+
 def head_logits(cfg: ModelConfig, params, h: jax.Array) -> jax.Array:
-    """Final norm and the head: float32 logits over the vocabulary. A
-    model with ``tie_embeddings`` has no ``head``: the embedding is read
-    the other way."""
+    """Final norm and the head: float32 logits over the vocabulary, times
+    ``lm_head_multiplier``. A model with ``tie_embeddings`` has no
+    ``head``: the embedding is read the other way."""
     h = rms_norm(h, params["ln_f"], cfg.rms_eps)
     if cfg.tie_embeddings:
-        return jnp.einsum("...d,vd->...v", h, params["embed"]).astype(jnp.float32)
-    return (h @ params["head"]).astype(jnp.float32)
+        logits = jnp.einsum("...d,vd->...v", h, params["embed"])
+    else:
+        logits = h @ params["head"]
+    return logits.astype(jnp.float32) * cfg.lm_head_multiplier
 
 
 def _tap_sum(taps, earlier, s):
@@ -769,35 +884,41 @@ def _unit_lower_inverse(a: jax.Array) -> jax.Array:
     return inv
 
 
-def delta_step(state, q, k, v, g, beta):
+def delta_step(state, q, k, v, g, beta=None):
     """The gated delta rule moved on by one token, every head of every
     sequence alike, in float32: ``S' = exp(g) S``; ``u = beta (v - S'^T
     k)``; ``S = S' + k u^T``; ``o = S^T q``. state: [..., dk, dv]; q, k:
     [..., dk]; v: [..., dv]; g, beta: [...]. Returns (o [..., dv], S).
-    Products and sums over one axis, no matrix unit: nothing is rounded."""
+    With ``beta`` None the transition is diagonal, with no correction by
+    what ``S'`` already holds (Mamba-2's: ``u = v``). Products and sums
+    over one axis, no matrix unit: nothing is rounded."""
     state = state * jnp.exp(g)[..., None, None]
-    u = beta[..., None] * (v - jnp.sum(k[..., None] * state, -2))
-    state = state + k[..., None] * u[..., None, :]
+    if beta is not None:
+        v = beta[..., None] * (v - jnp.sum(k[..., None] * state, -2))
+    state = state + k[..., None] * v[..., None, :]
     return jnp.sum(q[..., None] * state, -2), state
 
 
-def delta_scan(state, q, k, v, g, beta, block: int = 0):
+def delta_scan(state, q, k, v, g, beta=None, block: int = 0):
     """The same recurrence over T tokens of one sequence as a scan over
     blocks of ``block`` tokens (0 = ``DELTA_BLOCK``; a power of two; the
-    delta rule's chunked form, Yang et al. 2024, with the gate's decay): inside a block products of block-sized
-    matrices, from block to block the carried ``S``. state: [H, dk, dv]
-    float32, the state before the first token; q, k: [T, H, dk]; v: [T, H,
-    dv]; g, beta: [T, H]; all float32. Returns (o [T, H, dv], the state
-    after the last token). A token with g = 0 and beta = 0 changes
-    nothing, which is how the caller ends the sequence before T and how a
-    ragged last block is filled here.
+    delta rule's chunked form, Yang et al. 2024, with the gate's decay):
+    inside a block products of block-sized matrices, from block to block the
+    carried ``S``. state: [H, dk, dv] float32, the state before the first
+    token; q, k: [T, H, dk]; v: [T, H, dv]; g, beta: [T, H]; all float32.
+    Returns (o [T, H, dv], the state after the last token). A token with g
+    = 0 and beta = 0 changes nothing, which is how the caller ends the
+    sequence before T and how a ragged last block is filled here.
 
     With ``c_t`` the sum of g over the block's tokens up to t and ``D[t,
     i] = exp(c_t - c_i)`` for i <= t: ``u`` solves ``(I + A) U = beta (V -
     exp(c) K S_0)``, ``A[t, i] = beta_t D[t, i] k_t.k_i`` below the
     diagonal; ``O = exp(c) Q S_0 + ((Q K^T) * D) U``; ``S_end = exp(c_C)
     S_0 + (K * D[C, :])^T U``. What does not hold ``S_0`` is computed for
-    all blocks at once."""
+    all blocks at once. With ``beta`` None the transition is diagonal
+    (``delta_step``'s), ``U = V`` and there is nothing to solve: Mamba-2's
+    state-space dual form (Dao and Gu 2024), where q, k and v are C, B and
+    dt x and a token with g = 0 and v = 0 changes nothing."""
     block = block or DELTA_BLOCK
     t, heads = g.shape
     n = -(-t // block)
@@ -807,23 +928,27 @@ def delta_scan(state, q, k, v, g, beta, block: int = 0):
         x = jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
         return jnp.moveaxis(x.reshape(n, block, *x.shape[1:]), 1, 2)
 
-    q, k, v, g, beta = map(blocks, (q, k, v, g, beta))
+    q, k, v, g = map(blocks, (q, k, v, g))
     c = jnp.cumsum(g, -1)  # [n, H, C]
     at = jnp.arange(block)
     upto = at[:, None] >= at[None, :]
     # exp of a masked difference: above the diagonal it would overflow
     decay = jnp.exp(jnp.where(upto, c[..., :, None] - c[..., None, :], -jnp.inf))
-    kk = jnp.einsum("nhtd,nhid->nhti", k, k, precision=_EXACT)
-    a = jnp.where(at[:, None] > at[None, :], beta[..., None] * decay * kk, 0.0)
-    solve = _unit_lower_inverse(a)  # [n, H, C, C]
-    u_free = jnp.einsum(
-        "nhti,nhid->nhtd", solve, beta[..., None] * v, precision=_EXACT
-    )
     since = jnp.exp(c)  # the decay from the block's start to each token
-    w = jnp.einsum(
-        "nhti,nhid->nhtd", solve, (beta * since)[..., None] * k,
-        precision=_EXACT,
-    )
+    if beta is None:
+        u_free, w = v, None
+    else:
+        beta = blocks(beta)
+        kk = jnp.einsum("nhtd,nhid->nhti", k, k, precision=_EXACT)
+        a = jnp.where(at[:, None] > at[None, :], beta[..., None] * decay * kk, 0.0)
+        solve = _unit_lower_inverse(a)  # [n, H, C, C]
+        u_free = jnp.einsum(
+            "nhti,nhid->nhtd", solve, beta[..., None] * v, precision=_EXACT
+        )
+        w = jnp.einsum(
+            "nhti,nhid->nhtd", solve, (beta * since)[..., None] * k,
+            precision=_EXACT,
+        )
     qk = decay * jnp.einsum("nhtd,nhid->nhti", q, k, precision=_EXACT)
     q_in = since[..., None] * q
     end = since[..., -1]  # [n, H]
@@ -831,7 +956,9 @@ def delta_scan(state, q, k, v, g, beta, block: int = 0):
 
     def one(state, xs):
         u_free, w, qk, q_in, k_out, end = xs
-        u = u_free - jnp.einsum("htk,hkv->htv", w, state, precision=_EXACT)
+        u = u_free
+        if w is not None:
+            u = u - jnp.einsum("htk,hkv->htv", w, state, precision=_EXACT)
         o = jnp.einsum("htk,hkv->htv", q_in, state, precision=_EXACT) + (
             jnp.einsum("hti,hiv->htv", qk, u, precision=_EXACT)
         )
@@ -885,6 +1012,70 @@ def gated_delta(cfg: ModelConfig, p, x, shift, recur):
     return (o.reshape(*lead, -1).astype(cfg.dtype) * gate) @ p["wo"], cache
 
 
+def mamba2(cfg: ModelConfig, p, x, shift, recur):
+    """The Mamba-2 mixer of a ``parallel`` layer (Dao and Gu 2024, as
+    Falcon-H1 runs it): ``[z, x, B, C, dt] = (x W_in) * mu`` (``ssm_in``,
+    ``mu`` constant on each of the five segments, ``ssm_multipliers``);
+    ``x``, ``B``, ``C`` through a causal depthwise convolution over the last
+    ``conv_kernel`` tokens with a bias (``ssm_conv``, the oldest tap first;
+    ``ssm_conv_bias``) and SiLU; ``dt = softplus(dt + dt_bias)`` and ``A =
+    -exp(A_log)`` a head; head ``i`` reads group ``i // (heads / groups)``
+    of B and C; per head, in float32, ``S_t = exp(dt_t A) S_{t-1} + B_t
+    (dt_t x_t)^T`` and ``y_t = S_t^T C_t + D x_t``; then ``y * silu(z)``
+    RMS-normed over each group of ``ssm_inner / groups`` channels with one
+    scale of ``ssm_inner`` (the gate before the norm), through ``ssm_out``.
+    ``shift(s)`` is ``gated_conv``'s, over x, B and C as the projection
+    gives them (in the served type); ``recur(q, k, v, g, beta, cache)`` is
+    ``gated_delta``'s with ``q = C``, ``k = B``, ``v = dt x``, ``g = dt A``
+    and ``beta`` None: the diagonal transition of ``delta_step``."""
+    lead, f32 = x.shape[:-1], jnp.float32
+    heads, size, n, groups = (
+        cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    )
+    inner = cfg.ssm_inner
+    proj = x @ p["ssm_in"]
+    if cfg.ssm_multipliers:
+        widths = (inner, inner, groups * n, groups * n, heads)
+        proj = proj * jnp.concatenate([
+            jnp.full((w,), m, proj.dtype)
+            for w, m in zip(widths, cfg.ssm_multipliers)
+        ])
+    z, xbc, dt = jnp.split(proj, [inner, inner + cfg.ssm_width], axis=-1)
+    earlier, cache = shift(xbc)
+    xbc = jax.nn.silu(
+        _tap_sum(p["ssm_conv"], earlier, xbc) + p["ssm_conv_bias"].astype(f32)
+    )
+    xs, b, c = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
+    xs = xs.reshape(*lead, heads, size)
+
+    def by_head(y):  # [..., G * N] -> [..., H, N]: head i reads group i // (H / G)
+        y = y.reshape(*lead, groups, 1, n)
+        return jnp.broadcast_to(
+            y, (*lead, groups, heads // groups, n)
+        ).reshape(*lead, heads, n)
+
+    dt = jax.nn.softplus(dt.astype(f32) + p["ssm_dt_bias"].astype(f32))
+    a = -jnp.exp(p["ssm_a_log"].astype(f32))
+    y, cache = recur(by_head(c), by_head(b), dt[..., None] * xs, dt * a, None, cache)
+    y = y + p["ssm_d"].astype(f32)[:, None] * xs
+    y = gated_group_norm(cfg, y.reshape(*lead, inner), z, p["ssm_norm"])
+    return y.astype(cfg.dtype) @ p["ssm_out"], cache
+
+
+def gated_group_norm(cfg: ModelConfig, y, z, scale):
+    """Mamba-2's gated norm with the gate first (Falcon-H1's
+    ``mamba_norm_before_gate`` false): ``y * silu(z)``, RMS-normed over
+    each of the ``ssm_groups`` groups of channels, times one ``scale`` of
+    all of them; float32."""
+    f32, groups = jnp.float32, cfg.ssm_groups
+    y = y * jax.nn.silu(z.astype(f32))
+    y = rms_norm(
+        y.reshape(*y.shape[:-1], groups, -1),
+        scale.astype(f32).reshape(groups, -1), cfg.rms_eps,
+    )
+    return y.reshape(*y.shape[:-2], -1)
+
+
 def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
                   live=None, layer=None, experts_kernel=None):
     """One layer of the kind ``run`` names: norm, the token mixer through
@@ -898,7 +1089,12 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
     and v where the caller keeps them and returns (float32 [..., H *
     v_head_dim], the caller's cache). A convolution layer: ``gated_conv``
     with ``mix`` as its ``shift``; a delta layer: ``gated_delta`` with
-    ``mix`` as its (``shift``, ``recur``). Returns (h, that cache,
+    ``mix`` as its (``shift``, ``recur``). A parallel layer (Falcon-H1):
+    ``mix`` is (the attention's ``mix``, ``shift(s, cache)``, ``recur``),
+    and the operator is ``attn(x * attention_in_multiplier) *
+    attention_out_multiplier + mamba2(x * ssm_in_multiplier) *
+    ssm_out_multiplier`` over the one normed ``x``, k times
+    ``key_multiplier`` before the rotary. Returns (h, that cache,
     int32[2]: token-expert pairs this holder computed and held experts
     hit; zeros in a dense layer). ``live``: bool over the leading dims,
     tokens whose choice of expert counts. ``layer``: where ``p["moe"]``
@@ -911,10 +1107,13 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
     elif kind.name == "delta":
         op, cache = gated_delta(cfg, p, x, *mix)
     else:
+        attend = mix[0] if kind.state else mix
+        xa = x * cfg.attention_in_multiplier
+
         def project(w, heads, size, norm=None):
             """x W as heads; q and k normed over the whole row before the
             cut into heads, or over each head after it."""
-            y = x @ p[w]
+            y = xa @ p[w]
             norm = norm if cfg.qk_norm else None
             if norm and cfg.qk_norm_whole:
                 y = rms_norm(y, p[norm], cfg.rms_eps)
@@ -926,12 +1125,20 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
         q = project("wq", cfg.n_heads, cfg.head_dim, "q_norm")
         k = project("wk", kind.kv_heads, cfg.head_dim, "k_norm")
         v = project("wv", kind.kv_heads, cfg.v_head_dim)
+        k = k * cfg.key_multiplier
         if cfg.value_scale != 1.0:
             v = v * cfg.value_scale
         if ang is not None:
             q, k = rotate(q, ang), rotate(k, ang)
-        attn, cache = mix(q, k, v, p.get("sink"))
+        attn, cache = attend(q, k, v, p.get("sink"))
         op = attn.astype(cfg.dtype) @ p["wo"]
+        if kind.state == "ssm":
+            _, shift, recur = mix
+            ssm, cache = mamba2(
+                cfg, p, x * cfg.ssm_in_multiplier, functools.partial(shift, cache=cache), recur
+            )
+            op = (op * cfg.attention_out_multiplier
+                  + ssm * cfg.ssm_out_multiplier)
     if cfg.post_norm:
         op = rms_norm(op, p["ln1"], cfg.rms_eps)
     h = h + op
@@ -946,7 +1153,9 @@ def decoder_block(cfg: ModelConfig, run: LayerRun, p, h, ang, mix,
         )
         y = y.reshape(h.shape)
     else:
-        y = swiglu(x2, p["w_gate"], p["w_up"], p["w_down"])
+        y = swiglu(
+            x2, p["w_gate"], p["w_up"], p["w_down"], *cfg.mlp_multipliers
+        )
     if cfg.post_norm:
         y = rms_norm(y, p["ln2"], cfg.rms_eps)
     counts = jnp.stack([pairs, hit]) if run.experts else jnp.zeros((2,), jnp.int32)
@@ -962,12 +1171,13 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
     run (as the scan's ``xs`` a layer's ``[held, D, F]`` was copied out of
     the stack every step, before the grouped matmul read it).
     ``positions``: int32 of h's leading dims. ``attend(kind, layer, q, k,
-    v, sink, cache) -> (attention, cache)``; for a convolution or a delta
-    layer ``shift(kind, layer, s, cache) -> (the columns before each
-    token, cache)``, and for a delta layer also ``recur(layer, q, k, v, g,
-    beta, cache) -> (the recurrence's output, cache)``: ``layer`` counts
-    within what the kind keeps for a sequence (its class of KV page, its
-    state by slot); ``experts_kernel``: ``moe.experts_apply``'s ``kernel``.
+    v, sink, cache) -> (attention, cache)``, ``layer`` counting within the
+    kind's class of KV page; for a layer that keeps state by slot
+    ``shift(state, layer, s, cache) -> (the columns before each token,
+    cache)`` and, for ``delta`` and ``ssm``, ``recur(state, layer, q, k, v,
+    g, beta, cache) -> (the recurrence's output, cache)``, ``state`` being
+    the kind's ``AttnKind.state`` and ``layer`` counting within that state
+    by slot; ``experts_kernel``: ``moe.experts_apply``'s ``kernel``.
     Returns (h, cache, the blocks' int32[2] counts summed)."""
     cfg.require_blocks_by_run(blocks)
     counts = jnp.zeros((2,), jnp.int32)
@@ -979,25 +1189,35 @@ def run_stack(cfg: ModelConfig, blocks, h, positions, cache, attend,
             stack = {**stack, "moe": {
                 k: v for k, v in stack["moe"].items() if k not in experts
             }}
-        stateful = run.attn.name in STATE_KINDS
         ang = None
-        if not stateful and run.attn.rope_theta:
+        if run.attn.name not in STATE_KINDS and run.attn.rope_theta:
             ang = rope_freqs(
                 cfg.rotary_dim, cfg.max_seq_len, run.attn.rope_theta
             )[positions]
 
-        def body(carry, p, run=run, ang=ang, stateful=stateful,
-                 experts=experts):
+        def body(carry, p, run=run, ang=ang, experts=experts):
             h, cache, layer, counts = carry
-            if stateful:
-                def mix(s):
-                    return shift(run.attn, layer, s, cache)
+            kind = run.attn
+            # the layer's row in its state by slot
+            in_state = layer - run.cache_start + run.state_start
 
-                if run.attn.name == "delta":
-                    mix = (mix, functools.partial(recur, layer))
+            def attend_mix(q, k, v, sink):
+                return attend(kind, layer, q, k, v, sink, cache)
+
+            def shift_mix(s, cache=cache):
+                return shift(kind.state, in_state, s, cache)
+
+            def recur_mix(*args):
+                return recur(kind.state, in_state, *args)
+
+            if kind.name == "conv":
+                mix = shift_mix
+            elif kind.name == "delta":
+                mix = (shift_mix, recur_mix)
+            elif kind.state:
+                mix = (attend_mix, shift_mix, recur_mix)
             else:
-                def mix(q, k, v, sink):
-                    return attend(run.attn, layer, q, k, v, sink, cache)
+                mix = attend_mix
             in_run = None
             if run.experts:
                 # `layer` began at the run's first layer in its class

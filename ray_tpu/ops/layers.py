@@ -39,9 +39,12 @@ def apply_rope(x: jax.Array, angles: jax.Array) -> jax.Array:
     return out.astype(dtype)
 
 
-def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array):
-    g = jax.nn.silu(x @ w_gate)
-    return (g * (x @ w_up)) @ w_down
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array,
+           gate_scale: float = 1.0, down_scale: float = 1.0):
+    """SwiGLU; ``gate_scale`` scales the gate's projection before its SiLU
+    and ``down_scale`` the result (Falcon-H1's ``mlp_multipliers``)."""
+    g = jax.nn.silu((x @ w_gate) * gate_scale)
+    return ((g * (x @ w_up)) @ w_down) * down_scale
 
 
 def attention_reference(

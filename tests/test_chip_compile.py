@@ -131,6 +131,23 @@ def _model(name):
             post_norm=True, conv_kernel=4, delta_heads=30, delta_key_dim=96,
             delta_value_dim=192, delta_neg_eigval=True,
         ), 16, 4096
+    if name == "falcon":  # 6 parallel layers: attention of 20 heads on 4 KV
+        # heads x 128 (groups of 5), table 256, and beside it the Mamba-2
+        # mixer's float32 matrix a head by slot; a vocabulary of 261,120
+        return tfm.ModelConfig(
+            vocab_size=261120, d_model=5120, n_layers=6, n_heads=20,
+            n_kv_heads=4, head_dim=128, d_ff=21504, max_seq_len=4096,
+            rope_theta=1e11, dtype=jnp.bfloat16, rms_eps=1e-5,
+            attn_pattern=("parallel",) * 6, ffn_pattern=("dense",) * 6,
+            conv_kernel=4, ssm_heads=32, ssm_head_dim=128, ssm_state=256,
+            ssm_groups=2, embedding_multiplier=5.656854249492381,
+            lm_head_multiplier=0.0078125, attention_out_multiplier=0.0375,
+            key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+            ssm_out_multiplier=0.08838834764831845,
+            ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369,
+                             0.5, 0.3535533905932738),
+            mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        ), 64, 12288
     # mimo-v2.5-l7-ep16: a full class of 4 KV heads, groups of 16, keys of
     # 192 stored 256 wide, values 128, table 512; 64 rings of 9 pages
     return tfm.ModelConfig(
@@ -466,9 +483,16 @@ def test_state_by_slot_and_heads_of_64_copy_no_pool_and_no_weights(
     mem = compiled.memory_analysis()
     state = 2 * d.state["conv"].size
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
-    # 0.23, 0.06 and 0.68 GiB at the parent, the copied matrices among them
-    bound = {"decode_step": 0.05, "prefill": 0.05, "prefill_suffix": 0.5}
-    assert mem.temp_size_in_bytes < bound[program] * 2**30
+    # what a program adds beside its operands, temporaries and results of its
+    # own: 0.23, 0.06 and 0.68 GiB of temporaries when the stacks were by
+    # kind, the copied matrices among them. A prefill program returns one
+    # row of logits, and the compiler keeps in temporaries what it kept in
+    # the [2048, 65536] float32 logits' buffer before (0.035 GiB of
+    # temporaries beside 0.5 of logits then; 0.54 and 0.0002 now, the
+    # prefill's expert rows among them)
+    bound = {"decode_step": 0.05, "prefill": 0.6, "prefill_suffix": 0.5}
+    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    assert mem.temp_size_in_bytes + own < bound[program] * 2**30
 
 
 # -- a float32 matrix of state a head at the widths of `olmo-hybrid-7b-l16` ------
@@ -531,17 +555,95 @@ def test_a_matrix_of_state_a_head_and_groups_of_one_copy_no_state(
     # as the chip stores it: rows of 192 in 256 lanes
     state = 12 * 16 * 30 * 96 * 256 * 4 + 2 * d.state["delta_taps"].size
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
-    bound = {"decode_step": 0.05, "prefill": 0.75, "prefill_suffix": 0.3}
-    assert mem.temp_size_in_bytes < bound[program] * 2**30
+    # what a program adds beside its operands, temporaries and results of
+    # its own: the first program 0.55 GiB of temporaries beside 1.11 of
+    # logits when it returned every row, 1.35 and 0.0004 with one (the compiler keeps in
+    # temporaries what it kept in the logits' buffer); a chunk 0.16 and
+    # 0.27, then 0.18 and 0.0004
+    own = mem.output_size_in_bytes - mem.alias_size_in_bytes
+    bound = {"decode_step": 0.05, "prefill": 1.5, "prefill_suffix": 0.3}
+    assert mem.temp_size_in_bytes + own < bound[program] * 2**30
     if program == "prefill_suffix":
         assert not re.findall(r"f32\[[\d,]*\b16896\]", text)  # [736,30,1,16896]
-    # nothing but the logits leaves a program beside what it aliases
-    rows = {"decode_step": 0, "prefill": 2976, "prefill_suffix": 736}[program]
+    # nothing but the row of logits the host reads leaves a prefill program
+    # beside what it aliases (every row of the run's was 1.1 GiB at 2,976
+    # tokens)
+    assert own < 100352 * 4 + 2**20
+
+
+# -- a parallel layer's pages and state at the widths of `falcon-h1-34b-l6` ------
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill", "prefill_suffix"])
+def test_a_parallel_layer_writes_pages_and_state_and_copies_neither(
+    deployment, program
+):
+    """``falcon-h1-34b-l6.think``: 64 slots, contexts to 4,096 (a table of
+    256 pages), 12,288 pages of 6 layers x 4 KV heads x 128 read by groups
+    of 5 query heads (the first group neither a power of two nor a multiple
+    of 8 the kernel meets), and in the same 6 layers the Mamba-2 mixer's
+    state by slot: ``S`` ``[6, 64, 32, 256, 128]`` in float32 (1.5 GiB) and
+    the columns ``[6, 3, 64, 5120]``. Every program aliases both pools and
+    both arrays of state and copies none of them: ``decode_step`` moves
+    every live slot's state on in place inside the layers' scan, the prefill
+    programs carry one slot's rows and put them in once. The first prefill
+    program takes the cell's longest prompt, 2,048 tokens (it could take
+    3,648 at 20 heads), a chunk 912. Each prefill program returns one row
+    of logits: over every row of a 2,048-token prompt a head of 261,120
+    would be 2 GiB of float32 beside 9.8 GiB of weights."""
+    d = deployment("falcon")
+    eng, table = d.eng, d.tables["full"].shape[1]
+    assert (eng.pool.k_dim, eng.pool.v_dim, table) == (128, 128, 256)
+    assert (eng.max_prefill_tokens, eng.prefill_chunk) == (3648, 912)
+    assert d.state["ssm_s"].shape == (6, 64, 32, 256, 128)
+    assert d.state["ssm_s"].dtype == jnp.float32
+    assert d.state["ssm_taps"].shape == (6, 3, 64, 5120)
+    assert d.state["ssm_taps"].dtype == jnp.bfloat16
+    assert [r.count for r in d.cfg.layer_runs()] == [6]
+    if program == "decode_step":
+        compiled = d.decode_step()
+    elif program == "prefill":
+        compiled = eng._prefill.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(2048), 2048,
+            {"full": d.ints(2048 // PAGE)}, d.state, d.ints(), d.ints(),
+        ).compile()
+    else:
+        compiled = eng._prefill_suffix.lower(
+            d.params, d.pool_k, d.pool_v, d.ints(912), 912, d.ints(),
+            {"full": d.ints(table)}, d.ints(912 // PAGE), d.state,
+            d.ints(), d.ints(),
+        ).compile()
+    text = compiled.as_text()
+    assert not d.copies_of_a_pool(text)
+    # nothing yields the whole state but the program's own operand and
+    # result (a copy, or an update that is not in place)
+    for dims in (r"f32\[6,64,32,256,128\]", r"bf16\[6,3,64,5120\]"):
+        made = re.findall(
+            rf"(\S+) = {dims}\S* (?!bitcast\(|get-tuple-element\(|parameter\()"
+            r"([\w-]+)\(", text)
+        assert all(op in ("dynamic-update-slice", "fusion", "tuple")
+                   for _, op in made), made
+        assert not re.findall(rf"= {dims}\S* copy\(", text)
+    if program == "decode_step":
+        kernels = [
+            line for line in text.splitlines()
+            if f'custom_call_target="{KERNEL}"' in line
+            and "paged_attention_decode" in line
+        ]
+        assert kernels and all("bf16[6,4,12288,16,128]" in k for k in kernels)
+        assert "f32[64,4,5,128]" in text  # the kernel's groups of 5
+    mem = compiled.memory_analysis()
+    state = 4 * d.state["ssm_s"].size + 2 * d.state["ssm_taps"].size
+    assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
+    # compiled here: 5 MiB, 0.41 GiB (the [20, 2048, 2048] float32 scores
+    # among them) and 0.12 GiB
+    bound = {"decode_step": 0.05, "prefill": 0.75, "prefill_suffix": 0.25}
+    assert mem.temp_size_in_bytes < bound[program] * 2**30
     own = mem.output_size_in_bytes - mem.alias_size_in_bytes
-    assert own < rows * 100352 * 4 + 2**20
+    assert own < 261120 * 4 + 2**20
 
 
-@pytest.mark.parametrize("name", ["mistral", "internlm2", "olmo"])
+@pytest.mark.parametrize("name", ["mistral", "internlm2", "olmo", "falcon"])
 def test_a_dense_decode_step_holds_no_grouped_matmul(deployment, name):
     """The four dense cells' ``decode_step`` (chat and rag share mistral's)
     has no expert layer: neither Megablox ``gmm`` nor ``lax.ragged_dot``

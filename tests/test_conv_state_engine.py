@@ -100,14 +100,17 @@ def reference_logits(toy, tokens, quant=None):
 
 
 def capture_prefill_logits(eng):
-    """Logits of every run of the two prefill programs, in order."""
+    """What each run of the two prefill programs returns, in order: the
+    position of its last real token in the prompt, and that token's
+    logits (the one row the host reads)."""
     seen = []
     for name in ("_prefill", "_prefill_suffix"):
         program = getattr(eng, name)
 
-        def spied(*a, _program=program, **kw):
+        def spied(*a, _program=program, _suffix=name == "_prefill_suffix", **kw):
             out = _program(*a, **kw)
-            seen.append(np.asarray(out[0][0]))
+            at = int(a[-1]) - 1 + (int(a[5]) if _suffix else 0)
+            seen.append((at, np.asarray(out[0][0])))
             return out
 
         setattr(eng, name, spied)
@@ -115,11 +118,11 @@ def capture_prefill_logits(eng):
 
 
 def served(eng, prompt, new):
-    """(the prefill's logits at the prompt's positions, the tokens) of one
-    request run alone through ``eng``."""
+    """(the positions the prefill runs returned a row for, those rows, the
+    tokens) of one request run alone through ``eng``."""
     seen = capture_prefill_logits(eng)
     (out,) = eng.generate_ids([prompt], GenerationConfig(max_new_tokens=new))
-    return np.concatenate(seen)[: len(prompt)], out
+    return [p for p, _ in seen], np.stack([r for _, r in seen]), out
 
 
 def gaps(toy, prompt, out):
@@ -151,8 +154,9 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     """Prompts whose true length is not their padded length (37, 5, 23;
     16 is whole pages), and prompts longer than one prefill program (37,
     23: 16 tokens and then chunks of 4, the state carried from chunk to
-    chunk in the slot's row). Logits, not tokens: the prefill's at every
-    prompt position; a decoded token by the reference's logit of it against
+    chunk in the slot's row). Logits, not tokens: the row each prefill
+    run returns (its last real token's) against the reference's at that
+    position; a decoded token by the reference's logit of it against
     the reference's best at that position. With ``on_tpu`` the pool is
     built as on the chip, rows of 8 stored in whole tiles of 128, the full
     layers go through the Pallas kernel and the expert layers' decode
@@ -164,10 +168,10 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     new = 24
     for n in (37, 5, 16, 23):  # one at a time: the captures are this prompt's
         prompt = rng.integers(0, 512, n).tolist()
-        got, out = served(eng, prompt, new)
-        assert len(out) == new
+        at, got, out = served(eng, prompt, new)
+        assert len(out) == new and at[-1] == n - 1
         want = reference_logits(toy, prompt)
-        np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+        np.testing.assert_allclose(got, want[at], atol=TOL, rtol=0)
         assert gaps(toy, prompt, out).max() <= TOL
         # an altered token must fail: the reference does not put it first
         wrong = list(out)
@@ -206,8 +210,9 @@ def alone(toy, prompt, new):
 
 
 def assert_same(a, b):
-    np.testing.assert_array_equal(a[0], b[0])
-    assert a[1] == b[1]
+    assert a[0] == b[0]
+    np.testing.assert_array_equal(a[1], b[1])
+    assert a[2] == b[2]
 
 
 def test_a_recycled_slot_reads_nothing_of_its_former_occupant(toy):

@@ -21,7 +21,6 @@ fails...`` holds that one as a test; an inactive slot's ``S`` written:
 ``test_an_idle_slot_keeps_its_state...`` fails and nothing else, by design
 (its rows are read by no one before a prefill overwrites them)."""
 import dataclasses
-import hashlib
 import json
 import os
 import sys
@@ -112,14 +111,17 @@ def reference_logits(toy, tokens, quant=None, cfg=TOY):
 
 
 def capture_prefill_logits(eng):
-    """Logits of every run of the two prefill programs, in order."""
+    """What each run of the two prefill programs returns, in order: the
+    position of its last real token in the prompt, and that token's
+    logits (the one row the host reads)."""
     seen = []
     for name in ("_prefill", "_prefill_suffix"):
         program = getattr(eng, name)
 
-        def spied(*a, _program=program, **kw):
+        def spied(*a, _program=program, _suffix=name == "_prefill_suffix", **kw):
             out = _program(*a, **kw)
-            seen.append(np.asarray(out[0][0]))
+            at = int(a[-1]) - 1 + (int(a[5]) if _suffix else 0)
+            seen.append((at, np.asarray(out[0][0])))
             return out
 
         setattr(eng, name, spied)
@@ -127,11 +129,11 @@ def capture_prefill_logits(eng):
 
 
 def served(eng, prompt, new):
-    """(the prefill's logits at the prompt's positions, the tokens) of one
-    request run alone through ``eng``."""
+    """(the positions the prefill runs returned a row for, those rows, the
+    tokens) of one request run alone through ``eng``."""
     seen = capture_prefill_logits(eng)
     (out,) = eng.generate_ids([prompt], GenerationConfig(max_new_tokens=new))
-    return np.concatenate(seen)[: len(prompt)], out
+    return [p for p, _ in seen], np.stack([r for _, r in seen]), out
 
 
 def gaps(toy, prompt, out, cfg=TOY):
@@ -230,8 +232,9 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     """Prompts whose true length is not their padded length (37, 5, 23;
     16 is whole pages), and prompts longer than one prefill program: 37 is
     16 tokens and then six runs of the suffix program, 23 two, the state
-    carried from run to run in the slot's rows. Logits, not tokens: the
-    prefill's at every prompt position; a decoded token by the reference's
+    carried from run to run in the slot's rows. Logits, not tokens: the row
+    each prefill run returns (its last real token's) against the
+    reference's at that position; a decoded token by the reference's
     logit of it against the reference's best at that position. With
     ``on_tpu`` the pool is built as on the chip, rows of 16 stored in whole
     tiles of 128, and the full layers go through the Pallas kernel in
@@ -242,10 +245,10 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     new = 24
     for n in (37, 5, 16, 23):  # one at a time: the captures are this prompt's
         prompt = rng.integers(0, 512, n).tolist()
-        got, out = served(eng, prompt, new)
-        assert len(out) == new
+        at, got, out = served(eng, prompt, new)
+        assert len(out) == new and at[-1] == n - 1
         want = reference_logits(toy, prompt)
-        np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+        np.testing.assert_allclose(got, want[at], atol=TOL, rtol=0)
         assert gaps(toy, prompt, out).max() <= TOL
         # an altered token must fail: the reference does not put it first
         wrong = list(out)
@@ -282,8 +285,8 @@ def test_a_bfloat16_state_fails_the_comparison(toy, monkeypatch):
     monkeypatch.setattr(tfm, "delta_scan", rounded(tfm.delta_scan, 1))
     eng = make_engine(toy, max_batch=1)
     prompt = np.random.default_rng(0).integers(0, 512, 37).tolist()
-    got, out = served(eng, prompt, 24)
-    assert np.abs(got - reference_logits(toy, prompt)).max() > 10 * TOL
+    at, got, out = served(eng, prompt, 24)
+    assert np.abs(got - reference_logits(toy, prompt)[at]).max() > 10 * TOL
     want = reference_logits(toy, prompt + out)[36:-1]
     teacher = np.abs(want.max(-1) - want[np.arange(24), out]).max()
     logits_off = np.abs(got[-1] - want[0]).max()
@@ -328,8 +331,8 @@ def test_a_switch_set_wrong_fails_the_reference(toy, wrong):
     cfg, params = wrong(toy[0], toy[1])
     eng = make_engine((cfg, params, toy[2]), max_batch=1)
     prompt = np.random.default_rng(5).integers(0, 512, 21).tolist()
-    got, _ = served(eng, prompt, 2)
-    assert np.abs(got - reference_logits(toy, prompt)).max() > 100 * TOL
+    at, got, _ = served(eng, prompt, 2)
+    assert np.abs(got - reference_logits(toy, prompt)[at]).max() > 100 * TOL
 
 
 # -- (d) a slot that changes hands ----------------------------------------------
@@ -340,8 +343,9 @@ def alone(toy, prompt, new):
 
 
 def assert_same(a, b):
-    np.testing.assert_array_equal(a[0], b[0])
-    assert a[1] == b[1]
+    assert a[0] == b[0]
+    np.testing.assert_array_equal(a[1], b[1])
+    assert a[2] == b[2]
 
 
 def test_a_recycled_slot_reads_nothing_of_its_former_occupant(toy):
@@ -460,90 +464,107 @@ def test_the_small_switches_are_refused_where_they_are_not_implemented():
                         delta_key_dim=2, delta_value_dim=2)
 
 
-# -- (f) the configurations the benchmark had: bit for bit the parent's ----------
+# -- (f) the configurations the benchmark had: the parent's, to rounding --------
 
-PARENT = os.path.join(HERE, "data", "parent_outputs_pr41.json")
+PARENT = os.path.join(HERE, "data", "parent_outputs.json")
 OLD_TOYS = {
     "dense": "toy/configs/toy-gqa.json",
     "mimo": "toy_moe/configs/toy-moe-window.json",
     "lfm2": "toy_conv/configs/toy-moe-conv.json",
+    "olmo": "toy_delta/configs/toy-delta.json",
 }
 
 
 def old_toy_outputs(name, bench=BENCH):
     """Two requests through one slot of a toy of a family the benchmark
     had (the second after the first, so a slot changes hands), the first
-    longer than one prefill program: the logits of the first program's runs
-    (a hash), of the first request's chunks (their sum and sum of
-    magnitudes in float64) and the decoded tokens. ``python
-    tests/test_delta_state_engine.py <checkout>`` writes the file these are
-    compared with, from that checkout's program."""
+    longer than one prefill program: the decoded tokens and the row of
+    logits each prefill run gives the host (its last real token's), as the
+    first eight logits and the row's sum and sum of magnitudes in float64.
+    ``python tests/test_delta_state_engine.py <checkout>`` writes the file
+    these are compared with, from that checkout's program (whose prefill
+    programs returned every row of logits: the one read is taken)."""
     with open(os.path.join(bench, "tests", OLD_TOYS[name])) as f:
         cfg = json.load(f)
     family = spec.load_family(cfg, bench)
     page = cfg["deployment"]["page_size"]
-    saved = continuous.PREFILL_SCORES_BYTES
+    saved = continuous.PREFILL_SCORES_BYTES, tfm.DELTA_BLOCK
     continuous.PREFILL_SCORES_BYTES = 4 * cfg["num_attention_heads"] * 32 * 32
+    tfm.DELTA_BLOCK = 8  # the Olmo toy's scan: several blocks a run
     try:
         eng = ContinuousBatchingEngine(
             family.model_config(cfg), family.make_weights(cfg, 7),
             max_batch=1, page_size=page, n_pages=64,
         )
         rng = np.random.default_rng(11)
-        first, chunks, tokens = [], [], []
+        at, rows, tokens = [], [], []
         for n in (53, 18):
             prompt = rng.integers(0, cfg["vocab_size"], n).tolist()
-            got, out = served(eng, prompt, 12)
-            got = np.asarray(got, np.float32)
-            first.append(got[: eng.max_prefill_tokens])
-            chunks.append(got[eng.max_prefill_tokens :])
+            where, got, out = served(eng, prompt, 12)
+            at.append(where)
+            rows.append(np.asarray(got, np.float32))
             tokens.append(out)
     finally:
-        continuous.PREFILL_SCORES_BYTES = saved
-    first, chunks = np.concatenate(first), np.concatenate(chunks)
-    assert len(chunks) == 53 - eng.max_prefill_tokens > 0
+        continuous.PREFILL_SCORES_BYTES, tfm.DELTA_BLOCK = saved
+    rows = np.concatenate(rows)
+    assert len(at[0]) > 1  # the first prompt ran in chunks
     return {
         "dtype": cfg["torch_dtype"],
         "tokens": tokens,
-        "first_program_sha256": hashlib.sha256(first.tobytes()).hexdigest(),
-        "first_program_last_row_head": [float(x) for x in first[-1, :8]],
-        "chunks_moments": [
-            float(chunks.sum(dtype=np.float64)),
-            float(np.abs(chunks).sum(dtype=np.float64)),
+        "positions": at,
+        "rows_head": rows[:, :8].tolist(),
+        "rows_moments": [
+            [float(r.sum(dtype=np.float64)), float(np.abs(r).sum(dtype=np.float64))]
+            for r in rows
         ],
-        "chunks_last_row_head": [float(x) for x in chunks[-1, :8]],
     }
 
 
 @pytest.mark.parametrize("name", sorted(OLD_TOYS))
 def test_the_families_the_benchmark_had_give_the_parents_outputs(name):
-    """A dense, a MiMo and an LFM2 toy take none of PR 40's branches: their
-    tokens and the logits of the first prefill program are, bit for bit,
-    what the parent commit's program gave on this machine. The logits of a
-    prompt's chunks are the parent's to the order of a sum: since PR 41 the
-    suffix program's softmax over the slot's pages is summed block by block
-    (``tests/test_table_attention.py`` holds it to the whole-table form)."""
+    """A dense, a MiMo, an LFM2 and an Olmo toy take none of the parallel
+    layer's branches: their tokens are what the parent commit's program
+    gave on this machine, and the row of logits each prefill run gives the
+    host is the parent's to rounding: the head now runs over that row
+    alone where it ran over every row of the run, a product of another
+    shape, which sums in another order on the CPU."""
     with open(PARENT) as f:
         want = json.load(f)[name]
     got = old_toy_outputs(name)
-    assert got["tokens"] == want["tokens"]
-    for key in ("dtype", "first_program_last_row_head", "first_program_sha256"):
+    for key in ("dtype", "tokens", "positions"):
         assert got[key] == want[key]
-    # float32 logits move by 1.4e-6 and their sums over 21 rows by 1e-4; the
-    # dense toy's stream is bfloat16, where a last bit of the attention's
-    # result is 0.4 % of a logit: 0.014, and 2.2 in a sum of 69,207
+    # float32 logits move by a few 1e-7 and a row's sums (of 512 or 4,096
+    # logits) by 1e-4; the dense toy's head is a bfloat16 product, whose
+    # last bit is 0.4 % of a logit: 0.03, and 2 in a row's sum of 4,096
     row, moments = {"float32": (1e-5, 1e-3), "bfloat16": (0.05, 5.0)}[got["dtype"]]
+    np.testing.assert_allclose(got["rows_head"], want["rows_head"], rtol=0, atol=row)
     np.testing.assert_allclose(
-        got["chunks_last_row_head"], want["chunks_last_row_head"], rtol=0, atol=row
+        got["rows_moments"], want["rows_moments"], rtol=0, atol=moments
     )
-    np.testing.assert_allclose(
-        got["chunks_moments"], want["chunks_moments"], rtol=0, atol=moments
-    )
+
+
+def _every_row_program(eng):
+    """``capture_prefill_logits`` for a program whose prefill runs return
+    every row of logits: the row the host read of each."""
+    seen = []
+    for name in ("_prefill", "_prefill_suffix"):
+        program = getattr(eng, name)
+
+        def spied(*a, _program=program, _suffix=name == "_prefill_suffix", **kw):
+            out = _program(*a, **kw)
+            at = int(a[-1]) - 1 + (int(a[5]) if _suffix else 0)
+            row = np.asarray(out[0][0])
+            seen.append((at, row if row.ndim == 1 else row[int(a[-1]) - 1]))
+            return out
+
+        setattr(eng, name, spied)
+    return seen
 
 
 if __name__ == "__main__":  # python tests/test_delta_state_engine.py <checkout>
     bench = os.path.join(os.path.abspath(sys.argv[1]), "benchmarks")
     assert tfm.__file__.startswith(os.path.abspath(sys.argv[1])), tfm.__file__
+    capture_prefill_logits = _every_row_program
     with open(PARENT, "w") as f:
         json.dump({n: old_toy_outputs(n, bench) for n in sorted(OLD_TOYS)}, f, indent=1)
         f.write("\n")

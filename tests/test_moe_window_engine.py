@@ -101,14 +101,17 @@ def reference_logits(toy, tokens, quant=None):
 
 
 def capture_prefill_logits(eng):
-    """Logits of every run of the two prefill programs, in order."""
+    """What each run of the two prefill programs returns, in order: the
+    position of its last real token in the prompt, and that token's
+    logits (the one row the host reads)."""
     seen = []
     for name in ("_prefill", "_prefill_suffix"):
         program = getattr(eng, name)
 
-        def spied(*a, _program=program, **kw):
+        def spied(*a, _program=program, _suffix=name == "_prefill_suffix", **kw):
             out = _program(*a, **kw)
-            seen.append(np.asarray(out[0][0]))
+            at = int(a[-1]) - 1 + (int(a[5]) if _suffix else 0)
+            seen.append((at, np.asarray(out[0][0])))
             return out
 
         setattr(eng, name, spied)
@@ -125,8 +128,9 @@ def capture_prefill_logits(eng):
 def test_prefill_then_decode_agrees_with_the_reference(toy, monkeypatch, lanes):
     """Prompts that span several chunks (one program takes 16 tokens, the
     rest goes in chunks of 4) and contexts that wrap a slot's ring of
-    3 pages x 4 tokens several times. Logits, not tokens: the prefill's at
-    every prompt position; a decoded token by the reference's logit of it
+    3 pages x 4 tokens several times. Logits, not tokens: the row each
+    prefill run returns (its last real token's) against the reference's at
+    that position; a decoded token by the reference's logit of it
     against the reference's best at that position."""
     monkeypatch.setattr(continuous, "LANES", lanes)
     eng = make_engine(toy)
@@ -141,10 +145,12 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, monkeypatch, lanes):
         (out,) = eng.generate_ids([prompt], GenerationConfig(max_new_tokens=new))
         assert len(out) == new
         want = reference_logits(toy, prompt + out)
-        got = np.concatenate(seen)[: len(prompt)]
+        at = [p for p, _ in seen]
+        got = np.stack([r for _, r in seen])
         padded = -(-len(prompt) // PAGE) * PAGE
         assert len(seen) == 1 + max(0, -(-(padded - 16) // 4))
-        np.testing.assert_allclose(got, want[: len(prompt)], atol=TOL, rtol=0)
+        assert at[-1] == len(prompt) - 1
+        np.testing.assert_allclose(got, want[at], atol=TOL, rtol=0)
         at = want[len(prompt) - 1 : len(prompt) + new - 1]
         gaps = at.max(-1) - at[np.arange(new), out]
         assert gaps.max() <= TOL
@@ -342,19 +348,16 @@ def test_the_int8_control_fails_the_comparison(toy):
 
 # -- (d) dense configurations through the new block, to the bit --------------------
 
-# CRC32 of the float32 logits of each prefill / prefill_suffix run, and the
-# served tokens, of the parent commit's engine (6598cc2, its three inlined
-# copies of the block) on this drive, computed by the parent itself
-PARENT = {
-    "bfloat16": [574494207, 3729361566, 2965980353, 4125194175],
-    "float32": [654391985, 3291833733, 537432005, 2138226089],
-}
-# the last two runs are of the suffix program (prefix hits), whose softmax
-# over the slot's pages is summed block by block since PR 41: float32
-# logits differ from the parent's in the last bits, bfloat16's do not. For
-# those two runs in float32 the logits' (sum, sum of magnitudes) in float64,
-# of the parent's own (a0eaa5c), stand for the CRCs
-PARENT_SUFFIX_MOMENTS = [(-40.9228, 565.0556), (-49.2118, 554.1606)]
+# The served tokens of the parent commit's engine (6598cc2, its three
+# inlined copies of the block) on this drive, and, of each prefill /
+# prefill_suffix run, the row of logits the host reads (its last real
+# token's) as the parent commit 1b925e3 gave it, computed by that
+# parent itself: in float32 the CRC32, to the bit; in bfloat16 the row's
+# (sum, sum of magnitudes) in float64, to rounding: the head is now a
+# product of one row, whose bfloat16 result rounds otherwise on the CPU
+PARENT = {"float32": [4000668772, 437513611, 1547471121, 3715026781]}
+PARENT_BF16_MOMENTS = [(-9.76585, 69.55078), (7.0463, 89.88766),
+                       (-14.37257, 71.235), (-2.20629, 68.70417)]
 PARENT_TOKENS = [
     [41] * 12,
     [37, 33, 32, 66, 28, 28, 57, 28, 66, 66, 66, 33],
@@ -394,12 +397,11 @@ class _ListPrefixCache:
         return {}
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_a_dense_configuration_gives_the_parents_logits_to_the_bit(
-    dtype, monkeypatch
-):
-    # whole prompts in one prefill program, as the dense cells run them
-    monkeypatch.setattr(continuous, "PREFILL_SCORES_BYTES", 2**30)
+def dense_drive(dtype):
+    """A dense configuration through the engine with a prefix cache, whole
+    prompts in one prefill program as the dense cells run them (the caller
+    sets ``PREFILL_SCORES_BYTES``): (the row of logits each prefill run
+    returns, as float32; the served tokens; the engine)."""
     cfg = tfm.ModelConfig(
         vocab_size=97, d_model=32, n_layers=3, n_heads=4, n_kv_heads=2,
         d_ff=48, max_seq_len=64, dtype=jnp.dtype(dtype),
@@ -414,74 +416,83 @@ def test_a_dense_configuration_gives_the_parents_logits_to_the_bit(
     gen = GenerationConfig(max_new_tokens=12)
     outs = eng.generate_ids([long, [4, 8], long[:9]], gen)
     outs += eng.generate_ids([long[:16] + [9, 9, 9]], gen)  # a prefix hit
+    return [np.asarray(r, np.float32) for _, r in seen], outs, eng
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_a_dense_configuration_gives_the_parents_logits_to_the_bit(
+    dtype, monkeypatch
+):
+    monkeypatch.setattr(continuous, "PREFILL_SCORES_BYTES", 2**30)
+    seen, outs, eng = dense_drive(dtype)
     assert eng.prefix_cache.hits == 2
     assert outs == PARENT_TOKENS
-    seen = [np.asarray(x, np.float32) for x in seen]
-    crcs = [zlib.crc32(x.tobytes()) for x in seen]
-    if dtype == "bfloat16":
-        assert crcs == PARENT[dtype]
-    else:  # the first program's runs to the bit, the suffix's to a sum's order
-        assert crcs[:2] == PARENT[dtype][:2]
+    if dtype == "float32":
+        assert [zlib.crc32(x.tobytes()) for x in seen] == PARENT[dtype]
+    else:
         moments = [
             (x.sum(dtype=np.float64), np.abs(x).sum(dtype=np.float64))
-            for x in seen[2:]
+            for x in seen
         ]
-        np.testing.assert_allclose(
-            moments, PARENT_SUFFIX_MOMENTS, rtol=0, atol=2e-4
-        )
+        np.testing.assert_allclose(moments, PARENT_BF16_MOMENTS, rtol=0, atol=0.05)
 
 
 # What the parent commit's engine (4ec9a34: `decoder_block` before it knew a
 # mixer that is no attention, stacks by kind sliced a run) gave on this
 # file's toy (13 layers, two page classes, 8 of 32 experts held): the CRC32
-# of the served tokens, and of every prefill / prefill_suffix run's logits
-# as float32. For float32 weights the logits' (sum, sum of magnitudes) in
-# float64 stand for the CRCs since PR 39: a run of L expert layers is one
-# stack of L x 8 groups to the grouped matmul, the CPU's `lax.ragged_dot`
-# sums over groups and columns in one contraction, and the empty groups are
-# zeros added at other places, so float32 logits differ from the parent's
-# in the last bits (2e-6 of 4 at most); bfloat16's do not, nor the tokens
+# of the served tokens; and of each prefill / prefill_suffix run the row of
+# logits the host reads, as the parent commit 1b925e3 gave it: in
+# float32 its CRC32, in bfloat16 its (sum, sum of magnitudes) in float64
+# (the head is now a product of one row, whose bfloat16 result
+# rounds otherwise on the CPU)
 PARENT_TOY = {
-    "float32": ([
-        (318.6947, 6508.7458), (86.1665, 1586.7288), (18.7128, 1628.7417),
-        (76.0736, 1608.4671), (102.4069, 1604.9964), (93.3399, 1609.777),
-        (26.5216, 1628.0693), (268.7715, 3201.9087), (233.5543, 6313.9024),
-        (383.9219, 6691.9366), (53.8578, 1579.7239), (64.0364, 1595.9792),
-    ], 3355322928),
-    "bfloat16": ([2188577051, 4088118218, 1348395843, 3190153717, 4108571303,
-                  3632099174, 936470520, 2925090720, 2329348211, 2147429832,
-                  1188732779, 2390298474], 3932849118),
+    "float32": ([2622514700, 3517881183, 2162343575, 4049468345, 3093657870,
+                 1525900907, 3785984651, 750154741, 711009151, 2225842359,
+                 2140864943, 3202436064], 3355322928),
+    "bfloat16": ([
+        (40.9505, 395.5886), (12.0921, 397.9979), (11.2609, 411.9698),
+        (24.3021, 395.1198), (29.7828, 402.0468), (16.7107, 411.5064),
+        (5.2977, 419.7569), (34.1218, 403.3266), (29.9677, 386.7218),
+        (6.3073, 413.1787), (19.7319, 398.4499), (0.4809, 403.5144),
+    ], 3932849118),
 }
 
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_the_windowed_expert_toy_gives_the_parents_outputs_to_the_bit(dtype):
-    """A stack by position that has no convolution layer takes none of the
-    branches PR 36 added (state by slot, QK-norm, a tied head, the router's
-    epsilon), a stack of its own for each run holds the rows the slice of a
-    kind's stack held, and an expert run's weights read in place by the
-    grouped matmul (PR 39) are the weights the scan sliced: the tokens to
-    the bit, the logits to the bit in bfloat16 and to the order of a sum
-    in float32."""
+def windowed_drive(dtype, bench=BENCH):
+    """This file's toy through the engine, prompts in chunks (the caller
+    sets the small programs): (the row of logits each prefill run returns,
+    as float32; the served tokens)."""
     cfg = dict(TOY, torch_dtype=dtype)
-    family = spec.load_family(cfg, BENCH)
+    family = spec.load_family(cfg, bench)
     model, weights = family.model_config(cfg), family.make_weights(cfg, 5)
     eng = make_engine((model, weights, None, by_run(model, weights)))
     seen = capture_prefill_logits(eng)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 512, n).tolist() for n in (37, 5, 16, 23)]
     outs = eng.generate_ids(prompts, GenerationConfig(max_new_tokens=20))
+    return [np.asarray(r, np.float32) for _, r in seen], outs
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_windowed_expert_toy_gives_the_parents_outputs_to_the_bit(dtype):
+    """A stack by position that has no convolution layer takes none of the
+    branches a model with state by slot takes (state by slot, QK-norm, a tied head, the router's
+    epsilon), a stack of its own for each run holds the rows the slice of a
+    kind's stack held, and an expert run's weights read in place by the
+    grouped matmul are the weights the scan sliced: the tokens to
+    the bit, the rows of logits the host reads to the bit in float32 and
+    to rounding in bfloat16."""
+    seen, outs = windowed_drive(dtype)
     logits, tokens = PARENT_TOY[dtype]
     assert zlib.crc32(np.asarray(outs, np.int32).tobytes()) == tokens
-    seen = [np.asarray(x, np.float32) for x in seen]
-    if dtype == "bfloat16":
+    if dtype == "float32":
         assert [zlib.crc32(x.tobytes()) for x in seen] == logits
     else:
         moments = [
             (x.sum(dtype=np.float64), np.abs(x).sum(dtype=np.float64))
             for x in seen
         ]
-        np.testing.assert_allclose(moments, logits, rtol=0, atol=2e-3)
+        np.testing.assert_allclose(moments, logits, rtol=0, atol=0.05)
 
 
 def test_rms_eps_is_the_configurations(toy):
@@ -500,8 +511,10 @@ def test_rms_eps_is_the_configurations(toy):
         seen = capture_prefill_logits(eng)
         eng.generate_ids([[1, 2, 3]], GenerationConfig(max_new_tokens=1))
         logits = tfm.forward(params, jnp.asarray([[1, 2, 3]]), cfg)
-        np.testing.assert_allclose(seen[0][:3], logits[0], atol=1e-5)
-        return seen[0][:3]
+        ((at, row),) = seen
+        assert at == 2
+        np.testing.assert_allclose(row, logits[0, 2], atol=1e-5)
+        return row
 
     assert np.abs(first_logits(1e-6) - first_logits(1e-2)).max() > 1e-3
 

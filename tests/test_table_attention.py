@@ -164,12 +164,14 @@ def test_the_walk_ends_where_the_keys_do(monkeypatch):
 # -- through the engine: a prompt in chunks against the same prompt whole --------
 
 # the benchmark's toy of each family: dense GQA; windowed layers, a sink and
-# held experts; gated short convolutions; the gated delta rule
+# held experts; gated short convolutions; the gated delta rule; attention
+# and a Mamba-2 mixer side by side
 TOYS = {
     "dense": "toy/configs/toy-gqa.json",
     "windowed-and-expert": "toy_moe/configs/toy-moe-window.json",
     "convolution": "toy_conv/configs/toy-moe-conv.json",
     "delta": "toy_delta/configs/toy-delta.json",
+    "parallel": "toy_ssm/configs/toy-ssm.json",
 }
 # what the engines' chunk tests allow a chunked prompt's logits; the delta
 # toy's differ by 5.4e-4 at the parent too, in rows of the first program
@@ -185,8 +187,10 @@ def test_a_prompt_in_chunks_gives_the_logits_of_the_prompt_prefilled_whole(
     through it and the rest in chunks of a page through the suffix program,
     each chunk walking the slot's table in blocks of two pages (up to
     seven trips a layer of the ``full`` class; the dense toy's pages are 16
-    tokens, its three chunks walk one to two blocks): the logits at every
-    position, float32."""
+    tokens, its three chunks walk one to two blocks): float32 logits of the
+    row each run returns (its last real token's), the first chunk's, a
+    middle one's and the last's, against the same prompt cut there and
+    prefilled whole."""
     with open(os.path.join(BENCH, "tests", TOYS[name])) as f:
         cfg = {**json.load(f), "torch_dtype": "float32"}
     family = spec.load_family(cfg, BENCH)
@@ -194,7 +198,9 @@ def test_a_prompt_in_chunks_gives_the_logits_of_the_prompt_prefilled_whole(
     page, heads = cfg["deployment"]["page_size"], cfg["num_attention_heads"]
     prompt = np.random.default_rng(5).integers(0, cfg["vocab_size"], 53).tolist()
 
-    def logits(longest, block_pages):
+    def logits(longest, block_pages, n=len(prompt)):
+        """(the positions of the rows the runs returned, the rows, the
+        engine) of ``prompt[:n]``."""
         monkeypatch.setattr(
             continuous, "PREFILL_SCORES_BYTES", 4 * heads * longest * longest
         )
@@ -208,21 +214,25 @@ def test_a_prompt_in_chunks_gives_the_logits_of_the_prompt_prefilled_whole(
         )
         seen = []
         for program in ("_prefill", "_prefill_suffix"):
-            def spied(*a, _program=getattr(eng, program), **kw):
+            def spied(*a, _program=getattr(eng, program),
+                      _suffix=program == "_prefill_suffix", **kw):
                 out = _program(*a, **kw)
-                seen.append(np.asarray(out[0][0]))
+                at = int(a[-1]) - 1 + (int(a[5]) if _suffix else 0)
+                seen.append((at, np.asarray(out[0][0])))
                 return out
 
             setattr(eng, program, spied)
-        eng.generate_ids([prompt], GenerationConfig(max_new_tokens=1))
-        return np.concatenate(seen)[: len(prompt)], len(seen), eng
+        eng.generate_ids([prompt[:n]], GenerationConfig(max_new_tokens=1))
+        return [p for p, _ in seen], np.stack([r for _, r in seen]), eng
 
-    whole, runs, _ = logits(64, 64)
-    assert runs == 1
-    chunked, runs, eng = logits(16, 2)
+    at, chunked, eng = logits(16, 2)
     padded = -(-len(prompt) // page) * page
-    assert eng.prefill_chunk == page and runs == 1 + (padded - 16) // page
+    assert eng.prefill_chunk == page and len(at) == 1 + (padded - 16) // page
+    assert at[-1] == len(prompt) - 1
     assert continuous._attention_block_pages(
         page, heads, page, eng.max_pages_per_seq
     ) == 2 < eng.max_pages_per_seq
-    np.testing.assert_allclose(chunked, whole, rtol=0, atol=TOL[name])
+    for i in (1, len(at) // 2, len(at) - 1):
+        whole_at, whole, _ = logits(64, 64, at[i] + 1)
+        assert whole_at == [at[i]]
+        np.testing.assert_allclose(chunked[i], whole[0], rtol=0, atol=TOL[name])
