@@ -508,6 +508,7 @@ class ContinuousBatchingEngine:
             )
         self.windowed = "window" in cfg.kv_classes()
         self.stateful = cfg.state_layers > 0
+        self.recurrent = any(k in MATRIX_OF for k in cfg.state_kinds())
         if (self.windowed or self.stateful) and prefix_cache is not None:
             raise tfm.UnsupportedModelFeature(
                 "the shared prefix cache holds pages of the `full` class "
@@ -520,13 +521,14 @@ class ContinuousBatchingEngine:
         self.cfg = cfg
         self.B = max_batch
         self.page = page_size
-        # how decode_step attends over the ``full`` class of page and runs
-        # its expert products: on a TPU the Pallas kernels (paged attention,
-        # Megablox gmm), elsewhere the XLA gather and lax.ragged_dot (prefill
+        # how decode_step attends over the ``full`` class of page, runs its
+        # expert products and moves a float32 state a head on: on a TPU the
+        # Pallas kernels (paged attention, Megablox gmm, the state step),
+        # elsewhere the XLA gather, lax.ragged_dot and jax.numpy (prefill
         # keeps ragged_dot everywhere: set-up, PERF.md section 6). Read
         # from the platform, set by no caller; CPU tests put "interpret" here
         kernel = "compiled" if jax.default_backend() == "tpu" else None
-        self._attn_kernel = self._moe_kernel = kernel
+        self._attn_kernel = self._moe_kernel = self._state_kernel = kernel
         self.pool = PagedKVPool(
             cfg, n_pages, page_size, max_batch, kernel=bool(self._attn_kernel)
         )
@@ -790,8 +792,10 @@ class ContinuousBatchingEngine:
             and how many there were, the pages the kernel walked and the
             table entries of those layers, which the gather would have
             read; with state by slot two more: the layers that keep it and
-            the rows of it written, one a layer a live slot; with experts
-            the expert layers run in ``gmm``), the pool and the state."""
+            the rows of it written, one a layer a live slot; with a float32
+            matrix of state a head one more, its layers moved on in the
+            state kernel; with experts the expert layers run in ``gmm``),
+            the pool and the state."""
             b = self.B
             h = tfm.embed(cfg, params, tokens)  # [B, D]
             # positions a slot's query sees, itself among them; none if idle
@@ -878,28 +882,50 @@ class ContinuousBatchingEngine:
 
             def recur(state, layer, q, k, v, g, beta, cache):
                 """Every slot's ``S`` moved on by its token
-                (``tfm.delta_step``); an inactive slot keeps its own."""
+                (``tfm.delta_step``); an inactive slot keeps its own. The
+                kernel moves the layer's rows on where they lie, each read
+                once and written once; the layer is its operand, not a
+                slice."""
                 name = MATRIX_OF[state]
                 every = cache["state"][name]
-                held = every[layer]  # [B, H, dk, dv]
-                o, moved = tfm.delta_step(held, q, k, v, g, beta)
-                moved = jnp.where(active[:, None, None, None], moved, held)
-                every = jax.lax.dynamic_update_index_in_dim(every, moved, layer, 0)
+                if self._state_kernel:
+                    from ray_tpu.ops.state_step import state_step_decode
+
+                    o, every = state_step_decode(
+                        every, layer, active, q, k, v, g, beta,
+                        interpret=self._state_kernel == "interpret",
+                    )
+                else:
+                    held = every[layer]  # [B, H, dk, dv]
+                    o, moved = tfm.delta_step(held, q, k, v, g, beta)
+                    moved = jnp.where(active[:, None, None, None], moved, held)
+                    every = jax.lax.dynamic_update_index_in_dim(
+                        every, moved, layer, 0
+                    )
                 return o, {
                     **cache, "state": {**cache["state"], name: every},
+                    "recurred": cache["recurred"] + bool(self._state_kernel),
                 }
 
+            # of a model with a float32 matrix of state a head: its layers
+            # whose update ran in the kernel
+            recurred = (
+                {"recurred": jnp.zeros((1,), jnp.int32)} if self.recurrent
+                else {}
+            )
             h, cache, moe = tfm.run_stack(
                 cfg, params["blocks"], h, positions,
                 {"k": pool_k, "v": pool_v, "state": state,
                  "walked": jnp.zeros((4,), jnp.int32),
-                 "stated": jnp.zeros((2,), jnp.int32)},
+                 "stated": jnp.zeros((2,), jnp.int32), **recurred},
                 attend, live=active, shift=shift, recur=recur,
                 experts_kernel=self._moe_kernel,
             )
             counts = [moe, cache["walked"]]
             if self.stateful:
                 counts.append(cache["stated"])
+            if self.recurrent:
+                counts.append(cache["recurred"])
             if cfg.n_routed_experts:
                 kernel_layers = bool(self._moe_kernel) * sum(
                     r.count for r in cfg.layer_runs() if r.experts
@@ -1844,6 +1870,8 @@ class ContinuousBatchingEngine:
                             state_layers=stated[0],
                             state_slots_written=stated[1],
                         )
+                    if self.recurrent:
+                        decode.set(state_kernel_layers=rest[2])
                     if self.cfg.n_routed_experts:
                         decode.set(
                             moe_pairs_held=pairs, moe_experts_hit=hit,

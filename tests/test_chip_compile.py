@@ -252,6 +252,36 @@ class _Deployment:
         )
 
     @staticmethod
+    def state_kernels(text):
+        """The state kernel's calls in the optimised module, each as (the
+        shape its second result has, the shape of the operand that result
+        aliases)."""
+        found = []
+        for line in text.splitlines():
+            if (f'custom_call_target="{KERNEL}"' not in line
+                    or "state_step_decode" not in line):
+                continue
+            result = re.search(r"= \((\S+), (f32\[[\d,]+\])", line).group(2)
+            into = int(re.search(
+                r"output_to_operand_aliasing=\{\{1\}: \((\d+),", line
+            ).group(1))
+            operands = re.search(
+                r"operand_layout_constraints=\{(.*?)\}\}", line
+            ).group(1)
+            found.append((result, re.findall(r"\w+\[[\d,]*\]", operands)[into]))
+        return found
+
+    @staticmethod
+    def readers_of_whole(text, dims):
+        """Fusions handed the whole state ``f32[dims]`` as an operand: a
+        fused computation's parameter of that shape. The parent's decode
+        step had two a parallel layer (the readout, the select and update
+        in place) and three a delta layer (``k S'``, ``q S``, the select)."""
+        return re.findall(
+            rf"%param\S* = f32\[{dims}\]\S* parameter\(", text
+        )
+
+    @staticmethod
     def grouped_matmuls(text):
         """(Megablox ``gmm`` kernels, ``lax.ragged_dot``'s operations) in
         the optimised module."""
@@ -542,7 +572,10 @@ def test_a_matrix_of_state_a_head_and_groups_of_one_copy_no_state(
     assert not d.copies_of_a_pool(text)
     assert not re.findall(r"= f32\[12,16,30,96,192\]\S* copy\(", text)
     assert not re.findall(r"= bf16\[12,3,16,11520\]\S* copy\(", text)
-    assert set(re.findall(r"f32\[12,16,30,96,192\](\{[^}]*\})", text)) == {
+    # the layouts arrays have (a kernel's operand_layout_constraints name
+    # its operands' shapes without their tiles)
+    held = re.sub(r"operand_layout_constraints=\{.*?\}\}", "", text)
+    assert set(re.findall(r"f32\[12,16,30,96,192\](\{[^}]*\})", held)) == {
         "{4,3,2,1,0:T(8,128)}"}
     if program == "decode_step":
         kernels = [
@@ -551,6 +584,13 @@ def test_a_matrix_of_state_a_head_and_groups_of_one_copy_no_state(
             and "paged_attention_decode" in line
         ]
         assert kernels and all("bf16[4,30,4096,16,128]" in k for k in kernels)
+        # the delta rule in the state kernel, one call in each of the four
+        # runs' scans (a call a layer), its result the state operand's
+        # buffer; no fusion is handed the whole state beside it
+        whole = "f32[12,16,30,96,192]"
+        assert d.state_kernels(text) == [(whole, whole)] * 4
+        assert not d.readers_of_whole(text, "12,16,30,96,192")
+        assert "select_dynamic-update-slice" not in text
     mem = compiled.memory_analysis()
     # as the chip stores it: rows of 192 in 256 lanes
     state = 12 * 16 * 30 * 96 * 256 * 4 + 2 * d.state["delta_taps"].size
@@ -616,13 +656,17 @@ def test_a_parallel_layer_writes_pages_and_state_and_copies_neither(
     text = compiled.as_text()
     assert not d.copies_of_a_pool(text)
     # nothing yields the whole state but the program's own operand and
-    # result (a copy, or an update that is not in place)
+    # result (a copy, or an update that is not in place); the state
+    # kernel's pair of results holds it, aliased
     for dims in (r"f32\[6,64,32,256,128\]", r"bf16\[6,3,64,5120\]"):
         made = re.findall(
             rf"(\S+) = {dims}\S* (?!bitcast\(|get-tuple-element\(|parameter\()"
             r"([\w-]+)\(", text)
-        assert all(op in ("dynamic-update-slice", "fusion", "tuple")
-                   for _, op in made), made
+        made += re.findall(rf"(\S+) = \([^=]*?{dims}[^=]*?\) ([\w-]+)\(", text)
+        assert all(
+            op in ("dynamic-update-slice", "fusion", "tuple", "custom-call")
+            for _, op in made
+        ), made
         assert not re.findall(rf"= {dims}\S* copy\(", text)
     if program == "decode_step":
         kernels = [
@@ -632,6 +676,14 @@ def test_a_parallel_layer_writes_pages_and_state_and_copies_neither(
         ]
         assert kernels and all("bf16[6,4,12288,16,128]" in k for k in kernels)
         assert "f32[64,4,5,128]" in text  # the kernel's groups of 5
+        # the Mamba-2 state in the state kernel, one call in the run's scan
+        # (a call a layer), its result the state operand's buffer; no
+        # fusion is handed the whole state beside it (the parent's readout
+        # and select-and-update, 2.71 s of 8.5 busy on the chip)
+        whole = "f32[6,64,32,256,128]"
+        assert d.state_kernels(text) == [(whole, whole)]
+        assert not d.readers_of_whole(text, "6,64,32,256,128")
+        assert "select_dynamic-update-slice" not in text
     mem = compiled.memory_analysis()
     state = 4 * d.state["ssm_s"].size + 2 * d.state["ssm_taps"].size
     assert mem.alias_size_in_bytes == sum(2 * p.size for p in d.pools) + state
@@ -641,6 +693,19 @@ def test_a_parallel_layer_writes_pages_and_state_and_copies_neither(
     assert mem.temp_size_in_bytes < bound[program] * 2**30
     own = mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert own < 261120 * 4 + 2**20
+
+
+@pytest.mark.parametrize("name", ["mistral", "internlm2", "mixed", "lfm2"])
+def test_a_decode_step_with_no_matrix_of_state_holds_no_state_kernel(
+    deployment, name
+):
+    """The four configurations that keep no float32 matrix a head by slot
+    (chat and rag share mistral's; ``turns``' state is columns) compile no
+    state kernel into ``decode_step``, whichever the engine would choose."""
+    d = deployment(name)
+    assert d.eng._state_kernel == "compiled"
+    text = d.decode_step().as_text()
+    assert "state_step_decode" not in text and not d.state_kernels(text)
 
 
 @pytest.mark.parametrize("name", ["mistral", "internlm2", "olmo", "falcon"])

@@ -188,8 +188,9 @@ def test_decode_kernel_matches_gather_path(small, temperature):
 
 def test_no_caller_chooses_the_attention_path(small, monkeypatch):
     """The platform chooses: the kernel on a TPU, the XLA formulation
-    elsewhere, for the decode attention and for the expert layers' grouped
-    matmuls alike; the constructor has no argument for either."""
+    elsewhere, for the decode attention, the expert layers' grouped
+    matmuls and the step of a float32 state a head alike; the constructor
+    has no argument for any."""
     import inspect
 
     cfg, params = small
@@ -198,10 +199,11 @@ def test_no_caller_chooses_the_attention_path(small, monkeypatch):
     with pytest.raises(TypeError):
         ContinuousBatchingEngine(cfg, params, use_pallas_attention=True)
     eng = ContinuousBatchingEngine(cfg, params)
-    assert (eng._attn_kernel, eng._moe_kernel) == (None, None)
+    kernels = ("_attn_kernel", "_moe_kernel", "_state_kernel")
+    assert {getattr(eng, k) for k in kernels} == {None}
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     eng = ContinuousBatchingEngine(cfg, params)
-    assert (eng._attn_kernel, eng._moe_kernel) == ("compiled", "compiled")
+    assert {getattr(eng, k) for k in kernels} == {"compiled"}
 
 
 def test_concurrent_callers_share_one_engine(small):

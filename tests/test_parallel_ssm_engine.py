@@ -89,7 +89,7 @@ def make_engine(toy, on_tpu=False, **kw):
             m.setattr(jax, "default_backend", lambda: "tpu")
         eng = ContinuousBatchingEngine(toy[0], toy[1], **kw)
     if on_tpu:
-        eng._attn_kernel = "interpret"
+        eng._attn_kernel = eng._state_kernel = "interpret"
     assert (eng.max_prefill_tokens, eng.prefill_chunk) == (16, 4)
     return eng
 
@@ -249,8 +249,9 @@ def test_prefill_then_decode_agrees_with_the_reference(toy, on_tpu):
     prefill run returns (its last real token's) against the reference's at
     that position; a decoded token by the reference's logit of it against
     the reference's best at that position. With ``on_tpu`` the pool is
-    built as on the chip, rows of 16 stored in whole tiles of 128, and the
-    attention goes through the Pallas kernel in groups of 5, interpreted."""
+    built as on the chip, rows of 16 stored in whole tiles of 128, the
+    attention goes through the Pallas kernel in groups of 5 and the
+    Mamba-2 state through the state kernel, both interpreted."""
     eng = make_engine(toy, on_tpu)
     assert (eng.pool.k_dim, eng.pool.v_dim) == ((128, 128) if on_tpu else (16, 16))
     rng = np.random.default_rng(0)
@@ -473,6 +474,30 @@ def test_spans_carry_the_state_and_scan_counts(toy):
         assert d["ssm_state_bytes"] == d["state_bytes"] == 2 * d["live"] * a_slot
         assert d["full_pages"] == d["pages_written"]
         assert d["attn_full_layers"] == 3 and "window_pages" not in d
+
+
+def test_the_state_kernel_serves_the_jnp_paths_tokens(toy):
+    """``decode_step``'s Mamba-2 state through the state kernel, interpreted (as
+    the engine runs it compiled on a TPU), serves the tokens the jnp path
+    serves, slot for slot, with slots that idle and fill beside live ones;
+    ``engine.decode`` ``state_kernel_layers`` counts the 3 parallel layers a
+    step in the kernel, 0 on the jnp path."""
+    prompts = [list(range(1, 30)), [7, 8], [9, 10, 11, 12, 13], [300, 301]]
+    outs = {}
+    for kernel in (None, "interpret"):
+        tracing.SPANS.clear()
+        eng = make_engine(toy)
+        assert eng._state_kernel is None  # no TPU here
+        eng._state_kernel = kernel
+        outs[kernel] = eng.generate_ids(
+            prompts, GenerationConfig(max_new_tokens=12)
+        )
+        decodes = [s["args"] for s in tracing.SPANS.slices(cat="engine")
+                   if s["name"] == "engine.decode"]
+        assert decodes and max(d["live"] for d in decodes) == 3
+        assert {d["state_kernel_layers"] for d in decodes} == {
+            3 if kernel else 0}
+    assert outs["interpret"] == outs[None]
 
 
 class _Cache:
